@@ -74,10 +74,7 @@ ROWS_PER_WRITER = 16
 def worker() -> None:
     import faulthandler
 
-    import jax
-
     faulthandler.register(signal.SIGUSR1)  # stack dump on demand
-    jax.config.update("jax_platforms", "cpu")
 
     from pilosa_tpu.server.config import Config
     from pilosa_tpu.server.server import Server

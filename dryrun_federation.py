@@ -74,8 +74,6 @@ def worker() -> None:
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-
     from pilosa_tpu.server.config import ClusterConfig, Config
     from pilosa_tpu.server.server import Server
 

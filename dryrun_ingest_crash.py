@@ -50,10 +50,6 @@ FAULTS = "fsync_fail_every=23,torn_at=9000"
 
 
 def worker() -> None:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from pilosa_tpu.server.config import Config
     from pilosa_tpu.server.server import Server
 

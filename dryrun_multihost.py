@@ -85,8 +85,6 @@ def worker() -> None:
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-
     from pilosa_tpu.parallel import multihost
     from pilosa_tpu.server.config import Config
     from pilosa_tpu.server.http_handler import encode_result

@@ -65,10 +65,6 @@ N_SHARDS = 4  # ≥3 owned fragments get rotted
 
 
 def worker() -> None:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from pilosa_tpu.server.config import ClusterConfig, Config
     from pilosa_tpu.server.server import Server
 

@@ -46,10 +46,6 @@ ARTIFACT = "TRANSLATE_r20.json"
 
 
 def worker() -> None:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from pilosa_tpu.server.config import Config
     from pilosa_tpu.server.server import Server
 

@@ -860,7 +860,6 @@ _SKIP_DIRS = {
     "__pycache__",
     ".git",
     "native",
-    "experiments",
     ".claude",
     "node_modules",
 }

@@ -1,6 +1,6 @@
 """Device health gate: graceful TPU -> CPU degradation.
 
-A tunneled/remote accelerator can wedge mid-serving (a hung PJRT call
+An accelerator can wedge mid-serving (a hung PJRT call
 blocks in C and never returns). The reference has no analog — its
 compute is the serving process — but here every query would otherwise
 hang behind a dead device even though the executor carries a complete

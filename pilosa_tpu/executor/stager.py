@@ -1115,6 +1115,15 @@ class DeviceStager:
             frag=frags,
         )
 
+    def has_room(self, nbytes: int) -> bool:
+        """Would an entry of ``nbytes`` fit without evicting anything?
+        Advisory staging asks first: what it would push out is, as a
+        rule, what the running query is reading."""
+        with self._mu:
+            fits = self._bytes + nbytes <= self.budget_bytes
+        gov = self.governor
+        return fits and (gov is None or nbytes <= gov.headroom())
+
     def stage_ahead(self, thunk) -> None:
         """Queue an advisory warm thunk on the background prefetch
         thread: the dispatch engine calls this with the NEXT wave's

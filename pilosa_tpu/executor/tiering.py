@@ -35,8 +35,7 @@ cheap:
 The compressed-upload path (stager._dense_from_blocks) rides T1: when
 the dense/compressed ratio clears ``compressed-upload-min-ratio``, the
 container payloads themselves cross the wire and a jit scatter kernel
-(ops.packed.expand_blocks; ops/pallas_kernels.py expand_runs_pallas on
-TPU-shaped inputs) expands them to packed words on device.
+(ops.packed.expand_blocks) expands them to packed words on device.
 """
 
 from __future__ import annotations

@@ -1,90 +1,132 @@
 """ctypes binding for the native C++ bitmap kernels (native/).
 
-Auto-builds ``native/libpilosa_kernels.so`` with g++ on first import if
-missing, and degrades to numpy implementations when no compiler is
-available — the roaring engine works either way, the native path just
-removes temporaries and Python overhead from the hot loops.
+Builds ``native/libpilosa_kernels.so`` with g++ on first use when it is
+missing or was not built from this source, with these flags, on this
+host's CPU (``-march=native`` ties the binary to the CPU that compiled
+it, and a tree may be copied between machines with the ``.so`` in it).
+Degrades to numpy implementations when no compiler is available — the
+roaring engine works either way, the native path just removes
+temporaries and Python overhead from the hot loops. ``require()`` is for
+callers that must not degrade.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 from typing import Optional
 
 import numpy as np
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "bitmap_kernels.cpp")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libpilosa_kernels.so")
+_STAMP_PATH = _SO_PATH + ".stamp"
+_CXXFLAGS = (
+    "-O3", "-march=native", "-funroll-loops", "-fPIC", "-shared", "-std=c++17",
+)
 
 _lib: Optional[ctypes.CDLL] = None
+# why the last _load() returned None, for require()
+_load_error = ""
 
 
-def _build() -> bool:
-    src = os.path.join(_NATIVE_DIR, "bitmap_kernels.cpp")
-    if not os.path.exists(src):
-        return False
+def _host_cpu() -> str:
+    """What ``-march=native`` resolves against: the machine type and the
+    first CPU's model and ISA flags."""
+    ident = [platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            seen = set()
+            for line in f:
+                key = line.split(":", 1)[0].strip()
+                if key in ("model name", "flags", "Features") and key not in seen:
+                    seen.add(key)
+                    ident.append(line.strip())
+    except OSError:
+        pass
+    return "\n".join(ident)
+
+
+def _fingerprint() -> Optional[str]:
+    """Content hash of source + flags + host CPU; None without source."""
+    try:
+        with open(_SRC_PATH, "rb") as f:
+            src = f.read()
+    except OSError:
+        return None
+    h = hashlib.sha256(src)
+    h.update(" ".join(_CXXFLAGS).encode())
+    h.update(_host_cpu().encode())
+    return h.hexdigest()
+
+
+def _build(fingerprint: str) -> bool:
+    global _load_error
+    # build beside the target and rename: concurrent builders (test
+    # workers) each publish a whole file, never a half-written one
+    tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            [
-                "g++",
-                "-O3",
-                "-march=native",
-                "-funroll-loops",
-                "-fPIC",
-                "-shared",
-                "-std=c++17",
-                "-o",
-                _SO_PATH,
-                src,
-            ],
+            ["g++", *_CXXFLAGS, "-o", tmp, _SRC_PATH],
             check=True,
             capture_output=True,
             timeout=120,
         )
+        os.replace(tmp, _SO_PATH)
+        with open(tmp, "w") as f:
+            f.write(fingerprint)
+        os.replace(tmp, _STAMP_PATH)
         return True
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
+        stderr = getattr(e, "stderr", b"") or b""
+        _load_error = f"build failed: {e} {stderr.decode(errors='replace')[-400:]}"
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
         return False
 
 
-def _stale() -> bool:
-    """The .so predates the source — a prebuilt library from an older
-    checkout would be missing newer symbols."""
+def _built_from(fingerprint: str) -> bool:
+    """Was the .so on disk built from this source, flags and host?"""
     try:
-        src = os.path.getmtime(os.path.join(_NATIVE_DIR, "bitmap_kernels.cpp"))
-        so = os.path.getmtime(_SO_PATH)
-        return src > so
+        with open(_STAMP_PATH) as f:
+            return os.path.exists(_SO_PATH) and f.read().strip() == fingerprint
     except OSError:
         return False
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib
+    global _lib, _load_error
     if _lib is not None:
         return _lib
-    if (not os.path.exists(_SO_PATH) or _stale()) and not _build():
-        if not os.path.exists(_SO_PATH):
-            return None
-    try:
-        lib = ctypes.CDLL(_SO_PATH)
-    except OSError:
+    fingerprint = _fingerprint()
+    if fingerprint is None:
+        _load_error = f"no source at {_SRC_PATH}"
+        return None
+    if not _built_from(fingerprint) and not _build(fingerprint):
+        # never load a library of unknown origin: it may be built for
+        # another CPU or from an older source
         return None
     try:
+        lib = ctypes.CDLL(_SO_PATH)
         _bind(lib)
-    except AttributeError:
-        # stale prebuilt .so missing a newer symbol (e.g. built before
-        # the mtime check existed): rebuild once, then degrade to numpy
-        # rather than crash — the module contract
-        if not _build():
-            return None
-        try:
-            lib = ctypes.CDLL(_SO_PATH)
-            _bind(lib)
-        except (OSError, AttributeError):
-            return None
+    except (OSError, AttributeError) as e:
+        _load_error = f"load failed: {e}"
+        return None
     _lib = lib
     return lib
+
+
+def require() -> None:
+    """Raise unless the native library is built for this host and
+    loaded — for callers that must not fall back to numpy."""
+    if _load() is None:
+        raise RuntimeError(f"native kernels unavailable: {_load_error}")
 
 
 def _bind(lib: ctypes.CDLL) -> None:

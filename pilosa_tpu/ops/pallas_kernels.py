@@ -1,14 +1,15 @@
-"""Pallas TPU kernels for the hottest scan: TopN intersection scoring.
+"""Pallas TPU kernels for the hottest scans, explicitly tiled.
 
 The XLA path (ops.intersection_counts_matrix) already fuses AND+popcount+
-reduce; this Pallas version adds explicit tiling so the fragment matrix
+reduce; the Pallas versions add explicit tiling so the fragment matrix
 streams HBM→VMEM in (TILE_R, TILE_W) blocks with the src row pinned in
-VMEM, accumulating per-row partial popcounts across word tiles — the
-scan is purely HBM-bandwidth-bound and this keeps the working set inside
-VMEM. bench.py measures both and the executor keeps whichever wins.
+VMEM, accumulating per-row partial popcounts across word tiles.
 
-Falls back to interpret mode off-TPU so semantics are testable on the
-CPU mesh.
+No kernel here is called by the served path today: the executor and the
+stager use the XLA forms in ops/packed.py, and only bench.py and the
+tests call these (ROADMAP D4). Every kernel compiles for a described
+v5e (tests/test_tpu_compile.py); ``interpret=True`` runs them on the CPU
+so their semantics are tested there.
 """
 
 from __future__ import annotations
@@ -189,25 +190,25 @@ def _expand_runs_kernel(starts_ref, ends_ref, out_ref):
     # [start, end] bit interval against each word's 32-bit span and
     # ORs in the overlap mask. Runs are few (RLE containers cap at
     # 2048 intervals) while words are many, so the run loop stays
-    # sequential and the word axis rides the VPU lanes.
+    # sequential and the word axis rides the VPU lanes. The endpoints
+    # are scalar-prefetched into SMEM: the loop reads one scalar per
+    # run from the ref (Mosaic has no dynamic slice of a loaded vector).
     i = pl.program_id(0)
     full = jnp.uint32(0xFFFFFFFF)
     wid = i * TILE_W + jax.lax.broadcasted_iota(jnp.int32, (1, TILE_W), 1)
     word_lo = wid * 32
     word_hi = word_lo + 31
-    starts = starts_ref[:]
-    ends = ends_ref[:]
 
     def body(k, acc):
-        lo = jnp.maximum(starts[0, k], word_lo)
-        hi = jnp.minimum(ends[0, k], word_hi)
+        lo = jnp.maximum(starts_ref[k], word_lo)
+        hi = jnp.minimum(ends_ref[k], word_hi)
         sb = jnp.clip(lo - word_lo, 0, 31).astype(jnp.uint32)
         eb = jnp.clip(hi - word_lo, 0, 31).astype(jnp.uint32)
         m = (full << sb) & (full >> (31 - eb))
         return acc | jnp.where(lo <= hi, m, jnp.uint32(0))
 
     out_ref[:] = jax.lax.fori_loop(
-        0, starts.shape[1], body, jnp.zeros((1, TILE_W), jnp.uint32)
+        0, starts_ref.shape[0], body, jnp.zeros((1, TILE_W), jnp.uint32)
     )
 
 
@@ -218,23 +219,21 @@ def expand_runs_pallas(run_starts, run_ends, num_words: int, *, interpret: bool 
     along as width-1 runs). num_words must be a multiple of TILE_W (a
     row is 32768 words, so stacked rows always are); pad the run list
     with start > end — an empty interval contributes nothing. The jit
-    scatter fallback (ops.packed.expand_blocks) covers CPU/interpret
-    mode and dense bitmap containers."""
-    n = run_starts.shape[0]
-    grid = (num_words // TILE_W,)
+    scatter form (ops.packed.expand_blocks) is what the stager calls; it
+    also covers dense bitmap containers."""
     out = pl.pallas_call(
         _expand_runs_kernel,
         out_shape=jax.ShapeDtypeStruct((1, num_words), jnp.uint32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, n), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, TILE_W), lambda i: (0, i), memory_space=pltpu.VMEM
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(num_words // TILE_W,),
+            in_specs=[],
+            out_specs=pl.BlockSpec(
+                (1, TILE_W), lambda i, starts, ends: (0, i), memory_space=pltpu.VMEM
+            ),
         ),
         interpret=interpret,
-    )(run_starts.reshape(1, n), run_ends.reshape(1, n))
+    )(run_starts, run_ends)
     return out[0]
 
 

@@ -24,19 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-if not hasattr(jax, "shard_map"):
-    # jax < 0.5 ships shard_map under experimental, where the replication
-    # checker kwarg is spelled check_rep instead of check_vma; adapt so
-    # the kernels below read against the stable spelling
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, *, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _exp_shard_map(f, **kw)
-
-    jax.shard_map = _shard_map
-
 SHARD_AXIS = "shards"
 
 

@@ -76,9 +76,9 @@ class Config:
     # (executor/devicehealth.py); 0 disables the gate. The default
     # clears a cold first-query compile (~40 s) with margin.
     device_timeout: float = 120.0
-    # auto-policy crossover, in estimated touched containers (see
-    # AUTOTUNE.json): default assumes a co-located chip; raise to
-    # ~3700 behind a high-RTT tunnel. 0 = keep the executor default.
+    # auto-policy crossover, in estimated touched containers. 0 = keep
+    # the executor default (not measured on the current machine;
+    # executor/autotune.py measures the crossover at open).
     auto_device_min_containers: int = 0
     # SPMD: number of local devices to mesh the shard axis over.
     # 0/1 = single-device; >1 builds a jax.sharding.Mesh and the

@@ -863,6 +863,8 @@ class Handler:
         stager = getattr(self.api.executor, "stager", None)
         if stager is not None:
             metrics.gauge(metrics.STAGER_BYTES, stager._bytes)
+        # the hbm.* gauges otherwise lag by the poller's interval
+        profiler.TELEMETRY.poll_once()
         # scrape-time freshness: uptime companion to build_info, and the
         # SLO gauges re-derived from the sample windows so the scrape
         # never reads a stale burn rate between server ticks

@@ -83,6 +83,7 @@ EXECUTOR_CALLS = "executor.calls"
 EXECUTOR_ROUTE_DEVICE = "executor.route.device"
 EXECUTOR_ROUTE_CPU = "executor.route.cpu"
 EXECUTOR_DEVICE_DOWN_FALLBACK = "executor.device_down_fallback"
+EXECUTOR_NOT_DEVICEABLE = "executor.not_deviceable"
 SPMD_COMPILE_SECONDS = "spmd.compile_seconds"
 SPMD_EXECUTE_SECONDS = "spmd.execute_seconds"
 # batched scorers
@@ -215,6 +216,7 @@ ANALYTICS_DEGRADED_LEGS = "analytics.degraded_legs"
 PLANCACHE_DEVICE_HITS = "plancache.device_hits"
 PLANCACHE_DEVICE_EVICTIONS = "plancache.device_evictions"
 PLANCACHE_DEVICE_BYTES = "plancache.device_bytes"
+PLANCACHE_DEVICE_UPLOAD_ERRORS = "plancache.device_upload_errors"
 # invariant checker — dynamic lock-order detection (analysis/locks.py)
 ANALYSIS_LOCK_CYCLES = "analysis.lock_cycles"
 ANALYSIS_LOCK_GRAPH_EDGES = "analysis.lock_graph_edges"
@@ -291,6 +293,11 @@ METRICS: dict[str, tuple[str, str]] = {
     EXECUTOR_DEVICE_DOWN_FALLBACK: (
         "counter",
         "read calls re-run on the CPU path after the device health gate tripped",
+    ),
+    EXECUTOR_NOT_DEVICEABLE: (
+        "counter",
+        "call subtrees the device path declined, served by the CPU "
+        "roaring path instead (label: what)",
     ),
     SPMD_COMPILE_SECONDS: (
         "summary",
@@ -757,6 +764,11 @@ METRICS: dict[str, tuple[str, str]] = {
         "gauge",
         "HBM bytes held by device-resident plan-cache entries",
     ),
+    PLANCACHE_DEVICE_UPLOAD_ERRORS: (
+        "counter",
+        "__cached subtree stacks whose upload to the device failed; the "
+        "call went on with the host array",
+    ),
     ANALYSIS_LOCK_CYCLES: (
         "gauge",
         "distinct lock-order cycles observed by the OrderedLock graph "
@@ -777,8 +789,8 @@ METRICS: dict[str, tuple[str, str]] = {
     BUILD_INFO: (
         "gauge",
         "always 1; the process identifies itself via labels (version, "
-        "jax, backend, pid, gang, rank, leader) — fleet scrapes are "
-        "self-identifying",
+        "jax, backend, device_kind, device_count, native, pid, gang, "
+        "rank, leader) — fleet scrapes are self-identifying",
     ),
     EVENTS_RECORDED: (
         "counter",

@@ -415,6 +415,8 @@ class DeviceTelemetry:
         self.interval_s = interval_s
         self._above: set = set()
         self._peak: dict = {}
+        # the poller thread and a /metrics scrape both poll
+        self._mu = threading.Lock()
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
         # optional callable returning (stager_bytes, stager_limit); the
@@ -445,6 +447,10 @@ class DeviceTelemetry:
         return out
 
     def poll_once(self) -> dict:
+        with self._mu:
+            return self._poll_locked()
+
+    def _poll_locked(self) -> dict:
         self.polls += 1
         snap: dict = {"devices": {}}
         for label, stats in self._device_stats():

@@ -17,12 +17,12 @@ from pilosa_tpu.executor.autotune import (
 
 
 class TestCrossoverMath:
-    def test_high_rtt_rig(self):
-        # the AUTOTUNE.json measurements: 66 ms dispatch, 0.018 ms/ctr
+    def test_slow_dispatch(self):
+        # a deployment whose dispatch costs 66 ms against 0.018 ms/ctr
         got = tuned_min_containers(dispatch_ms=66.0, cpu_ms_per_container=0.018)
         assert 3000 <= got <= 4000, got
 
-    def test_colocated_rig(self):
+    def test_fast_dispatch(self):
         got = tuned_min_containers(dispatch_ms=1.5, cpu_ms_per_container=0.018)
         assert 50 <= got <= 120, got
 
@@ -51,9 +51,9 @@ class TestExecutorAdoption:
                 h.field("i", "f").set_bit(r, c)
         return Executor(h, device_policy="auto")
 
-    def test_high_rtt_routes_small_queries_to_cpu_without_env(self):
+    def test_slow_dispatch_routes_small_queries_to_cpu_without_env(self):
         ex = self._executor()
-        # simulated deployment measurement: tunneled chip
+        # simulated deployment measurement: a 66 ms dispatch
         autotune_executor(
             ex, blocking=True,
             measure=lambda: tuned_min_containers(66.0, 0.018),

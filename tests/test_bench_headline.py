@@ -2,8 +2,8 @@
 published value is the best measured closed-loop serving number, never
 lowered by a degraded window below the sequential number the run
 achieved, and the vs_baseline note always states which convention the
-ratio uses. These lock the semantics the BENCH_r05 artifacts and
-docs/perf_analysis.md rely on."""
+ratio uses. These lock the semantics the BENCH_r05 artifacts rely
+on."""
 
 import importlib.util
 import os

@@ -182,6 +182,32 @@ def test_stack_is_mesh_sharded(spmd_exec, mesh):
     assert getattr(sharding, "mesh", None) is not None
 
 
+@pytest.mark.parametrize(
+    "form, query",
+    [
+        ("row_stack", "Count(Row(general=1))"),
+        ("planes_stack", "Sum(Row(general=1), field=val)"),
+        ("sparse_rows_stack", "TopN(general, Row(general=1), n=5)"),
+    ],
+)
+def test_staged_stack_is_spread_evenly_over_the_mesh(spmd_exec, mesh, form, query):
+    """Every shard-major form the mesh path stages puts an equal share
+    on each device: a stack that sits on the first chip would still
+    answer correctly, and only the bytes show it."""
+    import jax
+
+    spmd_exec.execute("i", query)
+    staged = [e.value for key, e in spmd_exec.stager._cache.items() if form in key]
+    assert staged, f"{form} was not staged"
+    arrays = [a for a in jax.tree_util.tree_leaves(staged) if hasattr(a, "sharding")]
+    assert arrays
+    for a in arrays:
+        per_device = {s.device: s.data.nbytes for s in a.addressable_shards}
+        assert set(per_device) == set(mesh.devices.flat)
+        assert len(set(per_device.values())) == 1
+        assert sum(per_device.values()) == a.nbytes
+
+
 def test_http_server_with_mesh(tmp_path):
     """End-to-end: HTTP query against a server configured with
     mesh_devices=all answers identically to a meshless server."""

@@ -1,0 +1,262 @@
+"""Compile the served path's kernels for a described TPU v5e, no chip
+attached (guide on-chip-measurement §2, rehearsal 3).
+
+What the chip's compiler refuses — an unsupported primitive in a Pallas
+kernel, a program that does not fit device memory, a kernel that cannot
+be partitioned — is raised here at real widths (W = 32768 words per
+shard row), so it costs no chip time. Nothing runs: a compile that
+passes says nothing about results or times.
+
+Only one process at a time may load the TPU's library and it keeps it
+until exit, so the topology is described inside a module-scoped fixture
+(never at import, in a ``skipif`` or in ``parametrize``), every compile
+happens in this process, and all of it lives in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from pilosa_tpu import ops
+from pilosa_tpu.executor import executor as executor_mod
+from pilosa_tpu.executor import fusion
+from pilosa_tpu.ops import pallas_kernels
+from pilosa_tpu.parallel import spmd
+
+W = 32768  # u32 words per shard row: 2^20 columns
+S = 64  # shards in BASELINE config 4
+DEPTH = 10  # BSI bit depth of chip_smoke.py's int field
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    return shape
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    return Mesh(np.array(topo.devices[:4]), (spmd.SHARD_AXIS,))
+
+
+_CHAIN = (
+    "Intersect",
+    (
+        ("Union", (("leaf", 0), ("leaf", 1))),
+        ("Union", (("leaf", 2), ("Difference", (("leaf", 3), ("leaf", 4))))),
+    ),
+)
+
+
+def _matrix_batch(s):
+    return ops.intersection_counts_matrix_batch.lower(s((32, W)), s((4096, W)))
+
+
+def _sparse_stacked_mat(s):
+    # chip_smoke.py's TopN head chunk: 128 candidates x 64 shards, every
+    # one a hot row holding all 16 container blocks
+    b = S * 128 * 16
+    i32 = jnp.int32
+    return ops.sparse_intersection_counts_stacked_mat.lower(
+        s((S, W)), s((b, 2048)), s((b,), i32), s((b,), i32), s((b,), i32),
+        num_rows=S * 128, n_shards=S, chunk=128,
+    )
+
+
+def _sparse_stacked_second_chunk(s):
+    # the largest program chip_smoke.py launches: 4096 candidates x 64
+    # shards, 385,024 blocks padded to 2^19 — 4 GiB in, 4 GiB of scratch
+    b = 1 << 19
+    i32 = jnp.int32
+    return ops.sparse_intersection_counts_stacked.lower(
+        s((S, W)), s((b, 2048)), s((b,), i32), s((b,), i32), s((b,), i32),
+        num_rows=S * 4096,
+    )
+
+
+def _count_tree(s):
+    fn = jax.jit(lambda *ls: ops.count_bits(executor_mod._eval_tree(_CHAIN, ls))[None])
+    return fn.lower(*[s((S, W))] * 5)
+
+
+def _expand_blocks(s):
+    i32 = jnp.int32
+    return ops.expand_blocks.lower(
+        s((65536,)), s((2048,)), s((2048,)), s((16, 2048)), s((16,), i32),
+        num_words=16 * W,
+    )
+
+
+def _bsi_sum(s):
+    return ops.bsi_plane_counts_batched.lower(
+        s((S, DEPTH + 1, W)), s((S, W)), bit_depth=DEPTH, has_filter=True
+    )
+
+
+def _bsi_range(s):
+    fn = jax.jit(
+        jax.vmap(
+            lambda p, pred: ops.bsi_range_lt(
+                p, pred, bit_depth=DEPTH, allow_equality=False
+            ),
+            in_axes=(0, None),
+        )
+    )
+    return fn.lower(s((S, DEPTH + 1, W)), s((), jnp.uint32))
+
+
+def _groupby_plane_counts(s):
+    return ops.groupby_plane_counts.lower(s((12, S * W)), s((DEPTH + 1, S * W)))
+
+
+def _fused_query(s):
+    # Count + Count(chain) + TopN head + Sum in one program, the shape of
+    # chip_smoke.py's first multi-call request
+    b = S * 128 * 16
+    i32 = jnp.int32
+    descs = (
+        ("count", ("leaf", 0), 1),
+        ("count", _CHAIN, 5),
+        ("topn", S * 128, S, 128),
+        ("sum", DEPTH, True),
+    )
+    flat = (
+        [s((S, W))] * 6
+        + [s((S, W)), s((b, 2048)), s((b,), i32), s((b,), i32), s((b,), i32)]
+        + [s((S, DEPTH + 1, W)), s((S, W))]
+    )
+    return jax.jit(fusion._build_program(descs)).lower(*flat)
+
+
+@pytest.mark.parametrize(
+    "lower",
+    [
+        _matrix_batch,
+        _sparse_stacked_mat,
+        _sparse_stacked_second_chunk,
+        _count_tree,
+        _expand_blocks,
+        _bsi_sum,
+        _bsi_range,
+        _groupby_plane_counts,
+        _fused_query,
+    ],
+    ids=lambda f: f.__name__.lstrip("_"),
+)
+def test_served_kernel_compiles_for_v5e(one_chip, lower):
+    compiled = lower(one_chip).compile()
+    mem = compiled.memory_analysis()
+    # 16 GB of HBM: arguments plus scratch of one program must fit
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 << 30
+
+
+def _count_fold(mesh, s):
+    return spmd.count_fold_spmd(mesh).lower(s((S, 3, W)))
+
+
+def _count_stack(mesh, s):
+    return spmd.count_stack_spmd(mesh).lower(s((S, W)))
+
+
+def _topn(mesh, s):
+    return spmd.topn_spmd(mesh, 16).lower(s((S, W)), s((S, 64, W)))
+
+
+def _topn_scores_sparse(mesh, s):
+    i32 = jnp.int32
+    return spmd.topn_scores_sparse_spmd(mesh, 128).lower(
+        s((S, W)), s((S, 2048, 2048)), s((S, 2048), i32), s((S, 2048), i32)
+    )
+
+
+def _bsi_sum_mesh(mesh, s):
+    return spmd.bsi_sum_spmd(mesh, DEPTH, True).lower(
+        s((S, DEPTH + 1, W)), s((S, W))
+    )
+
+
+@pytest.mark.parametrize(
+    "lower",
+    [_count_fold, _count_stack, _topn, _topn_scores_sparse, _bsi_sum_mesh],
+    ids=lambda f: f.__name__.strip("_"),
+)
+def test_spmd_kernel_compiles_for_four_chips(mesh, lower):
+    sharding = NamedSharding(mesh, P(spmd.SHARD_AXIS))
+    whole = []
+
+    def shape(dims, dtype=jnp.uint32):
+        whole.append(int(np.prod(dims)) * jnp.dtype(dtype).itemsize)
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    compiled = lower(mesh, shape).compile()
+    text = compiled.as_text()
+    # the cross-shard reduce is a collective inside the program (the
+    # compiler turns a small all_gather into an all-reduce)
+    assert "all-reduce" in text or "all-gather" in text
+    # each device holds a quarter of every shard-major operand
+    assert compiled.memory_analysis().argument_size_in_bytes == sum(whole) // 4
+
+
+def _pallas_scores(s):
+    return pallas_kernels.intersection_counts_matrix_pallas.lower(
+        s((W,)), s((4096, W))
+    )
+
+
+def _pallas_scores_batch(s):
+    return pallas_kernels.intersection_counts_matrix_batch_pallas.lower(
+        s((8, W)), s((4096, W))
+    )
+
+
+def _pallas_groupby_planes(s):
+    return pallas_kernels.groupby_plane_counts_pallas.lower(
+        s((DEPTH + 1, W)), s((512, W))
+    )
+
+
+def _pallas_expand_runs(s):
+    i32 = jnp.int32
+    return pallas_kernels.expand_runs_pallas.lower(
+        s((2048,), i32), s((2048,), i32), num_words=W
+    )
+
+
+@pytest.mark.parametrize(
+    "lower",
+    [
+        _pallas_scores,
+        _pallas_scores_batch,
+        _pallas_groupby_planes,
+        _pallas_expand_runs,
+    ],
+    ids=lambda f: f.__name__.lstrip("_"),
+)
+def test_pallas_kernel_compiles_for_v5e(one_chip, lower):
+    compiled = lower(one_chip).compile()
+    assert "tpu_custom_call" in compiled.as_text()
