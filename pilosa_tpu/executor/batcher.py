@@ -34,7 +34,7 @@ from typing import Optional
 import numpy as np
 
 from pilosa_tpu import ops
-from pilosa_tpu.utils import metrics, trace
+from pilosa_tpu.utils import metrics, profiler, trace
 
 
 def _next_pow2(n: int) -> int:
@@ -111,9 +111,18 @@ class BatchedScorer:
     """
 
     def __init__(
-        self, max_batch: int = 32, single_fn=None, batch_fn=None, pad_fn=None
+        self,
+        max_batch: int = 32,
+        single_fn=None,
+        batch_fn=None,
+        pad_fn=None,
+        kind: Optional[str] = "topn_score_dense",
     ) -> None:
         self.max_batch = max_batch
+        # the kernel's name in spmd.execute_seconds{kind} and
+        # kernel.operand_bytes{kind}, launch → fetched; None where the
+        # kernel pair is already wrapped by the executor's _timed_kernel
+        self.kind = kind
         # pow2 padding strategy: None = cached zeros_like (sources are
         # single arrays; a zero source scores 0 and is sliced off).
         # Callers whose src is NOT one array (the chain path's tuple of
@@ -165,48 +174,32 @@ class BatchedScorer:
         throughput is not measured on the current machine.
         """
         sp = trace.current()
-        attrib = trace.attrib_current()
-        t0 = time.monotonic()
         slot = _Slot(src, trim=trim)
-        with self._lock:
-            ent = self._pending.get(key)
-            if ent is None:
-                self._pending[key] = (mat, [slot])
-            else:
-                ent[1].append(slot)
-            if self._dispatching:
-                lead = False
-            else:
-                self._dispatching = lead = True
-        if lead:
-            pre_dev = (
-                attrib.get(trace.WF_DEVICE_COMPUTE, 0.0)
-                if attrib is not None
-                else 0.0
-            )
-            self._dispatch_loop(own=slot)
-        out = slot.finish(self)
-        wait = time.monotonic() - t0
-        metrics.observe(metrics.BATCHER_SLOT_WAIT_SECONDS, wait)
-        if attrib is not None:
+        # enqueue → result is the host's wait on the device. The leader's
+        # covers async launch + fetch (and at most one extra round served
+        # for peers); fenced legs inside its loop (the chain scorer's
+        # _timed_kernel) nest, so a second is credited once. A non-lead
+        # waiter's work ran inside the leader's launch, which credited
+        # only the leader's request: its slot wait is its device leg.
+        with trace.leg(trace.WF_DEVICE_COMPUTE) as lg:
+            with self._lock:
+                ent = self._pending.get(key)
+                if ent is None:
+                    self._pending[key] = (mat, [slot])
+                else:
+                    ent[1].append(slot)
+                if self._dispatching:
+                    lead = False
+                else:
+                    self._dispatching = lead = True
             if lead:
-                # the leader's wait covers async launch + device fetch
-                # (and at most one extra round served for peers) —
-                # device time. Kernels that are _timed_kernel-wrapped
-                # (chain batch) already attributed their fenced leg
-                # inside the dispatch loop; count only the remainder.
-                already = attrib.get(trace.WF_DEVICE_COMPUTE, 0.0) - pre_dev
-                if wait > already:
-                    trace.attrib_add(trace.WF_DEVICE_COMPUTE, wait - already)
-            else:
-                # a non-lead waiter's slot wait IS device time: its work
-                # ran inside the leader's launch, which attributed only
-                # to the leader's request (waterfall device.compute leg)
-                trace.attrib_add(trace.WF_DEVICE_COMPUTE, wait)
+                self._dispatch_loop(own=slot)
+            out = slot.finish(self)
+        metrics.observe(metrics.BATCHER_SLOT_WAIT_SECONDS, lg.seconds)
         if sp is not None:
             # backfill a span covering enqueue -> result (the wait was
             # spent inside finish(), so enter/exit timing can't be used)
-            sp.record(metrics.STAGE_BATCH_SCORE, t0, wait, lead=lead)
+            sp.record(metrics.STAGE_BATCH_SCORE, lg.t0, lg.seconds, lead=lead)
         return out
 
     def _rescue(self) -> None:
@@ -325,23 +318,24 @@ class BatchedScorer:
         # fetch back-to-back, lock management is the caller's business
         self._finish(self._launch(batch, mat))
 
-    def _launch(self, batch: list[_Slot], mat) -> list[tuple[list[_Slot], object]]:
+    def _launch(self, batch: list[_Slot], mat) -> list[tuple]:
         """Dispatch kernels for every chunk of ``batch`` asynchronously;
-        returns [(chunk, device_scores)] for _finish to fetch. On error,
-        fails EVERY not-yet-finished slot of the batch — including ones
-        whose chunk already launched (their device results are
-        discarded): a waiter must never be left blocked."""
+        returns [(chunk, device_scores, launch time)] for _finish to
+        fetch. On error, fails EVERY not-yet-finished slot of the batch
+        — including ones whose chunk already launched (their device
+        results are discarded): a waiter must never be left blocked."""
         import jax.numpy as jnp
 
-        launched: list[tuple[list[_Slot], object]] = []
+        launched: list[tuple] = []
         try:
             self.dispatches += 1
             metrics.count(metrics.BATCHER_DISPATCHES)
             metrics.observe(metrics.BATCHER_BATCH_SIZE, len(batch))
             if len(batch) == 1:
-                launched.append(
-                    (batch, _trim_device(self._single_fn(batch[0].src, mat), rows=batch[0].trim))
-                )
+                src, trim = batch[0].src, batch[0].trim
+                t0 = self._note_launch(src, mat)
+                dev = _trim_device(self._single_fn(src, mat), rows=trim)
+                launched.append((batch, dev, t0))
                 return launched
             for start in range(0, len(batch), self.max_batch):
                 chunk = batch[start : start + self.max_batch]
@@ -364,6 +358,7 @@ class BatchedScorer:
                                     "batcher", int(getattr(zero, "nbytes", 0))
                                 )
                         srcs = srcs + [zero] * (q - len(chunk))
+                t0 = self._note_launch(srcs, mat)
                 dev = self._batch_fn(srcs, mat)
                 # transfer hygiene: pad query lanes never reach the
                 # host, and when every slot declared its read width the
@@ -371,7 +366,7 @@ class BatchedScorer:
                 # moves exactly what the callers will consume)
                 trims = [s.trim for s in chunk]
                 keep = max(trims) if all(t is not None for t in trims) else None
-                launched.append((chunk, _trim_device(dev, rows=len(chunk), cols=keep)))
+                launched.append((chunk, _trim_device(dev, rows=len(chunk), cols=keep), t0))
             return launched
         except BaseException as e:
             for s in batch:
@@ -380,13 +375,26 @@ class BatchedScorer:
                     s.event.set()
             raise
 
-    def _finish(self, launched: list[tuple[list[_Slot], object]]) -> None:
+    def _note_launch(self, srcs, mat) -> float:
+        """One launch's operand bytes (padding included) under the
+        scorer's kernel name; returns the launch time for _finish."""
+        if self.kind is not None:
+            profiler.count_operands(self.kind, (srcs, mat))
+        return time.monotonic()
+
+    def _finish(self, launched: list[tuple]) -> None:
         """Fetch launched device results and wake the coalesced slots.
         Runs outside the dispatch lock so fetches pipeline with the next
         batch's launch."""
         try:
-            for chunk, dev_scores in launched:
+            for chunk, dev_scores, t0 in launched:
                 scores = np.asarray(dev_scores)
+                if self.kind is not None:
+                    metrics.observe(
+                        metrics.SPMD_EXECUTE_SECONDS,
+                        time.monotonic() - t0,
+                        kind=self.kind,
+                    )
                 if len(chunk) == 1 and scores.ndim == 1:
                     chunk[0].result = scores
                     chunk[0].event.set()
@@ -396,7 +404,7 @@ class BatchedScorer:
                     s.event.set()
         except BaseException as e:
             # every coalesced peer must see the real error, not None
-            for chunk, _ in launched:
+            for chunk, _, _ in launched:
                 for s in chunk:
                     if not s.event.is_set():
                         s.error = e

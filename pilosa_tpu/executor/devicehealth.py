@@ -38,7 +38,7 @@ from concurrent.futures import (
 )
 from typing import Callable, Optional
 
-from pilosa_tpu.utils import metrics
+from pilosa_tpu.utils import metrics, trace
 
 
 class DeviceDown(Exception):
@@ -122,6 +122,10 @@ class DeviceHealth:
             pool = self._pool
         timeout = timeout_s or self.timeout_s
         started = threading.Event()
+        # the guard pool is another thread: the caller's span, waterfall
+        # accumulator and wave id go with the call (not its deadline: a
+        # guarded call runs to its end as it did before the hand-over)
+        fn = trace.carried(fn)
 
         def run():
             started.set()
@@ -142,7 +146,9 @@ class DeviceHealth:
         # during a burst; the probe distinguishes the two cases cheaply:
         # only a failed probe condemns the device, a healthy one degrades
         # just this call to CPU.
-        if not started.wait(timeout=min(timeout, self.admission_timeout_s)):
+        with trace.leg(trace.WF_GUARD_QUEUE):
+            admitted = started.wait(timeout=min(timeout, self.admission_timeout_s))
+        if not admitted:
             fut.cancel()
             self.saturations += 1
             metrics.count(metrics.DEVICEHEALTH_SATURATIONS)

@@ -97,6 +97,7 @@ class _Item:
         "trace_ctx",
         "attrib",
         "wave_no",
+        "req",
     )
 
     def __init__(
@@ -121,6 +122,10 @@ class _Item:
         # item; result() merges them into the waiter's attribution ctx
         self.attrib: Optional[dict] = None
         self.wave_no = 0
+        # the submitter's request id: the wave's legs are annotated with
+        # it on the profiler's clock (a combined wave's with its head's)
+        d = trace.attrib_current()
+        self.req = d.get("_req", 0) if d else 0
 
     def finish(self, result=None, error=None) -> None:
         self.value = result
@@ -151,7 +156,8 @@ class _Item:
                 )
             if self.attrib:
                 for k, v in self.attrib.items():
-                    d[k] = d.get(k, 0.0) + v
+                    if k != "_req":
+                        d[k] = d.get(k, 0.0) + v
             if self.wave_no:
                 d["_wave"] = self.wave_no
         if self.error is not None:
@@ -433,7 +439,7 @@ class DispatchEngine:
         # measured inside (fenced device compute, transfer, stager, ...)
         # are apportioned to the members by call count — one wave, one
         # measurement, each waiter sees its share
-        measured: dict = {}
+        measured: dict = {"_req": head.req}
         try:
             with dm.activate(gang_dl), trace.attrib_activate(measured):
                 results = self.executor._execute(
@@ -446,11 +452,15 @@ class DispatchEngine:
         with self._mu:
             self.combined_items += len(leaders)
         total_calls = sum(it.n_calls for it in leaders) or 1
+        legs = {k: v for k, v in measured.items() if k != "_req"}
+        waited = sum(legs.values())
         off = 0
         for it in leaders:
-            if measured:
-                w = it.n_calls / total_calls
-                it.attrib = {k: v * w for k, v in measured.items()}
+            # a member waited through the whole wave; what was measured
+            # beyond its own share was its wave-mates' work
+            w = it.n_calls / total_calls
+            it.attrib = {k: v * w for k, v in legs.items()}
+            it.attrib[trace.WF_WAVE_MATES] = waited * (1.0 - w)
             it.finish(result=results[off : off + it.n_calls])
             off += it.n_calls
         return True
@@ -470,13 +480,13 @@ class DispatchEngine:
             metrics.count(metrics.PIPELINE_DEADLINE_EXPIRED, stage="dispatch")
             it.finish(error=dm.DeadlineExceeded("dispatch"))
             return
-        measured: dict = {}
+        measured: dict = {"_req": it.req}
         try:
             with dm.activate(it.deadline), trace.attrib_activate(measured):
                 result = self.executor._execute(
                     it.index, it.query, it.shards, it.opt
                 )
-            it.attrib = measured or None
+            it.attrib = measured
             it.finish(result=result)
         except BaseException as err:
             it.finish(error=err)

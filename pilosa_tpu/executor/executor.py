@@ -229,6 +229,7 @@ def _make_chain_scorer(ex: "Executor") -> BatchedScorer:
         single_fn=ex._chain_count_single,
         batch_fn=ex._chain_count_batch,
         pad_fn=lambda proto: proto,
+        kind=None,  # both are _timed_kernel-wrapped (tree_count*)
     )
 
 
@@ -245,6 +246,7 @@ def _make_stacked_scorer() -> BatchedScorer:
         batch_fn=lambda srcs, st: ops.sparse_intersection_counts_stacked_batch_list(
             srcs, *st
         ),
+        kind="topn_score_stacked",
     )
 
 
@@ -287,12 +289,13 @@ def _timed_kernel(kind: str, fn, signature=None, recovery=None):
         return out
 
     def run(*args, **kw):
-        t0 = time.monotonic()
-        if recovery is not None:
-            out = recovery.run(lambda: attempt(*args, **kw), kind=kind)
-        else:
-            out = attempt(*args, **kw)
-        dt = time.monotonic() - t0
+        profiler.count_operands(kind, args)
+        with trace.leg(trace.WF_DEVICE_COMPUTE) as lg:
+            if recovery is not None:
+                out = recovery.run(lambda: attempt(*args, **kw), kind=kind)
+            else:
+                out = attempt(*args, **kw)
+        dt = lg.seconds
         first = state["first"]
         if first:
             state["first"] = False
@@ -300,10 +303,9 @@ def _timed_kernel(kind: str, fn, signature=None, recovery=None):
             profiler.COMPILES.note(kind, signature, dt)
         else:
             metrics.observe(metrics.SPMD_EXECUTE_SECONDS, dt, kind=kind)
-        trace.attrib_add(trace.WF_DEVICE_COMPUTE, dt)
         sp = trace.current()
         if sp is not None:
-            sp.record(metrics.STAGE_SPMD_KERNEL, t0, dt, kind=kind, first=first)
+            sp.record(metrics.STAGE_SPMD_KERNEL, lg.t0, dt, kind=kind, first=first)
         return out
 
     return run
@@ -315,15 +317,40 @@ def _timed_kernel(kind: str, fn, signature=None, recovery=None):
 OOM_CPU_COOLDOWN_S = 30.0
 
 
+def _launch(kind: str, fn, *args, **kw):
+    """Launch a module-level jitted kernel whose small result the caller
+    reads at once, under its name, and return the result on the host:
+    launch → fetched is the request's device leg and
+    ``spmd.execute_seconds{kind}``, the operands count to
+    ``kernel.operand_bytes{kind}``. Wait and copy stay one step, as
+    ``np.asarray`` makes them (a count vector is a few KB): waiting
+    apart would hand the interpreter lock over once more per launch. A
+    first call's compile is in the time (``profiler.compiles{kind=xla}``
+    counts it)."""
+    profiler.count_operands(kind, args)
+    with trace.leg(trace.WF_DEVICE_COMPUTE) as lg:
+        out = fn(*args, **kw)
+        if isinstance(out, tuple):
+            out = tuple(np.asarray(o) for o in out)
+        else:
+            out = np.asarray(out)
+    metrics.observe(metrics.SPMD_EXECUTE_SECONDS, lg.seconds, kind=kind)
+    return out
+
+
 def _fetch(arr) -> np.ndarray:
-    """Materialize a device result on host, crediting the D2H
-    transfer+decode waterfall leg when attribution is active."""
+    """Materialize a device result on host. With attribution active the
+    wait for a launch that nobody fenced is the device's leg and the
+    copy alone is transfer.decode; a result that is ready (every
+    ``_timed_kernel``'s) is only copied."""
     if trace.attrib_current() is None:
         return np.asarray(arr)
-    t0 = time.monotonic()
-    out = np.asarray(arr)
-    trace.attrib_add(trace.WF_TRANSFER_DECODE, time.monotonic() - t0)
-    return out
+    is_ready = getattr(arr, "is_ready", None)
+    if is_ready is not None and not is_ready():
+        with trace.leg(trace.WF_DEVICE_COMPUTE):
+            arr.block_until_ready()
+    with trace.leg(trace.WF_TRANSFER_DECODE):
+        return np.asarray(arr)
 
 
 class Executor:
@@ -679,12 +706,10 @@ class Executor:
             # execution only: __cached placeholders never serialize.
             from pilosa_tpu.plan import planner
 
-            t0_cse = time.monotonic()
-            with trace.child(metrics.STAGE_PLAN_CANON):
+            with trace.leg(trace.WF_PLAN_CANON), trace.child(metrics.STAGE_PLAN_CANON):
                 calls = planner.rewrite_for_cse(
                     self, index_name, query.calls, shards, opt
                 )
-            trace.attrib_add(trace.WF_PLAN_CANON, time.monotonic() - t0_cse)
         # whole-query fusion (fusion.py): lower the fusable calls of a
         # multi-call read into ONE jitted launch; residual calls fall
         # through to the per-call paths below and results merge
@@ -715,12 +740,12 @@ class Executor:
             # scoring into batched kernel launches — the intra-request
             # form of continuous micro-batching.
             pool = self._read_pool_acquire()
-            parent = trace.current()  # contextvars don't follow pool workers
-            pdl = dl  # nor does the request deadline
-            attrib = trace.attrib_current()  # nor the waterfall accumulator
 
+            # contextvars don't follow pool workers: the span, waterfall
+            # accumulator and wave id are carried, the deadline re-entered
+            @trace.carried
             def run_call(call):
-                with trace.activate(parent), _deadline().activate(pdl), trace.attrib_activate(attrib):
+                with _deadline().activate(dl):
                     return self._execute_call(index_name, call, shards, opt)
 
             if pool is None:
@@ -870,10 +895,8 @@ class Executor:
         )
         try:
             if guarded:
-                # the guard pool is another thread: hand the span over
-                parent = trace.current()
                 return self.health.guard(
-                    lambda: self._execute_call_inner_on(parent, index, c, shards, opt)
+                    lambda: self._execute_call_inner(index, c, shards, opt)
                 )
             return self._execute_call_inner(index, c, shards, opt)
         except DeviceDown:
@@ -898,10 +921,6 @@ class Executor:
                 pass
             metrics.count(metrics.EXECUTOR_DEVICE_DOWN_FALLBACK)
         return self._execute_call_inner(index, c, shards, opt)
-
-    def _execute_call_inner_on(self, parent, index, c, shards, opt) -> Any:
-        with trace.activate(parent):
-            return self._execute_call_inner(index, c, shards, opt)
 
     def _execute_call_inner(self, index, c: Call, shards, opt) -> Any:
         name = c.name
@@ -987,11 +1006,8 @@ class Executor:
             elif attrib is None:
                 result = reduce_fn(result, v)
             else:
-                t0r = time.monotonic()
-                result = reduce_fn(result, v)
-                attrib[trace.WF_REDUCE] = attrib.get(trace.WF_REDUCE, 0.0) + (
-                    time.monotonic() - t0r
-                )
+                with trace.leg(trace.WF_REDUCE):
+                    result = reduce_fn(result, v)
         return result
 
     def _heat_read_legs(self, index, c, shards) -> None:
@@ -1533,9 +1549,14 @@ class Executor:
         key = repr(tree)
         fn = self._tree_jits.get(key)
         if fn is None:
+
+            @jax.named_scope("tree_count")
+            def run(*ls):
+                return ops.count_bits(_eval_tree(tree, ls))[None]
+
             fn = _timed_kernel(
                 "tree_count",
-                jax.jit(lambda *ls: ops.count_bits(_eval_tree(tree, ls))[None]),
+                jax.jit(run),
                 signature=key,
                 recovery=self._oom,
             )
@@ -1557,6 +1578,7 @@ class Executor:
         fn = self._tree_batch_jits.get(key)
         if fn is None:
 
+            @jax.named_scope("tree_count_batch")
             def run(*flat):
                 stacked = tuple(
                     jnp.stack([flat[k * nleaves + l] for k in range(q)])
@@ -1875,10 +1897,13 @@ class Executor:
                 try:
                     filt, has_filter = self._device_filter(index, c, shard)
                     planes = self.stager.planes(frag, depth)
-                    counts = _fetch(
-                        ops.bsi_plane_counts(
-                            planes, filt, bit_depth=depth, has_filter=has_filter
-                        )
+                    counts = _launch(
+                        "bsi_sum",
+                        ops.bsi_plane_counts,
+                        planes,
+                        filt,
+                        bit_depth=depth,
+                        has_filter=has_filter,
                     )
                     vsum = sum(int(counts[i]) << i for i in range(depth))
                     vcount = int(counts[depth])
@@ -1910,10 +1935,13 @@ class Executor:
                 self._spmd_kernel("plane_counts", depth, has_filter)(planes, filt)
             )
         else:
-            counts = _fetch(
-                ops.bsi_plane_counts_batched(
-                    planes, filt, bit_depth=depth, has_filter=has_filter
-                )
+            counts = _launch(
+                "bsi_sum",
+                ops.bsi_plane_counts_batched,
+                planes,
+                filt,
+                bit_depth=depth,
+                has_filter=has_filter,
             )
         vsum = sum(int(counts[i]) << i for i in range(depth))
         vcount = int(counts[depth])
@@ -1949,14 +1977,18 @@ class Executor:
                 try:
                     filt, has_filter = self._device_filter(index, c, shard)
                     planes = self.stager.planes(frag, depth)
-                    kernel = ops.bsi_min if is_min else ops.bsi_max
-                    bits, count = kernel(
-                        planes, filt, bit_depth=depth, has_filter=has_filter
+                    bits, count = _launch(
+                        "bsi_min" if is_min else "bsi_max",
+                        ops.bsi_min if is_min else ops.bsi_max,
+                        planes,
+                        filt,
+                        bit_depth=depth,
+                        has_filter=has_filter,
                     )
                     count = int(count)
                     if count == 0:
                         return ValCount()
-                    val = sum(1 << i for i, b in enumerate(_fetch(bits)) if b)
+                    val = sum(1 << i for i, b in enumerate(bits) if b)
                     return ValCount(val + bsig.min, count)
                 except _NotDeviceable:
                     pass
@@ -2060,7 +2092,7 @@ class Executor:
         metrics.count(metrics.FUSION_GROUPBY_LAUNCHES)
         metrics.observe(metrics.FUSION_GROUPBY_GROUPS, k)
         if plan.agg_field is None:
-            counts = _fetch(ops.groupby_counts(tuple(dim_stacks), filt))
+            counts = _launch("groupby_counts", ops.groupby_counts, tuple(dim_stacks), filt)
             return analytics.emit_device_groups(dims, counts)
         f = self.holder.field(index, plan.agg_field)
         bsig = f.bsi_group(plan.agg_field) if f is not None else None
@@ -2074,18 +2106,18 @@ class Executor:
             for s in shards
         )
         if not any(afrags):
-            counts = _fetch(ops.groupby_counts(tuple(dim_stacks), filt))
+            counts = _launch("groupby_counts", ops.groupby_counts, tuple(dim_stacks), filt)
             return analytics.emit_device_groups(
                 dims, counts, sums=[0] * int(counts.shape[0])
             )
         planes = jnp.transpose(
             self.stager.planes_stack(afrags, depth), (1, 0, 2)
         ).reshape(depth + 1, wf)
-        counts, plane_counts = ops.groupby_sum_reduce(
-            tuple(dim_stacks), filt, planes
+        counts, plane_counts = _launch(
+            "groupby_sum", ops.groupby_sum_reduce, tuple(dim_stacks), filt, planes
         )
-        sums = analytics.assemble_sums(_fetch(plane_counts), depth, bsig.min)
-        return analytics.emit_device_groups(dims, _fetch(counts), sums=sums)
+        sums = analytics.assemble_sums(plane_counts, depth, bsig.min)
+        return analytics.emit_device_groups(dims, counts, sums=sums)
 
     def _execute_distinct(self, index, c: Call, shards, opt) -> list[int]:
         field, ok = c.string_arg("field")
@@ -2147,10 +2179,13 @@ class Executor:
             filt = np.zeros((len(shards), _W32), dtype=np.uint32)
             has_filter = False
         planes = self.stager.planes_stack(frags, depth)
-        words = _fetch(
-            ops.bsi_distinct_presence(
-                planes, filt, bit_depth=depth, has_filter=has_filter
-            )
+        words = _launch(
+            "bsi_distinct",
+            ops.bsi_distinct_presence,
+            planes,
+            filt,
+            bit_depth=depth,
+            has_filter=has_filter,
         )
         return analytics.decode_presence_words(words, bsig.min)
 
@@ -2204,13 +2239,19 @@ class Executor:
             filt = np.zeros((len(shards), _W32), dtype=np.uint32)
             has_filter = False
         planes = self.stager.planes_stack(frags, depth)
-        bits, count = ops.bsi_percentile_batched(
-            planes, filt, np.int32(nth_bp), bit_depth=depth, has_filter=has_filter
+        bits, count = _launch(
+            "bsi_percentile",
+            ops.bsi_percentile_batched,
+            planes,
+            filt,
+            np.int32(nth_bp),
+            bit_depth=depth,
+            has_filter=has_filter,
         )
         count = int(count)
         if count == 0:
             return ValCount()
-        val = sum(1 << i for i, b in enumerate(_fetch(bits)) if b)
+        val = sum(1 << i for i, b in enumerate(bits) if b)
         return ValCount(val + bsig.min, count)
 
     def _percentile_by_counting(
@@ -2250,25 +2291,29 @@ class Executor:
     def _execute_topn(
         self, index, c: Call, shards, opt, prescored=None
     ) -> list[dict]:
-        ids_arg, _ = c.uint_slice_arg("ids")
-        n, _ = c.uint_arg("n")
-        # (shard, row_id) -> exact intersection count, filled by pass 1's
-        # scoring dispatches and consulted by pass 2: on skewed data the
-        # winning ids sit in every shard's cache head, so pass 2 usually
-        # needs no device round-trip at all
-        carry = _ScoreCarry()
-        pairs = self._execute_topn_shards(
-            index, c, shards, opt, carry, prescored=prescored
-        )
-        if not pairs or ids_arg or opt.remote:
-            return _pairs_result(pairs)
-        # Pass 2: re-query the union of candidate ids for exact counts.
-        other = c.clone()
-        other.args["ids"] = sorted(p[0] for p in pairs)
-        trimmed = self._execute_topn_shards(index, other, shards, opt, carry)
-        if n and n < len(trimmed):
-            trimmed = trimmed[:n]
-        return _pairs_result(trimmed)
+        # topn.walk is what the call spends outside its inner legs
+        # (candidates, staging, the device's wait, the fetch): the ranked
+        # walk, the cross-shard merge, the sorts, the pass-2 trim
+        with trace.leg(trace.WF_TOPN_WALK):
+            ids_arg, _ = c.uint_slice_arg("ids")
+            n, _ = c.uint_arg("n")
+            # (shard, row_id) -> exact intersection count, filled by pass
+            # 1's scoring dispatches and consulted by pass 2: on skewed
+            # data the winning ids sit in every shard's cache head, so
+            # pass 2 usually needs no device round-trip at all
+            carry = _ScoreCarry()
+            pairs = self._execute_topn_shards(
+                index, c, shards, opt, carry, prescored=prescored
+            )
+            if not pairs or ids_arg or opt.remote:
+                return _pairs_result(pairs)
+            # Pass 2: re-query the union of candidate ids for exact counts.
+            other = c.clone()
+            other.args["ids"] = sorted(p[0] for p in pairs)
+            trimmed = self._execute_topn_shards(index, other, shards, opt, carry)
+            if n and n < len(trimmed):
+                trimmed = trimmed[:n]
+            return _pairs_result(trimmed)
 
     def _execute_topn_shards(
         self, index, c: Call, shards, opt, carry=None, prescored=None
@@ -2339,28 +2384,30 @@ class Executor:
                 self.holder.fragment(index, field, VIEW_STANDARD, s)
                 for s in shards
             )
-            pairs_by_shard = [
-                f._top_bitmap_pairs(row_ids) if f is not None else []
-                for f in frags
-            ]
+            with trace.leg(trace.WF_TOPN_CANDIDATES):
+                pairs_by_shard = [
+                    f._top_bitmap_pairs(row_ids) if f is not None else []
+                    for f in frags
+                ]
         if not any(pairs_by_shard):
             return []
         # lazy: a pass 2 fully covered by the carry never resolves the
         # source stack (no device re-fold of compound sources)
-        provider = _StackedLazyScores(
-            self,
-            frags,
-            pairs_by_shard,
-            (
-                srcs0
-                if prescored is not None
-                else lambda: self._device_bitmap_stack(
-                    index, c.children[0], shards
-                )
-            ),
-            shards=shards,
-            carry=carry,
-        )
+        with trace.leg(trace.WF_TOPN_CANDIDATES):  # seeds from the carry
+            provider = _StackedLazyScores(
+                self,
+                frags,
+                pairs_by_shard,
+                (
+                    srcs0
+                    if prescored is not None
+                    else lambda: self._device_bitmap_stack(
+                        index, c.children[0], shards
+                    )
+                ),
+                shards=shards,
+                carry=carry,
+            )
         if prescored is not None:
             # inject the fused head as chunk 0; the walk continues from
             # _chunk_size(FIRST_CHUNK) exactly as the unfused schedule
@@ -2420,23 +2467,26 @@ class Executor:
         frags = tuple(
             self.holder.fragment(index, field, VIEW_STANDARD, s) for s in batch
         )
-        pairs_by_shard = [
-            f._top_bitmap_pairs(row_ids) if f is not None else [] for f in frags
-        ]
+        with trace.leg(trace.WF_TOPN_CANDIDATES):
+            pairs_by_shard = [
+                f._top_bitmap_pairs(row_ids) if f is not None else []
+                for f in frags
+            ]
         if not any(pairs_by_shard):
             return []
         # carry-seeded provider: pass 2's id subset was scored by pass 1
         # (same source, same fragment snapshot), so a fully-covered
         # second pass dispatches nothing — not even the source stack
         # (srcs is a thunk resolved on first chunk dispatch)
-        provider = _SpmdLazyScores(
-            self,
-            frags,
-            pairs_by_shard,
-            lambda: self._device_bitmap_stack(index, c.children[0], batch),
-            shards=batch,
-            carry=carry,
-        )
+        with trace.leg(trace.WF_TOPN_CANDIDATES):
+            provider = _SpmdLazyScores(
+                self,
+                frags,
+                pairs_by_shard,
+                lambda: self._device_bitmap_stack(index, c.children[0], batch),
+                shards=batch,
+                carry=carry,
+            )
         opt_ = TopOptions(
             n=int(n),
             src=None,
@@ -2497,14 +2547,16 @@ class Executor:
         """Device-accelerated TopN: batch all candidate intersection counts
         into one matrix kernel pass, then replay the reference's ranked
         walk on the precomputed scores (bit-identical outputs)."""
-        pairs = frag._top_bitmap_pairs(opt_.row_ids)
+        with trace.leg(trace.WF_TOPN_CANDIDATES):
+            pairs = frag._top_bitmap_pairs(opt_.row_ids)
         if not pairs:
             return []
         try:
             src_words = self._device_bitmap(index, c.children[0], shard)
         except _NotDeviceable:
             return frag.top(opt_)
-        scores = _LazyScores(self, frag, pairs, src_words, shard=shard, carry=carry)
+        with trace.leg(trace.WF_TOPN_CANDIDATES):
+            scores = _LazyScores(self, frag, pairs, src_words, shard=shard, carry=carry)
         return _ranked_walk(frag, opt_, pairs, scores)
 
     # -- writes (reference executor.go:998-1258) -----------------------------
@@ -2817,7 +2869,8 @@ class _ChunkedLazyScores:
         size = _chunk_size(lo)
         hi = lo + size
         self._pos = hi
-        ids_by_shard = tuple(_chunk_ids(ps, lo, hi) for ps in self._pairs)
+        with trace.leg(trace.WF_TOPN_CANDIDATES):
+            ids_by_shard = tuple(_chunk_ids(ps, lo, hi) for ps in self._pairs)
         staged = self._stage(ids_by_shard, size)
         # overlap: while this chunk's kernel runs + fetches, pre-stage
         # the NEXT chunk on a side thread (the stager memoizes by
@@ -2829,7 +2882,8 @@ class _ChunkedLazyScores:
         # re-introduce exactly the cold-staging cost the small head
         # chunk was measured to avoid (class docstring).
         if lo > 0 and hi < self._max_len:
-            self._prefetch(hi)
+            with trace.leg(trace.WF_TOPN_CANDIDATES):
+                self._prefetch(hi)
         if staged is None:  # no shard contributed blocks — all score 0
             mat = np.zeros((len(self._frags), size), dtype=np.int32)
         else:
@@ -2856,23 +2910,24 @@ class _ChunkedLazyScores:
         k = len(self._mats)
         if self._mat_cache is not None and self._mat_cache[0] == k:
             return self._mat_cache[1]
-        S = len(self._frags)
-        smat = (
-            np.concatenate(self._mats, axis=1) if k > 1 else self._mats[0]
-        )
-        P = smat.shape[1]
-        idm = np.full((S, P), -1, dtype=np.int64)
-        cntm = np.zeros((S, P), dtype=np.int64)
-        col = 0
-        for (lo, size, ids_by_shard), m in zip(self._chunk_meta, self._mats):
-            for i, ids in enumerate(ids_by_shard):
-                L = len(ids)
-                if L:
-                    a_ids, a_cnts = _chunk_arrays(self._pairs[i], lo, lo + L)
-                    idm[i, col : col + L] = a_ids
-                    cntm[i, col : col + L] = a_cnts
-            col += size
-        out = (smat, idm, cntm, idm >= 0)
+        with trace.leg(trace.WF_TOPN_CANDIDATES):
+            S = len(self._frags)
+            smat = (
+                np.concatenate(self._mats, axis=1) if k > 1 else self._mats[0]
+            )
+            P = smat.shape[1]
+            idm = np.full((S, P), -1, dtype=np.int64)
+            cntm = np.zeros((S, P), dtype=np.int64)
+            col = 0
+            for (lo, size, ids_by_shard), m in zip(self._chunk_meta, self._mats):
+                for i, ids in enumerate(ids_by_shard):
+                    L = len(ids)
+                    if L:
+                        a_ids, a_cnts = _chunk_arrays(self._pairs[i], lo, lo + L)
+                        idm[i, col : col + L] = a_ids
+                        cntm[i, col : col + L] = a_cnts
+                col += size
+            out = (smat, idm, cntm, idm >= 0)
         self._mat_cache = (k, out)
         return out
 
@@ -3034,10 +3089,11 @@ class _LazyScores:
         # ids materialise per chunk, never as one huge tuple — on a 50k-
         # candidate cache only the chunks the walk reaches pay anything
         size = _chunk_size(self._next)
-        ids = _chunk_ids(self._pairs, self._next, self._next + size)
-        self._next += size
         frag = self._frag
-        occupied = frag.sparse_block_count(list(ids))
+        with trace.leg(trace.WF_TOPN_CANDIDATES):
+            ids = _chunk_ids(self._pairs, self._next, self._next + size)
+            occupied = frag.sparse_block_count(list(ids))
+        self._next += size
         if occupied * 2 < len(ids) * (SHARD_WIDTH >> 16):
             blocks, brow, bslot, num_rows = self._ex.stager.sparse_rows(frag, ids)
             dev = ops.sparse_intersection_counts(
@@ -3234,11 +3290,10 @@ def _ranked_walk(frag, opt_: TopOptions, pairs, score_by_id) -> list[tuple[int, 
 
 
 def _row_from_device(words, shard: int) -> Row:
-    t0 = time.monotonic()
-    w32 = np.asarray(words)
-    w64 = np.ascontiguousarray(w32).view("<u8")
-    seg = Bitmap.from_words_range(w64, start=shard * SHARD_WIDTH)
-    trace.attrib_add(trace.WF_TRANSFER_DECODE, time.monotonic() - t0)
+    w32 = _fetch(words)
+    with trace.leg(trace.WF_TRANSFER_DECODE):
+        w64 = np.ascontiguousarray(w32).view("<u8")
+        seg = Bitmap.from_words_range(w64, start=shard * SHARD_WIDTH)
     return Row.from_segment(shard, seg)
 
 

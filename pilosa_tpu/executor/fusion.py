@@ -202,13 +202,9 @@ class QueryFuser:
             lower.append((i, c))
         if not lower:
             return out
-        parent = trace.current()
-        attrib = trace.attrib_current()
 
         def fused():
-            # guard-pool thread: hand over span + waterfall accumulator
-            with trace.activate(parent), trace.attrib_activate(attrib):
-                return self._lower_and_launch(index, lower, shards, opt)
+            return self._lower_and_launch(index, lower, shards, opt)
 
         if ex.health is not None:
             served = ex.health.guard(fused)
@@ -562,13 +558,17 @@ class QueryFuser:
         frags = tuple(
             ex.holder.fragment(index, field, VIEW_STANDARD, s) for s in shards
         )
-        pairs_by_shard = [
-            f._top_bitmap_pairs(row_ids) if f is not None else [] for f in frags
-        ]
+        size = FIRST_CHUNK
+        with trace.leg(trace.WF_TOPN_CANDIDATES):
+            pairs_by_shard = [
+                f._top_bitmap_pairs(row_ids) if f is not None else []
+                for f in frags
+            ]
+            ids_by_shard = tuple(
+                _chunk_ids(ps, 0, size) for ps in pairs_by_shard
+            )
         if not any(pairs_by_shard):
             return None  # classic path answers [] with no device work
-        size = FIRST_CHUNK
-        ids_by_shard = tuple(_chunk_ids(ps, 0, size) for ps in pairs_by_shard)
         srcs = ex._device_bitmap_stack(index, c.children[0], shards)
         staged = ex.stager.sparse_rows_stacked(frags, ids_by_shard, size)
         n_shards = len(shards)
@@ -649,87 +649,84 @@ class QueryFuser:
 
 def _build_program(descs: tuple):
     """The traced body of one fused query: consumes the flat input list
-    by per-unit offset and returns one output per unit. Pure — traced
-    under jax.jit, so no host effects (lint: jit-purity)."""
+    by per-unit offset and returns one output per unit, each unit traced
+    under ``jax.named_scope("fused/<unit kind>")`` so its operations
+    carry its name in a device trace. Pure — traced under jax.jit, so
+    no host effects (lint: jit-purity)."""
 
     def run(*flat):
+        import jax
+
         outs = []
         off = 0
         for d in descs:
-            kind = d[0]
-            if kind == "count":
-                tree, nleaves = d[1], d[2]
-                leaves = flat[off : off + nleaves]
-                off += nleaves
-                outs.append(ops.count_bits(_ex._eval_tree(tree, leaves))[None])
-            elif kind == "sum":
-                depth, has_filter = d[1], d[2]
-                planes, filt = flat[off], flat[off + 1]
-                off += 2
-                outs.append(
-                    ops.bsi_plane_counts_batched(
-                        planes, filt, bit_depth=depth, has_filter=has_filter
-                    )
-                )
-            elif kind in ("groupby_count", "groupby_sum"):
-                import jax.numpy as jnp
-
-                rcounts, has_filter = d[1], d[2]
-                nd = len(rcounts)
-                dims = tuple(flat[off : off + nd])
-                off += nd
-                filt = None
-                if has_filter:
-                    filt = flat[off]
-                    off += 1
-                if kind == "groupby_count":
-                    outs.append(ops.groupby_counts(dims, filt))
-                else:
-                    planes = flat[off]
-                    off += 1
-                    counts, pc = ops.groupby_sum_reduce(dims, filt, planes)
-                    # one output per unit: [K, depth+2] with the group
-                    # popcounts in column 0, plane counts after
-                    outs.append(jnp.concatenate([counts[:, None], pc], axis=1))
-            elif kind == "distinct":
-                depth, has_filter = d[1], d[2]
-                planes, filt = flat[off], flat[off + 1]
-                off += 2
-                outs.append(
-                    ops.bsi_distinct_presence(
-                        planes, filt, bit_depth=depth, has_filter=has_filter
-                    )
-                )
-            elif kind == "percentile":
-                import jax.numpy as jnp
-
-                depth, has_filter = d[1], d[2]
-                planes, filt, nth = flat[off : off + 3]
-                off += 3
-                bits, count = ops.bsi_percentile_batched(
-                    planes, filt, nth, bit_depth=depth, has_filter=has_filter
-                )
-                outs.append(
-                    jnp.concatenate(
-                        [bits.astype(jnp.int32), count[None].astype(jnp.int32)]
-                    )
-                )
-            else:  # topn head-chunk scoring
-                num_rows, n_shards, chunk = d[1], d[2], d[3]
-                srcs, blocks, brow, bslot, bshard = flat[off : off + 5]
-                off += 5
-                outs.append(
-                    ops.sparse_intersection_counts_stacked_mat(
-                        srcs,
-                        blocks,
-                        brow,
-                        bslot,
-                        bshard,
-                        num_rows=num_rows,
-                        n_shards=n_shards,
-                        chunk=chunk,
-                    )
-                )
+            with jax.named_scope(f"fused/{d[0]}"):
+                out, off = _trace_unit(d, flat, off)
+            outs.append(out)
         return tuple(outs)
 
     return run
+
+
+def _trace_unit(d: tuple, flat: tuple, off: int):
+    """One unit of a fused program: (its output, the next offset)."""
+    import jax.numpy as jnp
+
+    kind = d[0]
+    if kind == "count":
+        tree, nleaves = d[1], d[2]
+        leaves = flat[off : off + nleaves]
+        return ops.count_bits(_ex._eval_tree(tree, leaves))[None], off + nleaves
+    if kind == "sum":
+        depth, has_filter = d[1], d[2]
+        planes, filt = flat[off], flat[off + 1]
+        out = ops.bsi_plane_counts_batched(
+            planes, filt, bit_depth=depth, has_filter=has_filter
+        )
+        return out, off + 2
+    if kind in ("groupby_count", "groupby_sum"):
+        rcounts, has_filter = d[1], d[2]
+        nd = len(rcounts)
+        dims = tuple(flat[off : off + nd])
+        off += nd
+        filt = None
+        if has_filter:
+            filt = flat[off]
+            off += 1
+        if kind == "groupby_count":
+            return ops.groupby_counts(dims, filt), off
+        counts, pc = ops.groupby_sum_reduce(dims, filt, flat[off])
+        # one output per unit: [K, depth+2] with the group popcounts in
+        # column 0, plane counts after
+        return jnp.concatenate([counts[:, None], pc], axis=1), off + 1
+    if kind == "distinct":
+        depth, has_filter = d[1], d[2]
+        planes, filt = flat[off], flat[off + 1]
+        out = ops.bsi_distinct_presence(
+            planes, filt, bit_depth=depth, has_filter=has_filter
+        )
+        return out, off + 2
+    if kind == "percentile":
+        depth, has_filter = d[1], d[2]
+        planes, filt, nth = flat[off : off + 3]
+        bits, count = ops.bsi_percentile_batched(
+            planes, filt, nth, bit_depth=depth, has_filter=has_filter
+        )
+        out = jnp.concatenate(
+            [bits.astype(jnp.int32), count[None].astype(jnp.int32)]
+        )
+        return out, off + 3
+    # topn head-chunk scoring
+    num_rows, n_shards, chunk = d[1], d[2], d[3]
+    srcs, blocks, brow, bslot, bshard = flat[off : off + 5]
+    out = ops.sparse_intersection_counts_stacked_mat(
+        srcs,
+        blocks,
+        brow,
+        bslot,
+        bshard,
+        num_rows=num_rows,
+        n_shards=n_shards,
+        chunk=chunk,
+    )
+    return out, off + 5

@@ -260,7 +260,10 @@ class DeviceStager:
         while True:
             fl = None
             stale: Optional[_Entry] = None
-            with self._mu:
+            # stager.lookup: the probe of every call, hit or miss — a
+            # content-derived key (a TopN chunk's: 64 tuples of up to
+            # 4,096 ids) is hashed again by each dict it is looked up in
+            with trace.leg(trace.WF_STAGER_LOOKUP), self._mu:
                 ent = self._cache.get(key)
                 if ent is not None and _gen_fresh(ent.gen, gen):
                     self._cache.move_to_end(key)
@@ -284,7 +287,8 @@ class DeviceStager:
                 else:
                     building = False
             if not building:
-                fl.event.wait()
+                with trace.leg(trace.WF_STAGER):  # another thread's build
+                    fl.event.wait()
                 if fl.error is not None:
                     raise fl.error
                 if fl.gen is None or _gen_fresh(fl.gen, gen):
@@ -300,38 +304,33 @@ class DeviceStager:
                     and delta_fn is not None
                     and self.delta_enabled
                 ):
-                    t0 = time.monotonic()
                     sp = trace.current()
-                    if sp is None:
-                        res = delta_fn(stale.value, stale.gen)
-                    else:
-                        with sp.child(metrics.STAGE_DELTA) as ssp:
+                    with trace.leg(trace.WF_STAGER) as lg:
+                        if sp is None:
                             res = delta_fn(stale.value, stale.gen)
-                            if res is not None:
-                                ssp.annotate(nupdates=res[2])
+                        else:
+                            with sp.child(metrics.STAGE_DELTA) as ssp:
+                                res = delta_fn(stale.value, stale.gen)
+                                if res is not None:
+                                    ssp.annotate(nupdates=res[2])
                     if res is not None:
                         value, built_gen, _n = res
                         nbytes = stale.nbytes  # delta never changes shape
                         self.delta_applies += 1
                         metrics.count(metrics.STAGER_DELTA_APPLIED)
                         metrics.observe(
-                            metrics.STAGER_DELTA_APPLY_SECONDS,
-                            time.monotonic() - t0,
+                            metrics.STAGER_DELTA_APPLY_SECONDS, lg.seconds
                         )
-                        trace.attrib_add(trace.WF_STAGER, time.monotonic() - t0)
                 if value is None:
-                    t0 = time.monotonic()
                     sp = trace.current()
-                    if sp is None:
-                        value, nbytes, built_gen = builder()
-                    else:
-                        with sp.child(metrics.STAGE_STAGE) as ssp:
+                    with trace.leg(trace.WF_STAGER) as lg:
+                        if sp is None:
                             value, nbytes, built_gen = builder()
-                            ssp.annotate(nbytes=nbytes)
-                    metrics.observe(
-                        metrics.STAGER_STAGE_SECONDS, time.monotonic() - t0
-                    )
-                    trace.attrib_add(trace.WF_STAGER, time.monotonic() - t0)
+                        else:
+                            with sp.child(metrics.STAGE_STAGE) as ssp:
+                                value, nbytes, built_gen = builder()
+                                ssp.annotate(nbytes=nbytes)
+                    metrics.observe(metrics.STAGER_STAGE_SECONDS, lg.seconds)
                     metrics.count(metrics.STAGER_MISSES)
                     self._heat_stage(frag, nbytes, False)
                     if stale is None:

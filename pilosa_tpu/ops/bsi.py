@@ -29,6 +29,7 @@ def _filtered_exists(planes, filter_row):
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth", "has_filter"))
+@jax.named_scope("bsi_sum")
 def bsi_plane_counts(planes, filter_row, *, bit_depth: int, has_filter: bool):
     """Per-plane intersection counts for Sum (reference fragment.sum:563-597).
 
@@ -44,6 +45,7 @@ def bsi_plane_counts(planes, filter_row, *, bit_depth: int, has_filter: bool):
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth", "has_filter"))
+@jax.named_scope("bsi_min")
 def bsi_min(planes, filter_row, *, bit_depth: int, has_filter: bool):
     """Min recurrence (reference fragment.min:599-630).
 
@@ -63,6 +65,7 @@ def bsi_min(planes, filter_row, *, bit_depth: int, has_filter: bool):
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth", "has_filter"))
+@jax.named_scope("bsi_max")
 def bsi_max(planes, filter_row, *, bit_depth: int, has_filter: bool):
     """Max recurrence (reference fragment.max:632-661)."""
     consider = _filtered_exists(planes, filter_row if has_filter else None)
@@ -82,6 +85,7 @@ def _pred_bit(predicate, i):
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth",))
+@jax.named_scope("bsi_range")
 def bsi_range_eq(planes, predicate, *, bit_depth: int):
     """EQ: keep columns whose every bit matches (reference rangeEQ:678-694)."""
     b = planes[-1]
@@ -93,6 +97,7 @@ def bsi_range_eq(planes, predicate, *, bit_depth: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth",))
+@jax.named_scope("bsi_range")
 def bsi_range_neq(planes, predicate, *, bit_depth: int):
     """NEQ = not-null minus EQ (reference rangeNEQ:696-710)."""
     eq = bsi_range_eq(planes, predicate, bit_depth=bit_depth)
@@ -100,6 +105,7 @@ def bsi_range_neq(planes, predicate, *, bit_depth: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth", "allow_equality"))
+@jax.named_scope("bsi_range")
 def bsi_range_lt(planes, predicate, *, bit_depth: int, allow_equality: bool):
     """LT / LTE keep-exclude recurrence (reference rangeLT:712-760).
 
@@ -144,6 +150,7 @@ def bsi_range_lt(planes, predicate, *, bit_depth: int, allow_equality: bool):
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth", "allow_equality"))
+@jax.named_scope("bsi_range")
 def bsi_range_gt(planes, predicate, *, bit_depth: int, allow_equality: bool):
     """GT / GTE recurrence (reference rangeGT:762-797)."""
     zero = jnp.zeros_like(planes[-1])
@@ -176,6 +183,7 @@ def bsi_range_gt(planes, predicate, *, bit_depth: int, allow_equality: bool):
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth",))
+@jax.named_scope("bsi_range")
 def bsi_range_between(planes, pred_min, pred_max, *, bit_depth: int):
     """BETWEEN (inclusive both ends) — fused GTE(min) ∧ LTE(max) recurrence
     (reference rangeBetween:806-840)."""
@@ -204,6 +212,7 @@ def bsi_range_between(planes, pred_min, pred_max, *, bit_depth: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth", "has_filter"))
+@jax.named_scope("bsi_sum")
 def bsi_plane_counts_batched(planes, filter_rows, *, bit_depth: int, has_filter: bool):
     """Shard-batched Sum: planes u32[S, D+1, W], filter u32[S, W] →
     i32[D+1] summed over shards in one dispatch."""
@@ -219,6 +228,7 @@ def bsi_plane_counts_batched(planes, filter_rows, *, bit_depth: int, has_filter:
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth", "has_filter"))
+@jax.named_scope("bsi_percentile")
 def bsi_percentile_batched(planes, filter_rows, nth_bp, *, bit_depth: int, has_filter: bool):
     """Shard-batched nearest-rank percentile as a bit-sliced binary
     search over the value planes (one launch for the whole shard set).
@@ -259,6 +269,7 @@ def bsi_percentile_batched(planes, filter_rows, nth_bp, *, bit_depth: int, has_f
 
 
 @functools.partial(jax.jit, static_argnames=("bit_depth", "has_filter"))
+@jax.named_scope("bsi_distinct")
 def bsi_distinct_presence(planes, filter_rows, *, bit_depth: int, has_filter: bool):
     """Distinct(field) as an OR-reduction over BSI planes with
     on-device id extraction: planes u32[S, D+1, W] → packed u32

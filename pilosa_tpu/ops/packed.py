@@ -91,6 +91,7 @@ def intersection_count(a, b) -> jax.Array:
 
 
 @jax.jit
+@jax.named_scope("topn_score_dense")
 def intersection_counts_matrix(src, mat) -> jax.Array:
     """TopN scoring kernel: popcount(src & row) for every row.
 
@@ -103,6 +104,7 @@ def intersection_counts_matrix(src, mat) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows",))
+@jax.named_scope("topn_score_sparse")
 def sparse_intersection_counts(src, blocks, block_row, block_slot, num_rows: int):
     """TopN scoring over block-sparse candidate rows.
 
@@ -126,6 +128,7 @@ def sparse_intersection_counts(src, blocks, block_row, block_slot, num_rows: int
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows",))
+@jax.named_scope("topn_score_stacked")
 def sparse_intersection_counts_stacked(
     srcs, blocks, block_row, block_slot, block_shard, num_rows: int
 ):
@@ -179,6 +182,7 @@ _BATCH_GROUP = 8  # queries scored per block-stream pass (footprint knob)
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows",))
+@jax.named_scope("topn_score_stacked")
 def sparse_intersection_counts_stacked_batch(
     srcs_q, blocks, block_row, block_slot, block_shard, num_rows: int
 ):
@@ -231,6 +235,7 @@ def sparse_intersection_counts_stacked_batch_list(
 
 
 @jax.jit
+@jax.named_scope("topn_score_dense")
 def intersection_counts_matrix_batch(srcs, mat) -> jax.Array:
     """Batched TopN scoring: popcount(src_q & row_r) for every (q, r).
 
@@ -285,6 +290,7 @@ def combine_groups(dims, filt):
 
 
 @jax.jit
+@jax.named_scope("groupby_counts")
 def groupby_counts(dims, filt):
     """Count-aggregate GroupBy: per-group popcounts i32[ΠR_d] in one
     dispatch (cross product + segmented popcount fused by XLA)."""
@@ -313,6 +319,7 @@ def groupby_plane_counts(groups, planes):
 
 
 @jax.jit
+@jax.named_scope("groupby_sum")
 def groupby_sum_reduce(dims, filt, planes):
     """Fused Sum-aggregate GroupBy: one dispatch yielding
     (counts i32[K], plane_counts i32[K, P]). counts[k] is the group's

@@ -76,6 +76,7 @@ def count_fold_spmd(mesh: Mesh):
     collective.
     """
 
+    @jax.named_scope("count_fold")
     def kernel(block):  # block: u32[s_local, K, W] per device
         folded = jax.lax.reduce(
             block, jnp.uint32(0xFFFFFFFF), jnp.bitwise_and, (1,)
@@ -104,6 +105,7 @@ def topn_spmd(mesh: Mesh, k: int):
     does.
     """
 
+    @jax.named_scope("topn")
     def kernel(src, mat):
         # per-device: src u32[s_local, W], mat u32[s_local, R, W]
         scores = jnp.sum(
@@ -141,6 +143,7 @@ def topn_batch_spmd(mesh: Mesh, k: int):
     -> (ids i32[Q, S*k], counts i32[Q, S*k]) replicated on every device.
     """
 
+    @jax.named_scope("topn_batch")
     def kernel(srcs, mat):
         # per-device: srcs u32[Q, W], mat u32[s_local, R, W].
         # lax.map over sources keeps the popcount intermediate at one
@@ -184,6 +187,7 @@ def count_stack_spmd(mesh: Mesh):
     uint64-sum reduceFn (executor.go:966-996) riding ICI.
     """
 
+    @jax.named_scope("count")
     def kernel(block):  # u32[s_local, W]
         local = jnp.sum(jax.lax.population_count(block).astype(jnp.int32))
         return jax.lax.psum(local, SHARD_AXIS)
@@ -215,6 +219,7 @@ def topn_scores_sparse_spmd(mesh: Mesh, k: int):
     """
     from pilosa_tpu.ops.packed import CONTAINER_WORDS
 
+    @jax.named_scope("topn_scores_sparse")
     def kernel(srcs, blocks, brow, bslot):
         # per-device: srcs u32[s_local, W], blocks u32[s_local, B, 2048]
         per_shard = srcs.reshape(srcs.shape[0], -1, CONTAINER_WORDS)
@@ -249,6 +254,7 @@ def bsi_sum_spmd(mesh: Mesh, bit_depth: int, has_filter: bool = True):
     with an all-ones mask.
     """
 
+    @jax.named_scope("plane_counts")
     def kernel(planes, filt):
         block = (
             jnp.bitwise_and(planes, filt[:, None, :]) if has_filter else planes
@@ -276,6 +282,7 @@ def row_algebra_spmd(mesh: Mesh, op: str):
 
     from pilosa_tpu.ops.packed import fold_rows
 
+    @jax.named_scope("row_algebra")
     def kernel(mat):  # u32[s_local, K, W]
         if op == "and":
             init, fn = jnp.uint32(0xFFFFFFFF), jnp.bitwise_and

@@ -101,6 +101,7 @@ class API:
         cache: bool = True,
         trace_ctx: Optional[tuple] = None,
         waterfall: bool = False,
+        req_id: int = 0,
     ) -> dict:
         self._validate("query")
         # deadline boundary: cancel BEFORE the parse — an expired
@@ -131,7 +132,9 @@ class API:
         # + float add per instrumented leg, no spans, no sampling gate.
         # Created HERE (not the HTTP thread) because pipeline thunks run
         # on worker threads where the handler's contextvars don't reach.
-        wf: dict = {}
+        # _req names the request in every leg's profiler annotation; the
+        # transport hands its own id down so its legs carry the same one
+        wf: dict = {"_req": req_id or trace.next_request_id()}
         t_q0 = time.monotonic()
         # an UNSAMPLED upstream context still propagates its ids to
         # dispatch items and outbound RPC headers, span-free
@@ -149,9 +152,8 @@ class API:
                         metrics.STAGE_PIPELINE_WAIT, root.t0 - wait, wait
                     )
             try:
-                t0p = time.monotonic()
-                q = parse(query)
-                wf[trace.WF_PLAN_CANON] = time.monotonic() - t0p
+                with trace.leg(trace.WF_PLAN_CANON):
+                    q = parse(query)
             except Exception as e:
                 raise APIError(f"parsing: {e}") from e
             idx = self.holder.index(index)

@@ -282,106 +282,110 @@ class Handler:
             return thunk()
 
     def post_query(self, req) -> dict:
-        index = req.params["index"]
-        q = req.query
-        # protobuf content negotiation (reference handlePostQuery:406 +
-        # internal/public.proto QueryRequest)
-        if req.is_proto:
-            pbreq = _decode_proto(publicproto.decode_query_request, req.body)
-            body = pbreq["query"]
-            shards = pbreq["shards"]
-            remote = pbreq["remote"]
-            exclude_row_attrs = pbreq["excludeRowAttrs"]
-            exclude_columns = pbreq["excludeColumns"]
-            column_attrs = pbreq["columnAttrs"]
-        else:
-            body = req.body.decode() if req.body else ""
-            shards = None
-            if "shards" in q:
-                shards = [int(s) for s in _qreq(q, "shards").split(",") if s != ""]
-            remote = q.get("remote", ["false"])[0] == "true"
-            exclude_row_attrs = q.get("excludeRowAttrs", ["false"])[0] == "true"
-            exclude_columns = q.get("excludeColumns", ["false"])[0] == "true"
-            column_attrs = q.get("columnAttrs", ["false"])[0] == "true"
-        # profile=true returns the span tree; profile=waterfall returns
-        # the per-stage latency split from the attribution layer
-        profile_raw = q.get("profile", ["false"])[0]
-        profile = profile_raw == "true"
-        waterfall = profile_raw == "waterfall"
-        cache = q.get("cache", ["true"])[0] != "false"
-        # W3C trace context ingress: a sampled traceparent makes this
-        # request a leg of a distributed trace (api.query adopts the
-        # id); malformed headers parse to None and never fail the query
-        trace_ctx = trace.parse_traceparent(req.headers.get("traceparent"))
-        # pipeline classification (pipeline.classify_query): remote legs
-        # are internal traffic; analytic bulk queries (GroupBy /
-        # Distinct / Percentile) run in the BULK class with their own
-        # default deadline budget (analytics-timeout), so a panel burst
-        # burns the bulk SLO instead of interactive p50; everything
-        # else is interactive. Read-only queries coalesce (singleflight)
-        # by CANONICAL plan signature (plan/canon.py) — argument-order-
-        # permuted duplicates like Intersect(Row(a),Row(b)) vs
-        # Intersect(Row(b),Row(a)) share one execution; unparseable
-        # text falls back to the raw bytes so syntax errors still 400
-        # individually. Plain whole-index reads additionally gang into
-        # combined cross-request executions.
-        cls = pipeline_mod.classify_query(body, remote)
-        default_t = self.default_timeout
-        if cls == CLASS_BULK and self.analytics_timeout > 0:
-            default_t = self.analytics_timeout
-        dl = deadline_mod.from_request(req.headers, q, default_t)
-        signature = None
-        batch = None
-        # waterfall requests skip cross-request coalescing/batching like
-        # profile: a follower served by a leader's execution would report
-        # the LEADER's split, not its own
-        if not remote and not profile and not waterfall and not _WRITE_CALL_RE.search(body):
-            from pilosa_tpu.plan.canon import query_signature
+        # admission: everything the transport does before the hand-off
+        rid = (trace.attrib_current() or {}).get("_req", 0)
+        with trace.leg(trace.WF_ADMISSION):
+            index = req.params["index"]
+            q = req.query
+            # protobuf content negotiation (reference handlePostQuery:406 +
+            # internal/public.proto QueryRequest)
+            if req.is_proto:
+                pbreq = _decode_proto(publicproto.decode_query_request, req.body)
+                body = pbreq["query"]
+                shards = pbreq["shards"]
+                remote = pbreq["remote"]
+                exclude_row_attrs = pbreq["excludeRowAttrs"]
+                exclude_columns = pbreq["excludeColumns"]
+                column_attrs = pbreq["columnAttrs"]
+            else:
+                body = req.body.decode() if req.body else ""
+                shards = None
+                if "shards" in q:
+                    shards = [int(s) for s in _qreq(q, "shards").split(",") if s != ""]
+                remote = q.get("remote", ["false"])[0] == "true"
+                exclude_row_attrs = q.get("excludeRowAttrs", ["false"])[0] == "true"
+                exclude_columns = q.get("excludeColumns", ["false"])[0] == "true"
+                column_attrs = q.get("columnAttrs", ["false"])[0] == "true"
+            # profile=true returns the span tree; profile=waterfall returns
+            # the per-stage latency split from the attribution layer
+            profile_raw = q.get("profile", ["false"])[0]
+            profile = profile_raw == "true"
+            waterfall = profile_raw == "waterfall"
+            cache = q.get("cache", ["true"])[0] != "false"
+            # W3C trace context ingress: a sampled traceparent makes this
+            # request a leg of a distributed trace (api.query adopts the
+            # id); malformed headers parse to None and never fail the query
+            trace_ctx = trace.parse_traceparent(req.headers.get("traceparent"))
+            # pipeline classification (pipeline.classify_query): remote legs
+            # are internal traffic; analytic bulk queries (GroupBy /
+            # Distinct / Percentile) run in the BULK class with their own
+            # default deadline budget (analytics-timeout), so a panel burst
+            # burns the bulk SLO instead of interactive p50; everything
+            # else is interactive. Read-only queries coalesce (singleflight)
+            # by CANONICAL plan signature (plan/canon.py) — argument-order-
+            # permuted duplicates like Intersect(Row(a),Row(b)) vs
+            # Intersect(Row(b),Row(a)) share one execution; unparseable
+            # text falls back to the raw bytes so syntax errors still 400
+            # individually. Plain whole-index reads additionally gang into
+            # combined cross-request executions.
+            cls = pipeline_mod.classify_query(body, remote)
+            default_t = self.default_timeout
+            if cls == CLASS_BULK and self.analytics_timeout > 0:
+                default_t = self.analytics_timeout
+            dl = deadline_mod.from_request(req.headers, q, default_t)
+            signature = None
+            batch = None
+            # waterfall requests skip cross-request coalescing/batching like
+            # profile: a follower served by a leader's execution would report
+            # the LEADER's split, not its own
+            if not remote and not profile and not waterfall and not _WRITE_CALL_RE.search(body):
+                from pilosa_tpu.plan.canon import query_signature
 
-            canon_sig = query_signature(body)
-            signature = (
-                "q",
-                index,
-                canon_sig if canon_sig is not None else body,
-                tuple(shards) if shards is not None else None,
-                exclude_row_attrs,
-                exclude_columns,
-                column_attrs,
-                cache,
-            )
-            # sampled-trace requests stay out of cross-request batching
-            # (a combined execution has no per-request span tree); they
-            # still coalesce — the follower records a span link
-            if (
-                shards is None
-                and not column_attrs
-                and not (trace_ctx is not None and trace_ctx[2])
-            ):
-                batch = {
-                    "key": (index, exclude_row_attrs, exclude_columns, cache),
-                    "index": index,
-                    "query": body,
-                    "kwargs": {
-                        "exclude_row_attrs": exclude_row_attrs,
-                        "exclude_columns": exclude_columns,
-                        "cache": cache,
-                    },
-                }
+                canon_sig = query_signature(body)
+                signature = (
+                    "q",
+                    index,
+                    canon_sig if canon_sig is not None else body,
+                    tuple(shards) if shards is not None else None,
+                    exclude_row_attrs,
+                    exclude_columns,
+                    column_attrs,
+                    cache,
+                )
+                # sampled-trace requests stay out of cross-request batching
+                # (a combined execution has no per-request span tree); they
+                # still coalesce — the follower records a span link
+                if (
+                    shards is None
+                    and not column_attrs
+                    and not (trace_ctx is not None and trace_ctx[2])
+                ):
+                    batch = {
+                        "key": (index, exclude_row_attrs, exclude_columns, cache),
+                        "index": index,
+                        "query": body,
+                        "kwargs": {
+                            "exclude_row_attrs": exclude_row_attrs,
+                            "exclude_columns": exclude_columns,
+                            "cache": cache,
+                        },
+                    }
 
-        def thunk():
-            return self.api.query(
-                index,
-                body,
-                shards=shards,
-                remote=remote,
-                exclude_row_attrs=exclude_row_attrs,
-                exclude_columns=exclude_columns,
-                column_attrs=column_attrs,
-                profile=profile,
-                cache=cache,
-                trace_ctx=trace_ctx,
-                waterfall=waterfall,
-            )
+            def thunk():
+                return self.api.query(
+                    index,
+                    body,
+                    shards=shards,
+                    remote=remote,
+                    exclude_row_attrs=exclude_row_attrs,
+                    exclude_columns=exclude_columns,
+                    column_attrs=column_attrs,
+                    profile=profile,
+                    cache=cache,
+                    trace_ctx=trace_ctx,
+                    waterfall=waterfall,
+                    req_id=rid,
+                )
 
         t0 = time.monotonic()
         try:
@@ -413,34 +417,50 @@ class Handler:
         slo.MONITOR.record(cls, dur, ok=True)
         if self.tenancy is not None and cls != CLASS_INTERNAL:
             self.tenancy.observe(index, dur, ok=True)
-        # always-on waterfall: api.query attaches the summary; pop it
-        # (shared dicts from coalesced responses aggregate only once)
-        wf_summary = resp.pop("_waterfall", None)
-        if wf_summary is not None:
-            profiler.WATERFALL.record_summary(cls, wf_summary, tenant=index)
-        # slow-query logging (reference handler.go:257-261)
-        if self.long_query_time and dur > self.long_query_time and self.logger:
-            self.logger.printf("%.3fs SLOW QUERY %s %s", dur, index, body[:500])
-            self.stats.count(metrics.SLOW_QUERY, 1)
-        self.stats.with_tags(f"index:{index}").timing(metrics.QUERY_TIME, dur)
-        out = {"results": [encode_result(r) for r in resp["results"]]}
-        if "columnAttrs" in resp:
-            out["columnAttrs"] = resp["columnAttrs"]
-        if "profile" in resp:
-            # JSON-only: the protobuf QueryResponse has no profile field
-            out["profile"] = resp["profile"]
-        if "spans" in resp:
-            # remote-leg envelope: this process's serialized spans ride
-            # back so the root process stitches one complete tree
-            out["spans"] = resp["spans"]
-        if req.accepts_proto:
-            return RawResponse(
-                publicproto.encode_query_response(
-                    out["results"], out.get("columnAttrs")
-                ),
-                publicproto.CONTENT_TYPE,
-            )
-        return out
+        with trace.leg(trace.WF_RESPOND):
+            # always-on waterfall: api.query attaches the summary; pop it
+            # (shared dicts from coalesced responses aggregate only once)
+            wf_summary = resp.pop("_waterfall", None)
+            if wf_summary is not None:
+                self._record_waterfall(cls, wf_summary, index)
+            # slow-query logging (reference handler.go:257-261)
+            if self.long_query_time and dur > self.long_query_time and self.logger:
+                self.logger.printf("%.3fs SLOW QUERY %s %s", dur, index, body[:500])
+                self.stats.count(metrics.SLOW_QUERY, 1)
+            self.stats.with_tags(f"index:{index}").timing(metrics.QUERY_TIME, dur)
+            out = {"results": [encode_result(r) for r in resp["results"]]}
+            if "columnAttrs" in resp:
+                out["columnAttrs"] = resp["columnAttrs"]
+            if "profile" in resp:
+                # JSON-only: the protobuf QueryResponse has no profile field
+                out["profile"] = resp["profile"]
+            if "spans" in resp:
+                # remote-leg envelope: this process's serialized spans ride
+                # back so the root process stitches one complete tree
+                out["spans"] = resp["spans"]
+            if req.accepts_proto:
+                return RawResponse(
+                    publicproto.encode_query_response(
+                        out["results"], out.get("columnAttrs")
+                    ),
+                    publicproto.CONTENT_TYPE,
+                )
+            return out
+
+    @staticmethod
+    def _record_waterfall(cls: str, summary: dict, index: str) -> None:
+        """Aggregate one query's waterfall. Under the HTTP transport the
+        request's ``admission`` joins the summary now (``profile=
+        waterfall`` shows it) and the record waits for ``respond``,
+        which ends with the last write (``_Req._run``); a caller with no
+        transport around it (``Handler.handle`` direct) records here."""
+        transport = trace.attrib_current()
+        if transport is None:
+            profiler.WATERFALL.record_summary(cls, summary, tenant=index)
+            return
+        admission = transport.pop(trace.WF_ADMISSION, 0.0)
+        profiler.WATERFALL.extend(summary, {trace.WF_ADMISSION: admission})
+        transport["_record"] = (cls, summary, index)
 
     def get_index(self, req) -> dict:
         for ischema in self.api.schema():
@@ -1088,7 +1108,8 @@ class Handler:
         """Continuous-profiler surface: stack-sampler top frames,
         per-signature compile table, HBM telemetry, and on-demand
         ``jax.profiler`` capture control (``?capture=start&dir=<path>``
-        / ``?capture=stop``). ``?top=<n>`` sizes the tables."""
+        / ``?capture=stop``; ``&python=1`` turns the Python tracer on,
+        which slows the host it observes). ``?top=<n>`` sizes the tables."""
         q = req.query
         try:
             top = int(q.get("top", ["25"])[0])
@@ -1103,7 +1124,8 @@ class Handler:
         }
         if capture == "start":
             out["capture"] = profiler.start_capture(
-                q.get("dir", ["/tmp/pilosa-profile"])[0]
+                q.get("dir", ["/tmp/pilosa-profile"])[0],
+                python_tracer=q.get("python", ["0"])[0] == "1",
             )
         elif capture == "stop":
             out["capture"] = profiler.stop_capture()
@@ -1391,27 +1413,44 @@ def make_http_server(handler: Handler, host: str = "127.0.0.1", port: int = 0):
             if handler.logger:
                 handler.logger.debugf(fmt, *args)
 
+        def parse_request(self):
+            # the request line is in: the request's waterfall starts
+            # here, with the transport's own legs (admission, respond)
+            self._wf = {"_req": trace.next_request_id()}
+            with trace.attrib_activate(self._wf), trace.leg(trace.WF_ADMISSION):
+                return super().parse_request()
+
         def _run(self, method: str):
-            parsed = urlparse(self.path)
-            body = b""
-            length = int(self.headers.get("Content-Length") or 0)
-            if length:
-                body = self.rfile.read(length)
+            with trace.attrib_activate(self._wf):
+                self._serve(method)
+            record = self._wf.get("_record")
+            if record is not None:
+                # a served query: respond ended with the last write
+                cls, summary, index = record
+                profiler.WATERFALL.extend(summary, self._wf)
+                profiler.WATERFALL.record_summary(cls, summary, tenant=index)
+
+        def _serve(self, method: str):
+            with trace.leg(trace.WF_ADMISSION):
+                parsed = urlparse(self.path)
+                query = parse_qs(parsed.query)
+                body = b""
+                length = int(self.headers.get("Content-Length") or 0)
+                if length:
+                    body = self.rfile.read(length)
+                headers = dict(self.headers)
             extra_headers = []
             try:
                 result = handler.handle(
-                    method,
-                    parsed.path,
-                    parse_qs(parsed.query),
-                    body,
-                    headers=dict(self.headers),
+                    method, parsed.path, query, body, headers=headers
                 )
-                if isinstance(result, RawResponse):
-                    payload = result.data
-                    ctype = result.content_type
-                else:
-                    payload = json.dumps(result).encode()
-                    ctype = "application/json"
+                with trace.leg(trace.WF_RESPOND):
+                    if isinstance(result, RawResponse):
+                        payload = result.data
+                        ctype = result.content_type
+                    else:
+                        payload = json.dumps(result).encode()
+                        ctype = "application/json"
                 self.send_response(200)
             except Overloaded as e:
                 # tenant-throttled (429: only THIS tenant must back
@@ -1490,12 +1529,13 @@ def make_http_server(handler: Handler, host: str = "127.0.0.1", port: int = 0):
                 traceback.print_exc()
                 payload, ctype = self._error_payload(f"internal error: {e}")
                 self.send_response(500)
-            for name, value in extra_headers:
-                self.send_header(name, value)
-            self.send_header("Content-Type", ctype)
-            self.send_header("Content-Length", str(len(payload)))
-            self.end_headers()
-            self.wfile.write(payload)
+            with trace.leg(trace.WF_RESPOND):
+                for name, value in extra_headers:
+                    self.send_header(name, value)
+                self.send_header("Content-Type", ctype)
+                self.send_header("Content-Length", str(len(payload)))
+                self.end_headers()
+                self.wfile.write(payload)
 
         def _error_payload(self, msg: str):
             # Only the query route speaks protobuf errors: clients

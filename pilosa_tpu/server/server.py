@@ -638,6 +638,7 @@ class Server:
 
         profiler.TELEMETRY.stager_probe = _stager_probe
         profiler.TELEMETRY.start()
+        profiler.COMPILES.listen()
         if self.exporter is not None:
             self.exporter.start()
         if self.config.profiler_hz > 0:

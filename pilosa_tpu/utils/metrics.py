@@ -258,6 +258,7 @@ DEVICE_OOM = "device.oom"
 DEVICE_OOM_RECOVERED = "device.oom_recovered"
 DEVICE_OOM_CPU_DEGRADES = "device.oom_cpu_degrades"
 DEVICE_FAULTS_INJECTED = "device.faults_injected"
+KERNEL_OPERAND_BYTES = "kernel.operand_bytes"
 PROFILER_COMPILES = "profiler.compiles"
 PROFILER_RECOMPILE_STORMS = "profiler.recompile_storms"
 PROFILER_SAMPLES = "profiler.samples"
@@ -305,7 +306,8 @@ METRICS: dict[str, tuple[str, str]] = {
     ),
     SPMD_EXECUTE_SECONDS: (
         "summary",
-        "warm dispatches of cached compiled kernels (label: kind)",
+        "launch → result ready of a warm compiled kernel, by kernel name; "
+        "a batched scorer's launches count to the fetch (label: kind)",
     ),
     BATCHER_DISPATCHES: (
         "counter",
@@ -900,10 +902,16 @@ METRICS: dict[str, tuple[str, str]] = {
         "device faults injected by the device-faults schedule "
         "(label: fault = oom | stall | poison_jit)",
     ),
+    KERNEL_OPERAND_BYTES: (
+        "counter",
+        "bytes of the device operands handed to kernel launches, padding "
+        "included: attempted bytes, against the bytes a query needs (label: kind)",
+    ),
     PROFILER_COMPILES: (
         "counter",
-        "XLA compiles observed at the jit entry points (label: kind); "
-        "per-plan-signature detail at /debug/profile",
+        "XLA compiles: per kernel at the cached-jit entry points, and "
+        "kind=xla for every backend compile JAX reports, wherever it "
+        "happens (label: kind); per-signature detail at /debug/profile",
     ),
     PROFILER_RECOMPILE_STORMS: (
         "counter",

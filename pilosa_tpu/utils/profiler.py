@@ -24,7 +24,9 @@ Four components, all process-global singletons mirroring
   journals high-watermark crossings.
 
 An on-demand ``jax.profiler`` trace capture (``start_capture`` /
-``stop_capture``) covers the deep dives the always-on layer can't.
+``stop_capture``) covers the deep dives the always-on layer can't; while
+one runs, every waterfall leg is also an annotation on the profiler's
+clock (``trace.leg``).
 """
 
 from __future__ import annotations
@@ -99,6 +101,21 @@ class WaterfallAggregator:
         if wave:
             out["wave"] = wave
         return out
+
+    @staticmethod
+    def extend(summary: dict, stages: dict) -> None:
+        """Add legs measured outside the summary's total (the
+        transport's ``admission`` and ``respond``) to it: each joins its
+        stage and the total, so ``other`` stays total − measured."""
+        merged = dict(summary["stages"])
+        for name, seconds in stages.items():
+            if name in trace.WATERFALL and seconds > 0.0:
+                ms = round(seconds * 1000.0, 3)
+                merged[name] = round(merged.get(name, 0.0) + ms, 3)
+                summary["total_ms"] = round(summary["total_ms"] + ms, 3)
+        summary["stages"] = {
+            n: merged[n] for n in trace.WATERFALL_STAGES if n in merged
+        }
 
     def record(
         self,
@@ -197,11 +214,18 @@ class WaterfallAggregator:
 
 # -- XLA compile tracking -----------------------------------------------------
 
+XLA_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+XLA_KIND = "xla"
+
 
 class CompileTracker:
     """Per-canonical-plan-signature compile counts and compile-seconds,
     observed at the jit entry points (``executor._timed_kernel`` calls
-    ``note()`` on every cold invocation). Bounded: beyond ``max_sigs``
+    ``note()`` on every cold invocation) and, as kind ``xla``, from
+    JAX's own report of every backend compile (``listen()``): module-
+    level ``@jax.jit`` kernels, the scorers' batch functions and fused
+    programs alike. A cached-jit compile therefore shows twice, under
+    its kernel's kind and under ``xla``. Bounded: beyond ``max_sigs``
     distinct signatures, new ones fold into an overflow row. A burst of
     ``storm_threshold`` compiles inside ``storm_window_s`` journals one
     ``profiler.recompile_storm`` event (edge-triggered — the storm must
@@ -224,6 +248,23 @@ class CompileTracker:
         self.total_compiles = 0
         self.total_seconds = 0.0
         self.storms = 0
+        self._listening = False
+
+    def listen(self) -> None:
+        """Feed this tracker from JAX's backend-compile event, once per
+        process (a listener cannot be taken off again; it runs only
+        when something compiles)."""
+        with self._mu:
+            if self._listening:
+                return
+            self._listening = True
+        import jax.monitoring
+
+        def on_duration(event: str, seconds: float, **kw) -> None:
+            if event == XLA_COMPILE_EVENT:
+                self.note(XLA_KIND, kw.get("fun_name"), seconds)
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
 
     def note(self, kind: str, signature: Optional[object], seconds: float) -> None:
         """Record one compile of ``kind`` for ``signature``."""
@@ -534,16 +575,32 @@ class DeviceTelemetry:
         }
 
 
+def operand_bytes(operands) -> int:
+    """Bytes of the arrays in a (nested) tuple or list of kernel
+    operands, padding included; scalars and static values count 0."""
+    if isinstance(operands, (tuple, list)):
+        return sum(operand_bytes(o) for o in operands)
+    return int(getattr(operands, "nbytes", 0))
+
+
+def count_operands(kind: str, operands) -> None:
+    """One launch's operands into ``kernel.operand_bytes{kind}``."""
+    metrics.count(metrics.KERNEL_OPERAND_BYTES, operand_bytes(operands), kind=kind)
+
+
 # -- on-demand jax.profiler capture -------------------------------------------
 
 _capture_mu = OrderedLock("profiler.capture_mu")
 _capture_dir: Optional[str] = None
 
 
-def start_capture(log_dir: str) -> dict:
+def start_capture(log_dir: str, python_tracer: bool = False) -> dict:
     """Begin a ``jax.profiler`` trace into ``log_dir`` for an offline
-    deep dive (TensorBoard / xprof). Returns a status dict; never
-    raises — the profiler may be unavailable or already running."""
+    deep dive (TensorBoard / xprof). The Python tracer is off unless
+    asked for: on, it slows the host it observes severalfold (PERF.md),
+    and the waterfall's legs are in the host plane either way. Returns
+    a status dict; never raises — the profiler may be unavailable or
+    already running."""
     global _capture_dir
     with _capture_mu:
         if _capture_dir is not None:
@@ -551,11 +608,14 @@ def start_capture(log_dir: str) -> dict:
         try:
             import jax
 
-            jax.profiler.start_trace(log_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if python_tracer else 0
+            jax.profiler.start_trace(log_dir, profiler_options=options)
+            trace.set_capturing(True)
         except Exception as e:  # noqa: BLE001 - report, never raise
             return {"ok": False, "error": f"{type(e).__name__}: {e}"}
         _capture_dir = log_dir
-        return {"ok": True, "dir": log_dir}
+        return {"ok": True, "dir": log_dir, "python_tracer": python_tracer}
 
 
 def stop_capture() -> dict:
@@ -565,6 +625,7 @@ def stop_capture() -> dict:
             return {"ok": False, "error": "no capture running"}
         d = _capture_dir
         _capture_dir = None
+        trace.set_capturing(False)
         try:
             import jax
 

@@ -46,6 +46,7 @@ wave-deduped dispatch item links the executed item.
 from __future__ import annotations
 
 import contextvars
+import itertools
 import os
 import random
 import threading
@@ -522,11 +523,17 @@ TRACER = Tracer()
 WF_ADMISSION = "admission"
 WF_PIPELINE_QUEUE = "pipeline.queue"
 WF_PLAN_CANON = "plan.canon"
+WF_STAGER_LOOKUP = "stager.lookup"
 WF_STAGER = "stager"
 WF_DISPATCH_QUEUE = "dispatch.queue"
+WF_WAVE_MATES = "dispatch.wave_mates"
+WF_GUARD_QUEUE = "guard.queue"
+WF_TOPN_CANDIDATES = "topn.candidates"
 WF_DEVICE_COMPUTE = "device.compute"
 WF_TRANSFER_DECODE = "transfer.decode"
+WF_TOPN_WALK = "topn.walk"
 WF_REDUCE = "reduce"
+WF_RESPOND = "respond"
 WF_OTHER = "other"
 
 # display / aggregation order of the waterfall
@@ -534,23 +541,35 @@ WATERFALL_STAGES: tuple = (
     WF_ADMISSION,
     WF_PIPELINE_QUEUE,
     WF_PLAN_CANON,
+    WF_STAGER_LOOKUP,
     WF_STAGER,
     WF_DISPATCH_QUEUE,
+    WF_WAVE_MATES,
+    WF_GUARD_QUEUE,
+    WF_TOPN_CANDIDATES,
     WF_DEVICE_COMPUTE,
     WF_TRANSFER_DECODE,
+    WF_TOPN_WALK,
     WF_REDUCE,
+    WF_RESPOND,
     WF_OTHER,
 )
 
 WATERFALL: dict = {
-    WF_ADMISSION: "HTTP parse, auth, validation before the pipeline",
+    WF_ADMISSION: "request line read → hand-off to the pipeline (HTTP parse, classification)",
     WF_PIPELINE_QUEUE: "admission-pipeline queue wait (+ coalescing)",
     WF_PLAN_CANON: "query parse, canonicalization, CSE planning",
+    WF_STAGER_LOOKUP: "stager cache probe: content-key hashing + LRU touch",
     WF_STAGER: "HBM stage miss: building + uploading shard planes",
     WF_DISPATCH_QUEUE: "dispatch-engine queue wait before a wave",
-    WF_DEVICE_COMPUTE: "fenced device execution (jit dispatch → ready)",
-    WF_TRANSFER_DECODE: "device→host transfer and result decode",
+    WF_WAVE_MATES: "combined wave: the wave-mates' share of its measured legs, waited through",
+    WF_GUARD_QUEUE: "device-guard pool: wait for a worker to pick the call up",
+    WF_TOPN_CANDIDATES: "TopN ranked-cache snapshot and candidate chunk assembly",
+    WF_DEVICE_COMPUTE: "host's wait on the device (launch → result ready)",
+    WF_TRANSFER_DECODE: "device→host copy and result decode",
+    WF_TOPN_WALK: "TopN ranked walk, cross-shard merge, sort, pass-2 trim",
     WF_REDUCE: "host-side shard-result reduction",
+    WF_RESPOND: "results → JSON bytes → last write",
     WF_OTHER: "unattributed host time (total − measured legs)",
 }
 
@@ -630,6 +649,81 @@ def attrib_activate(d: Optional[dict]) -> _AttribActivation:
     return _AttribActivation(d)
 
 
+# -- leg: the waterfall's one timing primitive --------------------------------
+#
+# ``with trace.leg(stage):`` times an interval on time.monotonic() and
+# credits it to the active request's waterfall. Legs nest: a second is
+# credited once, to the innermost leg open on the thread that spent it
+# (a thread-local holds that leg), so a leg may wrap code whose callees
+# have legs of their own (the TopN walk pulls chunks, which stage, score
+# and fetch) and ``other`` stays a true remainder. While a jax.profiler
+# capture runs the same interval is also a ``TraceAnnotation(stage,
+# req=<request id>)`` in the profiler's host plane, on the thread that
+# did the work: legs and device ops then share one clock. The request
+# id rides in the attribution dict (``_req``, like ``_wave``).
+#
+# Cost with no capture running: two clock reads, one contextvar get, one
+# global test, one thread-local read and write; jax is never imported.
+
+_request_ids = itertools.count(1)
+
+# jax.profiler.TraceAnnotation while a capture runs (profiler.start_capture
+# binds it, stop_capture clears it), else None. A plain global: a leg
+# racing the flip misses or gains one annotation.
+_annotation = None
+
+_open_leg = threading.local()
+
+
+def next_request_id() -> int:
+    return next(_request_ids)
+
+
+def set_capturing(on: bool) -> None:
+    global _annotation
+    if on:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    else:
+        _annotation = None
+
+
+class leg:
+    """``t0`` and ``seconds`` (the whole interval, inner legs included)
+    are there for a site that also feeds a histogram or a span."""
+
+    __slots__ = ("stage", "t0", "seconds", "_inner", "_parent", "_ann")
+
+    def __init__(self, stage: str) -> None:
+        self.stage = stage
+
+    def __enter__(self) -> "leg":
+        self._inner = 0.0
+        self._parent = getattr(_open_leg, "leg", None)
+        _open_leg.leg = self
+        ann = _annotation
+        if ann is not None:
+            d = _attrib.get()
+            ann = ann(self.stage, req=d.get("_req", 0) if d else 0)
+            ann.__enter__()
+        self._ann = ann
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dt = self.seconds = time.monotonic() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        parent = _open_leg.leg = self._parent
+        if parent is not None:
+            parent._inner += dt
+        d = _attrib.get()
+        if d is not None:
+            d[self.stage] = d.get(self.stage, 0.0) + max(0.0, dt - self._inner)
+        return False
+
+
 # -- dispatch wave id ---------------------------------------------------------
 #
 # The wave number of the dispatch-engine wave currently executing on
@@ -652,3 +746,22 @@ def set_wave(wave_no: int):
 
 def reset_wave(token) -> None:
     _wave_var.reset(token)
+
+
+def carried(fn):
+    """``fn`` bound to the caller's span, attribution dict and wave id,
+    for a hop onto a pool thread (contextvars do not follow a submit).
+    The deadline is deliberately not carried: each hop decides that
+    itself."""
+    parent, attrib, wave = _current.get(), _attrib.get(), _wave_var.get()
+
+    def run(*args, **kwargs):
+        tokens = (_current.set(parent), _attrib.set(attrib), _wave_var.set(wave))
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _wave_var.reset(tokens[2])
+            _attrib.reset(tokens[1])
+            _current.reset(tokens[0])
+
+    return run

@@ -1,0 +1,313 @@
+"""The waterfall's legs (ISSUE 26): ``trace.leg`` itself, the hand-over
+across the device-guard thread, the capture's options, compiles counted
+wherever they happen, the batched scorers' kernel accounting, and the
+benchmark's per-layer metric files against the names the program
+publishes."""
+
+import glob
+import json
+import os
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from pilosa_tpu import SHARD_WIDTH
+from pilosa_tpu.core import Holder
+from pilosa_tpu.executor import Executor
+from pilosa_tpu.executor.batcher import BatchedScorer
+from pilosa_tpu.executor.devicehealth import DeviceHealth
+from pilosa_tpu.utils import metrics, profiler, trace
+
+ROOT = os.path.join(os.path.dirname(__file__), "..")
+
+
+def _metric(name: str, **labels) -> float:
+    """A counter's value, or a summary's count, from the registry."""
+    key = metrics._flat_key(name, metrics._labels_key(labels))
+    snap = metrics.snapshot()
+    if key in snap:
+        return snap[key]
+    hist = snap.get(metrics._flat_key(name + ".hist", metrics._labels_key(labels)))
+    return hist["count"] if hist else 0
+
+
+# -- the primitive ------------------------------------------------------------
+
+
+def test_leg_credits_each_second_once_to_the_innermost_leg():
+    wf: dict = {}
+    with trace.attrib_activate(wf):
+        with trace.leg(trace.WF_TOPN_WALK) as outer:
+            time.sleep(0.02)
+            with trace.leg(trace.WF_DEVICE_COMPUTE) as inner:
+                time.sleep(0.03)
+    assert inner.seconds >= 0.03 and outer.seconds >= 0.05
+    assert wf[trace.WF_DEVICE_COMPUTE] == pytest.approx(inner.seconds)
+    assert wf[trace.WF_TOPN_WALK] == pytest.approx(outer.seconds - inner.seconds)
+    assert sum(wf.values()) == pytest.approx(outer.seconds)
+
+
+def test_leg_without_attribution_is_a_timer_only():
+    assert trace.attrib_current() is None
+    with trace.leg(trace.WF_REDUCE) as lg:
+        pass
+    assert lg.seconds >= 0.0 and trace.attrib_current() is None
+
+
+def test_leg_off_capture_builds_no_annotation_and_imports_no_jax():
+    """Run in a child: this process has long imported jax."""
+    import subprocess
+
+    code = (
+        "import sys\n"
+        "from pilosa_tpu.utils import trace\n"
+        "wf = {}\n"
+        "with trace.attrib_activate(wf):\n"
+        "    with trace.leg(trace.WF_REDUCE) as lg:\n"
+        "        pass\n"
+        "assert lg._ann is None and trace._annotation is None\n"
+        "assert trace.WF_REDUCE in wf\n"
+        "assert 'jax' not in sys.modules, 'leg() imported jax'\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=ROOT, capture_output=True, text=True, timeout=60
+    )
+    assert out.returncode == 0, out.stderr
+
+
+def test_leg_annotates_with_the_request_id_only_while_capturing(monkeypatch):
+    made = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            made.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    with trace.attrib_activate({"_req": 41}):
+        with trace.leg(trace.WF_RESPOND):
+            pass
+        assert made == []
+        monkeypatch.setattr(trace, "_annotation", Annotation)
+        with trace.leg(trace.WF_RESPOND):
+            pass
+    assert made == [(trace.WF_RESPOND, {"req": 41})]
+
+
+def test_carried_hands_span_attribution_and_wave_to_a_pool_thread():
+    wf: dict = {}
+    seen = {}
+    with trace.attrib_activate(wf):
+        token = trace.set_wave(7)
+        try:
+            fn = trace.carried(
+                lambda: seen.update(d=trace.attrib_current(), wave=trace.current_wave())
+            )
+        finally:
+            trace.reset_wave(token)
+    t = threading.Thread(target=fn)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    assert seen == {"d": wf, "wave": 7}
+
+
+# -- across the device-guard thread -------------------------------------------
+
+
+@pytest.fixture()
+def gated(tmp_path):
+    """An executor as the server builds it by default: reads run on the
+    device health gate's pool, another thread."""
+    h = Holder(str(tmp_path))
+    h.open()
+    idx = h.create_index("i")
+    f = idx.create_field("f")
+    from pilosa_tpu.core.field import FieldOptions
+
+    v = idx.create_field("v", FieldOptions(type="int", min=0, max=1000))
+    rng = np.random.default_rng(3)
+    rows, cols, vcols = [], [], []
+    for shard in range(12):
+        base = shard * SHARD_WIDTH
+        for row in range(1, 301):  # a ranked cache deep enough to walk
+            picked = rng.choice(60000, size=max(4, 400 // row), replace=False)
+            rows.append(np.full(picked.size, row))
+            cols.append(base + picked)
+        vcols.append(base + np.arange(0, 60000, 25))
+    f.import_bits(np.concatenate(rows), np.concatenate(cols))
+    vcols = np.concatenate(vcols)
+    v.import_values(vcols, rng.integers(0, 1000, size=vcols.size))
+    health = DeviceHealth(timeout_s=120.0)
+    ex = Executor(h, device_policy="always", health=health)
+    yield ex
+    ex.close()
+    h.close()
+
+
+@pytest.mark.parametrize("query", ["TopN(f, Row(f=1), n=3)", "Sum(Row(f=1), field=v)"])
+def test_guarded_read_keeps_its_device_legs(gated, query):
+    """The parent handed the guard thread the span only: every leg below
+    it was dropped and the whole request read as ``other``."""
+    from pilosa_tpu.pql import parse
+
+    parsed = parse(query)  # as api.query hands it over, its parse a leg of its own
+    gated.execute("i", parsed)  # compile
+    wf: dict = {}
+    with trace.attrib_activate(wf):
+        t0 = time.monotonic()
+        res = gated.execute("i", parsed)
+        total = time.monotonic() - t0
+    assert res and res[0]
+    assert wf.get(trace.WF_DEVICE_COMPUTE, 0.0) > 0.0
+    summary = profiler.WATERFALL.summarize(wf, total)
+    assert summary["stages"].get(trace.WF_OTHER, 0.0) < summary["total_ms"] / 5, summary
+    assert {k for k in wf if not k.startswith("_")} <= set(trace.WATERFALL_STAGES)
+    if query.startswith("TopN"):
+        # (a lone Sum's count vector is waited for and copied in one
+        # step, inside device.compute: executor._launch)
+        assert wf.get(trace.WF_TRANSFER_DECODE, 0.0) > 0.0
+        assert wf.get(trace.WF_TOPN_WALK, 0.0) > 0.0
+        assert wf.get(trace.WF_TOPN_CANDIDATES, 0.0) > 0.0
+
+
+# -- the capture --------------------------------------------------------------
+
+
+@pytest.mark.parametrize("python_tracer, level", [(False, 0), (True, 1)])
+def test_capture_runs_without_the_python_tracer_unless_asked(
+    monkeypatch, tmp_path, python_tracer, level
+):
+    import jax
+
+    calls = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace", lambda d, **kw: calls.append((d, kw))
+    )
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    try:
+        out = profiler.start_capture(str(tmp_path), python_tracer=python_tracer)
+        assert out["ok"], out
+        assert trace._annotation is jax.profiler.TraceAnnotation
+    finally:
+        assert profiler.stop_capture()["ok"]
+    assert trace._annotation is None
+    (d, kw), = calls
+    assert d == str(tmp_path)
+    assert kw["profiler_options"].python_tracer_level == level
+
+
+def test_debug_profile_python_param_reaches_the_capture(monkeypatch):
+    from pilosa_tpu.server.http_handler import Handler, Request
+
+    seen = []
+
+    def start_capture(log_dir, python_tracer=False):
+        seen.append(python_tracer)
+        return {"ok": True}
+
+    monkeypatch.setattr(profiler, "start_capture", start_capture)
+    h = Handler.__new__(Handler)
+    for q in ({"capture": ["start"]}, {"capture": ["start"], "python": ["1"]}):
+        h.get_debug_profile(Request({}, q, b""))
+    assert seen == [False, True]
+
+
+# -- compiles, wherever they happen -------------------------------------------
+
+
+def test_module_level_jit_compile_is_counted_as_xla():
+    import jax
+    import jax.numpy as jnp
+
+    profiler.COMPILES.listen()
+    profiler.COMPILES.listen()  # once per process, however often asked
+
+    @jax.jit
+    def fresh(x):
+        return x * 3 + 1
+
+    x = jnp.asarray(np.arange(7, dtype=np.int32))  # no program of its own
+    before = _metric(metrics.PROFILER_COMPILES, kind=profiler.XLA_KIND)
+    fresh(x).block_until_ready()
+    after = _metric(metrics.PROFILER_COMPILES, kind=profiler.XLA_KIND)
+    assert after == before + 1
+    fresh(x).block_until_ready()  # warm: no compile
+    assert _metric(metrics.PROFILER_COMPILES, kind=profiler.XLA_KIND) == after
+    rows = profiler.COMPILES.snapshot(top=256)["signatures"]
+    assert any(r["kind"] == profiler.XLA_KIND and "fresh" in r["signature"] for r in rows)
+
+
+# -- the batched scorers' launches --------------------------------------------
+
+
+def test_batched_scorer_launch_lands_in_kernel_families():
+    import jax.numpy as jnp
+
+    kind = "topn_score_dense"
+    mat = jnp.asarray(np.arange(8 * 64, dtype=np.uint32).reshape(8, 64))
+    src = jnp.asarray(np.full(64, 0xFFFF, dtype=np.uint32))
+    scorer = BatchedScorer()
+    assert scorer.kind == kind
+    launches = _metric(metrics.SPMD_EXECUTE_SECONDS, kind=kind)
+    handed = _metric(metrics.KERNEL_OPERAND_BYTES, kind=kind)
+    wf: dict = {}
+    with trace.attrib_activate(wf):
+        out = scorer.score(("k", id(mat)), mat, src)
+    assert out.shape == (8,)
+    assert _metric(metrics.SPMD_EXECUTE_SECONDS, kind=kind) == launches + 1
+    assert _metric(metrics.KERNEL_OPERAND_BYTES, kind=kind) == handed + mat.nbytes + src.nbytes
+    assert wf.get(trace.WF_DEVICE_COMPUTE, 0.0) > 0.0
+
+
+def test_operand_bytes_walks_nested_operands():
+    a = np.zeros((4, 8), dtype=np.uint32)
+    assert profiler.operand_bytes(((a, [a, 3]), "static", None)) == 2 * a.nbytes
+
+
+# -- the transport's legs -----------------------------------------------------
+
+
+def test_extend_adds_transport_legs_to_stage_and_total():
+    s = profiler.WATERFALL.summarize({trace.WF_REDUCE: 0.002, "_req": 5}, 0.010)
+    other = s["stages"][trace.WF_OTHER]
+    profiler.WATERFALL.extend(
+        s, {trace.WF_ADMISSION: 0.001, trace.WF_RESPOND: 0.003, "_req": 5, "_record": ()}
+    )
+    assert s["total_ms"] == pytest.approx(14.0)
+    assert s["stages"][trace.WF_ADMISSION] == pytest.approx(1.0)
+    assert s["stages"][trace.WF_RESPOND] == pytest.approx(3.0)
+    assert s["stages"][trace.WF_OTHER] == other
+    order = [n for n in trace.WATERFALL_STAGES if n in s["stages"]]
+    assert list(s["stages"]) == order
+
+
+# -- the benchmark's readers against the program's names ----------------------
+
+
+def test_layer_metric_files_name_published_metrics_and_stages():
+    """A ``/metrics`` sample that is renamed reads 0 in its
+    ``layer_metrics`` file, silently (PERF.md §7): hold the files to the
+    registry's and the waterfall's names."""
+    files = sorted(glob.glob(os.path.join(ROOT, "benchmark", "layer_metrics", "*.json")))
+    assert len(files) >= 20
+    for path in files:
+        with open(path) as fh:
+            spec = json.load(fh)
+        assert spec["name"] == os.path.basename(path)[: -len(".json")]
+        for sample in spec.get("numerator", ()):
+            name = sample["metric"]
+            # a summary is published as <name>_sum and <name>_count
+            base = name if name in metrics.METRICS else name.rsplit("_", 1)[0]
+            assert base in metrics.METRICS, f"{spec['name']}: {name} is not declared in utils/metrics.py"
+            stage = sample.get("labels", {}).get("stage")
+            if stage is not None:
+                assert base == metrics.LATENCY_STAGE_SECONDS
+                assert stage in trace.WATERFALL_STAGES, f"{spec['name']}: no stage {stage!r}"
