@@ -59,6 +59,18 @@ def pairs_arrays(pairs):
     return ids, cnts
 
 
+def _count_reads(found: list[Optional[int]]) -> list[int]:
+    """One get_many's reads as get() returns them (0 for an id the cache
+    does not hold), counted into cache.hits / cache.misses once."""
+    misses = found.count(None)
+    if len(found) - misses:
+        metrics.count(metrics.CACHE_HITS, value=len(found) - misses)
+    if misses:
+        metrics.count(metrics.CACHE_MISSES, value=misses)
+        return [n or 0 for n in found]
+    return found
+
+
 class Rankings(list):
     """Rankings snapshot (a list of (id, count) pairs) carrying its own
     memo of per-slice id tuples. The memo lives ON the snapshot — not
@@ -66,10 +78,14 @@ class Rankings(list):
     rankings can never hand a caller ids inconsistent with the pairs
     list it is iterating."""
 
-    def chunk_ids(self, lo: int, hi: int) -> tuple[int, ...]:
-        memo = getattr(self, "_memo", None)
+    def _memo_of(self, name: str) -> dict:
+        memo = self.__dict__.get(name)
         if memo is None:
-            memo = self._memo = {}
+            memo = self.__dict__[name] = {}
+        return memo
+
+    def chunk_ids(self, lo: int, hi: int) -> tuple[int, ...]:
+        memo = self._memo_of("_memo")
         t = memo.get((lo, hi))
         if t is None:
             # a racing duplicate build produces an identical tuple — benign
@@ -82,13 +98,40 @@ class Rankings(list):
         the snapshot (same rationale as chunk_ids): the vectorized
         cross-shard TopN walk consumes candidate ids/counts as numpy
         arrays per shard per chunk on every query."""
-        memo = getattr(self, "_np_memo", None)
-        if memo is None:
-            memo = self._np_memo = {}
+        memo = self._memo_of("_np_memo")
         t = memo.get((lo, hi))
         if t is None:
             t = memo[(lo, hi)] = pairs_arrays(self[lo:hi])
         return t
+
+    def chunk_index(self, lo: int, hi: int) -> dict[int, int]:
+        """{id: position in self[lo:hi]}, memoized on the snapshot: TopN
+        pass 2 looks the winners' scores up in the chunks pass 1 scored.
+        Only a chunk a walk has scored is ever asked for."""
+        memo = self._memo_of("_ix_memo")
+        t = memo.get((lo, hi))
+        if t is None:
+            ids = self.chunk_ids(lo, hi)
+            t = memo[(lo, hi)] = dict(zip(ids, range(len(ids))))
+        return t
+
+    def chunk_blocks(self, lo: int, hi: int, frag) -> tuple[int, bool]:
+        """(nonempty container blocks of self[lo:hi]'s rows in ``frag``,
+        whether they had to be counted now). The count is kept with the
+        ``frag.generation`` it was read at, the tag the fragment's own
+        occupancy snapshot is validated by: a Set into a ranked row adds
+        a container without a recalculate, so the snapshot alone does
+        not fix the count. The generation is read BEFORE the count: a
+        write in between leaves the memo under the old tag, and the
+        next call counts again."""
+        memo = self._memo_of("_blocks_memo")
+        gen = frag.generation
+        t = memo.get((lo, hi))
+        if t is not None and t[0] == gen:
+            return t[1], False
+        n = frag.sparse_block_count(self.chunk_ids(lo, hi))
+        memo[(lo, hi)] = (gen, n)
+        return n, True
 
 
 class RankCache:
@@ -123,6 +166,11 @@ class RankCache:
             return 0
         metrics.count(metrics.CACHE_HITS)
         return n
+
+    def get_many(self, ids) -> list[int]:
+        """get() for each id, the hits and misses counted once a call:
+        a TopN pass 2 re-reads the winners in every shard."""
+        return _count_reads(list(map(self.entries.get, ids)))
 
     def remove(self, id_: int) -> None:
         if self.entries.pop(id_, None) is not None:
@@ -212,6 +260,13 @@ class LRUCache:
         self._lru.move_to_end(id_)
         metrics.count(metrics.CACHE_HITS)
         return n
+
+    def get_many(self, ids) -> list[int]:
+        found = list(map(self._lru.get, ids))
+        for i, n in zip(ids, found):
+            if n is not None:
+                self._lru.move_to_end(i)
+        return _count_reads(found)
 
     def remove(self, id_: int) -> None:
         self._lru.pop(id_, None)

@@ -1251,8 +1251,7 @@ class Fragment:
                 return self.cache.top()
         pairs = []
         missing = []
-        for row_id in row_ids:
-            n = self.cache.get(row_id)
+        for row_id, n in zip(row_ids, self.cache.get_many(row_ids)):
             if n > 0:
                 pairs.append((row_id, n))
             else:

@@ -135,61 +135,58 @@ class _NotDeviceable(Exception):
 
 class _ScoreCarry:
     """Cross-pass TopN score carry: pass 1's chunk scores, appended as
-    whole arrays and resolved vectorized at pass-2 seed time.
+    whole arrays; pass 2 looks up the ids it asks for.
 
-    Pass 2 only needs the union winners' counts (~n ids per shard), but
-    the previous dict form fanned EVERY pass-1 score into a (shard, id)
-    tuple key eagerly — ~8k tuple builds + dict inserts per query at 64
-    shards, measured ~3 ms of the ~6 ms serialized host work that
-    bounds serving throughput on a 1-core host. Append is O(1) per
-    chunk; seed() builds one small per-shard zip-dict on demand (see
-    its docstring for why not np.isin)."""
+    Pass 2 only needs the union winners' counts (~n ids per shard), so
+    nothing here is proportional to what pass 1 scored: add is O(1) per
+    chunk and shard, and seed() finds each requested id through the
+    chunk's id -> position index, which a Rankings snapshot keeps
+    (core.cache.Rankings.chunk_index) and a plain list builds."""
 
     __slots__ = ("_by_shard", "_n")
 
     def __init__(self) -> None:
-        # shard -> [(ids, scores), ...]: seed() is called once PER
-        # SHARD at pass-2 provider init (64 calls/query on the tall
-        # config), so a flat chunk list would be rescanned 64x — the
-        # first cut of this class did exactly that and profiled at
-        # ~3.6 ms/query, as expensive as the dict fanout it replaced
+        # shard -> [(pairs, lo, hi, scores), ...]: seed() is called once
+        # PER SHARD at pass-2 provider init (64 calls/query on the tall
+        # config), so a flat chunk list would be rescanned 64x
         self._by_shard: dict[int, list] = {}
         self._n = 0
 
     def __len__(self) -> int:  # `if carry:` seeds only when non-empty
         return self._n
 
-    def add(self, shard: int, ids, scores) -> None:
-        # scores may be pow2- or chunk-size-padded past len(ids) (the
-        # old dict zip truncated implicitly) — slice, never trust widths
-        if len(ids):
-            self._by_shard.setdefault(shard, []).append((ids, scores[: len(ids)]))
+    def add(self, shard: int, pairs, lo: int, hi: int, scores) -> None:
+        """``scores[j]`` is the score of ``pairs[lo + j]``'s id; the row
+        may be pow2- or chunk-size-padded past the chunk's ids, which
+        no position of the index reaches."""
+        if len(pairs) > lo:
+            self._by_shard.setdefault(shard, []).append((pairs, lo, hi, scores))
             self._n += 1
 
-    def add_stacked(self, shards, ids_by_shard, mat) -> None:
-        for i, ids in enumerate(ids_by_shard):
-            if ids:
-                self._by_shard.setdefault(shards[i], []).append(
-                    (ids, mat[i][: len(ids)])
-                )
-                self._n += 1
+    def add_stacked(self, shards, pairs_by_shard, lo: int, mat) -> None:
+        hi = lo + mat.shape[1]
+        for i, pairs in enumerate(pairs_by_shard):
+            self.add(shards[i], pairs, lo, hi, mat[i])
 
     def seed(self, shard: int, rids) -> dict[int, int]:
         """{rid: score} for the requested ids present in this carry.
         Chunks are disjoint id ranges per shard (prefix walks), so no
-        overwrite ambiguity. Plain zip-dict, deliberately NOT np.isin:
-        at the serving sizes (a 128-entry head chunk vs ~n winner ids,
-        64 shards/query) isin's fixed per-call overhead profiled at
-        ~2 ms/query while the zip build is ~5 us/shard; at deep-walk
-        sizes (16k ids) the two are comparable."""
+        overwrite ambiguity."""
         chunks = self._by_shard.get(shard)
         if not chunks or not rids:
             return {}
-        lut: dict[int, object] = {}
-        for ids, scores in chunks:
-            sc = scores.tolist() if hasattr(scores, "tolist") else scores
-            lut.update(zip(ids, sc))
-        return {rid: int(lut[rid]) for rid in rids if rid in lut}
+        found = [
+            (_chunk_index(pairs, lo, hi).get, scores)
+            for pairs, lo, hi, scores in chunks
+        ]
+        out: dict[int, int] = {}
+        for rid in rids:
+            for position, scores in found:
+                j = position(rid)
+                if j is not None:
+                    out[rid] = int(scores[j])
+                    break
+        return out
 
 
 def _eval_tree(t, leaves):
@@ -2415,7 +2412,7 @@ class Executor:
             provider._mats.append(mat0)
             provider._chunk_meta.append((0, mat0.shape[1], ids0))
             provider._pos = mat0.shape[1]
-            provider._publish(ids0, mat0)
+            provider._publish(0, mat0)
         opt_ = TopOptions(
             n=int(n),
             src=None,
@@ -2801,6 +2798,25 @@ def _chunk_arrays(pairs, lo: int, hi: int):
     return cache_pairs_arrays(pairs[lo:hi])
 
 
+def _chunk_index(pairs, lo: int, hi: int) -> dict[int, int]:
+    """{id: position in pairs[lo:hi]}; memoized on Rankings snapshots."""
+    chunk = getattr(pairs, "chunk_index", None)
+    if chunk is not None:
+        return chunk(lo, hi)
+    return {p[0]: j for j, p in enumerate(pairs[lo:hi])}
+
+
+def _chunk_blocks(pairs, lo: int, hi: int, frag) -> tuple[int, bool]:
+    """(nonempty container blocks of pairs[lo:hi]'s rows in ``frag``,
+    whether they were counted now): kept on Rankings snapshots with the
+    fragment generation they were counted at, counted afresh for plain
+    lists."""
+    chunk = getattr(pairs, "chunk_blocks", None)
+    if chunk is not None:
+        return chunk(lo, hi, frag)
+    return frag.sparse_block_count([p[0] for p in pairs[lo:hi]]), True
+
+
 class _ChunkedLazyScores:
     """Shared chunk-walk skeleton for cross-shard lazy TopN scoring:
     the next pow2 chunk of every shard's candidate list is staged and
@@ -2853,7 +2869,9 @@ class _ChunkedLazyScores:
                 if seed:
                     self._scores[i].update(seed)
 
-    def _stage(self, ids_by_shard, size: int):
+    def _stage(self, ids_by_shard, size: int, peek: bool = False):
+        """The staged bundle for a chunk; ``peek``: whether the stager
+        holds (or is building) it, staging nothing."""
         raise NotImplementedError
 
     def _score(self, staged, size: int):
@@ -2880,7 +2898,10 @@ class _ChunkedLazyScores:
         # chunk (lo == 0): most walks prune inside it on skewed data —
         # eagerly staging the 4096-candidate chunk behind it would
         # re-introduce exactly the cold-staging cost the small head
-        # chunk was measured to avoid (class docstring).
+        # chunk was measured to avoid (class docstring). The decision
+        # runs here, on the request's thread, before this chunk's
+        # kernel is launched: it has to cost what the walk reads (one
+        # number a shard), not what the next chunk holds (_prefetch).
         if lo > 0 and hi < self._max_len:
             with trace.leg(trace.WF_TOPN_CANDIDATES):
                 self._prefetch(hi)
@@ -2890,7 +2911,7 @@ class _ChunkedLazyScores:
             mat = self._score(staged, size)
         self._mats.append(mat)
         self._chunk_meta.append((lo, size, ids_by_shard))
-        self._publish(ids_by_shard, mat)
+        self._publish(lo, mat)
 
     def _fanout(self) -> None:
         """Populate the per-shard id->score dicts from chunk matrices
@@ -2937,22 +2958,42 @@ class _ChunkedLazyScores:
         raise NotImplementedError
 
     def _prefetch(self, lo: int) -> None:
+        """Stage the chunk at ``lo`` ahead on a side thread, where that
+        pushes nothing out. Advisory means it may not evict: staging
+        ahead a chunk that does not fit would push out the chunks this
+        walk is scoring, and every later query would stage all of them
+        again. Every request of a deep walk asks, so the question is
+        answered from what the rankings snapshots keep: a lower bound
+        first (a ranked candidate has a bit, so a block; has_room is
+        monotone), then the chunk's block counts, which are counted
+        once per snapshot and fragment generation. A count stale by a
+        racing write can mis-stage, never mis-answer."""
         if self._prefetching:
             return
         size = _chunk_size(lo)
-        ids_by_shard = tuple(
-            _chunk_ids(ps, lo, lo + size) for ps in self._pairs
+        hi = lo + size
+        block_bytes = ops.packed.CONTAINER_WORDS * 4
+        has_room = self._ex.stager.has_room
+        lens = [
+            max(min(len(ps), hi) - lo, 0) if f is not None else 0
+            for f, ps in zip(self._frags, self._pairs)
+        ]
+        if not has_room(self._bundle_blocks(lens) * block_bytes):
+            metrics.count(metrics.TOPN_PREFETCH_DECISIONS, how="bound")
+            return
+        counts = [
+            _chunk_blocks(ps, lo, hi, f) if n else (0, False)
+            for f, ps, n in zip(self._frags, self._pairs, lens)
+        ]
+        metrics.count(
+            metrics.TOPN_PREFETCH_DECISIONS,
+            how="counted" if any(c[1] for c in counts) else "memo",
         )
-        # advisory means it may not evict: staging ahead a chunk that
-        # does not fit would push out the chunks this walk is scoring,
-        # and every later query would stage all of them again
-        blocks = self._bundle_blocks(
-            [
-                f.sparse_block_count(ids) if f is not None and ids else 0
-                for f, ids in zip(self._frags, ids_by_shard)
-            ]
-        )
-        if not self._ex.stager.has_room(blocks * ops.packed.CONTAINER_WORDS * 4):
+        blocks = self._bundle_blocks([c[0] for c in counts])
+        if not has_room(blocks * block_bytes):
+            return
+        ids_by_shard = tuple(_chunk_ids(ps, lo, hi) for ps in self._pairs)
+        if self._stage(ids_by_shard, size, peek=True):
             return
         self._prefetching = True
 
@@ -2964,14 +3005,15 @@ class _ChunkedLazyScores:
             finally:
                 self._prefetching = False
 
+        metrics.count(metrics.TOPN_PREFETCH_STARTS)
         threading.Thread(
             target=warm, name="stage-prefetch", daemon=True
         ).start()
 
-    def _publish(self, ids_by_shard, mat) -> None:
+    def _publish(self, lo: int, mat) -> None:
         if self._carry is None:
             return
-        self._carry.add_stacked(self._shards, ids_by_shard, mat)
+        self._carry.add_stacked(self._shards, self._pairs, lo, mat)
 
     def view(self, shard_index: int) -> "_ShardScoreView":
         return _ShardScoreView(self, shard_index)
@@ -2983,8 +3025,10 @@ class _StackedLazyScores(_ChunkedLazyScores):
     (global segment ids), coalesced with concurrent queries through
     the BatchedScorer."""
 
-    def _stage(self, ids_by_shard, size: int):
-        return self._ex.stager.sparse_rows_stacked(self._frags, ids_by_shard, size)
+    def _stage(self, ids_by_shard, size: int, peek: bool = False):
+        return self._ex.stager.sparse_rows_stacked(
+            self._frags, ids_by_shard, size, peek=peek
+        )
 
     def _bundle_blocks(self, blocks_by_shard: list[int]) -> int:
         return _next_pow2(max(sum(blocks_by_shard), 1))
@@ -3034,8 +3078,10 @@ class _SpmdLazyScores(_ChunkedLazyScores):
     containers (reference threshold walk semantics preserved by
     _ranked_walk; fragment.go:870-1002)."""
 
-    def _stage(self, ids_by_shard, size: int):
-        return self._ex.stager.sparse_rows_stack(self._frags, ids_by_shard, size)
+    def _stage(self, ids_by_shard, size: int, peek: bool = False):
+        return self._ex.stager.sparse_rows_stack(
+            self._frags, ids_by_shard, size, peek=peek
+        )
 
     def _bundle_blocks(self, blocks_by_shard: list[int]) -> int:
         return len(blocks_by_shard) * _next_pow2(max(max(blocks_by_shard), 1))
@@ -3090,9 +3136,10 @@ class _LazyScores:
         # candidate cache only the chunks the walk reaches pay anything
         size = _chunk_size(self._next)
         frag = self._frag
+        lo = self._next
         with trace.leg(trace.WF_TOPN_CANDIDATES):
-            ids = _chunk_ids(self._pairs, self._next, self._next + size)
-            occupied = frag.sparse_block_count(list(ids))
+            ids = _chunk_ids(self._pairs, lo, lo + size)
+            occupied, _ = _chunk_blocks(self._pairs, lo, lo + size, frag)
         self._next += size
         if occupied * 2 < len(ids) * (SHARD_WIDTH >> 16):
             blocks, brow, bslot, num_rows = self._ex.stager.sparse_rows(frag, ids)
@@ -3115,7 +3162,7 @@ class _LazyScores:
             )
         self._scores.update(zip(ids, (int(s) for s in scores)))
         if self._carry is not None:
-            self._carry.add(self._shard, ids, scores)
+            self._carry.add(self._shard, self._pairs, lo, lo + size, scores)
 
     def __getitem__(self, row_id: int) -> int:
         while row_id not in self._scores and self._next < len(self._pairs):

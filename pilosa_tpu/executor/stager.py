@@ -965,7 +965,11 @@ class DeviceStager:
         )
 
     def sparse_rows_stacked(
-        self, frags, ids_by_shard: tuple[tuple[int, ...], ...], chunk: int
+        self,
+        frags,
+        ids_by_shard: tuple[tuple[int, ...], ...],
+        chunk: int,
+        peek: bool = False,
     ):
         """Merged block-sparse candidate staging for ALL shards: one
         (blocks u32[B, 2048], global_row i32[B], slot i32[B],
@@ -973,8 +977,13 @@ class DeviceStager:
         * chunk + local candidate index. One kernel dispatch then
         scores the whole index's chunk (ops.sparse_intersection_counts_
         stacked). Returns None when no shard has candidates. No delta
-        path (see sparse_rows)."""
+        path (see sparse_rows). ``peek``: stage nothing, only say
+        whether the bundle is staged or being built (_holds)."""
         from pilosa_tpu.executor.batcher import _next_pow2
+
+        key = self._stack_key(frags, "sparse_stack", (chunk, ids_by_shard))
+        if peek:
+            return self._holds(key, self._stack_gen(frags))
 
         def build():
             gens = self._stack_gen(frags)
@@ -1016,7 +1025,7 @@ class DeviceStager:
             return dev, nbytes, gens
 
         return self._get_or_build(
-            self._stack_key(frags, "sparse_stack", (chunk, ids_by_shard)),
+            key,
             self._stack_gen(frags),
             build,
             self._sparse_fallback_for("sparse_stack"),
@@ -1024,7 +1033,11 @@ class DeviceStager:
         )
 
     def sparse_rows_stack(
-        self, frags, ids_by_shard: tuple[tuple[int, ...], ...], k: int
+        self,
+        frags,
+        ids_by_shard: tuple[tuple[int, ...], ...],
+        k: int,
+        peek: bool = False,
     ):
         """Shard-major block-sparse candidate staging for the MESH TopN
         path: (blocks u32[S, B, 2048], brow i32[S, B], bslot i32[S, B])
@@ -1034,8 +1047,12 @@ class DeviceStager:
         sparse analog of rows_stack (SURVEY.md §7 hard part 2). Padding
         blocks are zeros aimed at (row 0, slot 0): they contribute 0 to
         every intersection. Returns None when no shard has blocks. No
-        delta path (see sparse_rows)."""
+        delta path (see sparse_rows). ``peek`` as in sparse_rows_stacked."""
         from pilosa_tpu.executor.batcher import _next_pow2
+
+        key = self._stack_key(frags, "sparse_rows_stack", (k, ids_by_shard))
+        if peek:
+            return self._holds(key, self._stack_gen(frags))
 
         def build():
             gens = self._stack_gen(frags)
@@ -1081,7 +1098,7 @@ class DeviceStager:
             return dev, w32.nbytes + brow.nbytes + bslot.nbytes, gens
 
         return self._get_or_build(
-            self._stack_key(frags, "sparse_rows_stack", (k, ids_by_shard)),
+            key,
             self._stack_gen(frags),
             build,
             self._sparse_fallback_for("sparse_rows_stack"),
@@ -1113,6 +1130,17 @@ class DeviceStager:
             delta,
             frag=frags,
         )
+
+    def _holds(self, key, gen) -> bool:
+        """A peek for advisory staging: is ``key`` staged fresh for
+        ``gen``, or being built? Counts no hit and leaves the LRU order
+        alone: a chunk staged ahead that no walk reads should be the
+        first to go."""
+        with self._mu:
+            ent = self._cache.get(key)
+            return key in self._inflight or (
+                ent is not None and _gen_fresh(ent.gen, gen)
+            )
 
     def has_room(self, nbytes: int) -> bool:
         """Would an entry of ``nbytes`` fit without evicting anything?

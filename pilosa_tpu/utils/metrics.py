@@ -118,6 +118,9 @@ TIERING_UPLOAD_BYTES_SAVED = "tiering.upload_bytes_saved"
 PREFETCH_ISSUED = "tiering.prefetch_issued"
 PREFETCH_USED = "tiering.prefetch_used"
 PREFETCH_EVICTED = "tiering.prefetch_evicted"
+# the TopN walk's advisory stage-ahead of its next candidate chunk
+TOPN_PREFETCH_DECISIONS = "topn.prefetch_decisions"
+TOPN_PREFETCH_STARTS = "topn.prefetch_starts"
 # TopN rank/LRU caches
 CACHE_HITS = "cache.hits"
 CACHE_MISSES = "cache.misses"
@@ -413,6 +416,18 @@ METRICS: dict[str, tuple[str, str]] = {
     PREFETCH_EVICTED: (
         "counter",
         "prefetched blocks evicted unused — wasted prefetch bandwidth",
+    ),
+    TOPN_PREFETCH_DECISIONS: (
+        "counter",
+        "times a deep TopN walk asked whether its next candidate chunk "
+        "fits the stager without evicting (label: how = bound, settled by "
+        "one block per candidate; memo, block counts read from the "
+        "rankings snapshot; counted, blocks counted in the occupancy index)",
+    ),
+    TOPN_PREFETCH_STARTS: (
+        "counter",
+        "stage-prefetch threads started: the next chunk fits and the "
+        "stager does not hold it yet",
     ),
     CACHE_HITS: ("counter", "TopN rank/LRU cache hits"),
     CACHE_MISSES: ("counter", "TopN rank/LRU cache misses"),

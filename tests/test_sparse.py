@@ -3,6 +3,7 @@ pass and executor-level bit-identity on tall sparse fragments (the
 1B-row regime where dense candidate staging is not a memory plan)."""
 
 import numpy as np
+import pytest
 
 from pilosa_tpu import SHARD_WIDTH, ops
 from pilosa_tpu.core import Holder
@@ -334,3 +335,251 @@ class TestAdvisoryPrefetchNeverEvicts:
         counts = [3, 0, 9, 5]
         assert _StackedLazyScores._bundle_blocks(None, counts) == 32
         assert _SpmdLazyScores._bundle_blocks(None, counts) == 4 * 16
+
+
+def _counter(name, **labels):
+    from pilosa_tpu.utils import metrics
+
+    key = metrics._flat_key(name, metrics._labels_key(labels))
+    return metrics.snapshot().get(key, 0)
+
+
+def _decisions():
+    from pilosa_tpu.utils import metrics
+
+    return {
+        how: _counter(metrics.TOPN_PREFETCH_DECISIONS, how=how)
+        for how in ("bound", "memo", "counted")
+    }
+
+
+class TestPrefetchDecisionCost:
+    """The advisory prefetch's question is asked by every request of a
+    deep walk, so its cost follows what the walk reads: the block counts
+    of the next chunk are kept with the rankings snapshot and the
+    fragment generation, and a chunk the stager holds starts no thread."""
+
+    Q = "TopN(f, Row(f=0), n=5)"
+
+    @staticmethod
+    def _spy_threads(monkeypatch):
+        import threading
+
+        started = []
+
+        class Spy(threading.Thread):
+            def start(self):
+                started.append(self.name)
+                super().start()
+
+        monkeypatch.setattr(threading, "Thread", Spy)
+        return started
+
+    @pytest.mark.parametrize(
+        "budget, first, staged",
+        [
+            (None, "counted", [128, 4096, 8192]),
+            (70 << 20, "bound", [128, 4096]),
+        ],
+        ids=["with_room", "without_room"],
+    )
+    def test_second_query_counts_nothing_and_starts_no_thread(
+        self, tmp_path, monkeypatch, budget, first, staged
+    ):
+        from pilosa_tpu.executor.stager import DeviceStager
+        from pilosa_tpu.utils import metrics
+
+        deep = TestAdvisoryPrefetchNeverEvicts
+        h = deep._deep_walk_holder(tmp_path)
+        stager = DeviceStager(budget_bytes=budget) if budget else None
+        ex = Executor(h, device_policy="always", stager=stager)
+        cpu = Executor(h, device_policy="never")
+        want = cpu.execute("i", self.Q)
+        started = self._spy_threads(monkeypatch)
+        starts = _counter(metrics.TOPN_PREFETCH_STARTS)
+
+        # the first query decides by `first` (and stages ahead where
+        # there is room); the second reads what the first left
+        again = "memo" if first == "counted" else "bound"
+        threads = 1 if budget is None else 0
+        for how in (first, again):
+            before = _decisions()
+            assert ex.execute("i", self.Q) == want
+            assert deep._staged_chunks(ex) == staged
+            after = _decisions()
+            grown = {k: after[k] - before[k] for k in after}
+            assert grown == {"bound": 0, "memo": 0, "counted": 0, how: 1}
+            assert started.count("stage-prefetch") == threads
+        assert _counter(metrics.TOPN_PREFETCH_STARTS) - starts == threads
+        h.close()
+
+    def test_a_set_into_the_next_chunk_is_counted_and_seen(
+        self, tmp_path, monkeypatch
+    ):
+        from pilosa_tpu.core import cache as cache_mod
+
+        # no recalculate between the queries: the Set must show through
+        # the generation, under the same rankings snapshot
+        monkeypatch.setattr(cache_mod, "INVALIDATE_DEBOUNCE_SECONDS", 1e9)
+        h = TestAdvisoryPrefetchNeverEvicts._deep_walk_holder(tmp_path)
+        fld = h.index("i").field("f")
+        # 96 more one-bit rows a shard: the third chunk then holds 512
+        # candidates a shard, 1024 blocks, and one more container
+        # crosses the bundle's power of two
+        rows, cols = [], []
+        for shard in range(2):
+            for r in range(96):
+                rows.append(5500 + r)
+                cols.append(shard * SHARD_WIDTH + 100 + 4500 + r)
+        fld.import_bits(rows, cols)
+        for shard in range(2):
+            h.fragment("i", "f", "standard", shard).cache.recalculate()
+        ex = Executor(h, device_policy="always")
+        cpu = Executor(h, device_policy="never")
+        asked = []
+        real = ex.stager.has_room
+
+        def has_room(nbytes):
+            asked.append(nbytes)
+            return real(nbytes)
+
+        monkeypatch.setattr(ex.stager, "has_room", has_room)
+        block = 8192
+        assert ex.execute("i", self.Q) == cpu.execute("i", self.Q)
+        assert asked == [1024 * block, 1024 * block]
+        TestAdvisoryPrefetchNeverEvicts._staged_chunks(ex)
+
+        frag = h.fragment("i", "f", "standard", 0)
+        snap = frag.cache.top()
+        third = snap[4224 + 10][0]
+        assert snap.chunk_blocks(4224, 12416, frag) == (512, False)
+        assert ex.execute("i", f"Set({3 * 65536 + 7}, f={third})") == [True]
+        assert frag.cache.top() is snap
+        before = _decisions()
+        del asked[:]
+        assert ex.execute("i", self.Q) == cpu.execute("i", self.Q)
+        after = _decisions()
+        assert after["counted"] - before["counted"] == 1
+        assert after["memo"] == before["memo"]
+        # the bound still says 1024, the count says 1025 -> 2048
+        assert asked == [1024 * block, 2048 * block]
+        assert snap.chunk_blocks(4224, 12416, frag) == (513, False)
+        TestAdvisoryPrefetchNeverEvicts._staged_chunks(ex)
+        h.close()
+
+    def test_per_shard_scorer_reads_the_same_memo(self, tmp_path):
+        from pilosa_tpu.executor.executor import _chunk_blocks
+
+        h = _sparse_fragment(tmp_path)
+        frag = h.fragment("i", "f", "standard", 0)
+        snap = frag.cache.top()
+        ids = [p[0] for p in snap[0:128]]
+        want = frag.sparse_block_count(ids)
+        assert _chunk_blocks(snap, 0, 128, frag) == (want, True)
+        assert _chunk_blocks(snap, 0, 128, frag) == (want, False)
+        # a plain list (an ids= walk) has no memo and counts as before
+        assert _chunk_blocks(list(snap), 0, 128, frag) == (want, True)
+        assert _chunk_blocks(list(snap), 0, 128, frag) == (want, True)
+        h.close()
+
+
+def _old_seed(chunks, rids):
+    """_ScoreCarry.seed as it was: a dict of every scored id, built to
+    look up the few ids asked for."""
+    lut = {}
+    for ids, scores in chunks:
+        lut.update(zip(ids, scores[: len(ids)].tolist()))
+    return {rid: int(lut[rid]) for rid in rids if rid in lut}
+
+
+@pytest.mark.parametrize("snapshot", [True, False], ids=["rankings", "list"])
+@pytest.mark.parametrize("stacked", [True, False], ids=["stacked", "per_shard"])
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_carry_seed_equals_the_old_form(seed, stacked, snapshot):
+    from pilosa_tpu.core.cache import Rankings
+    from pilosa_tpu.executor.executor import _ScoreCarry, _chunk_size
+
+    rng = np.random.default_rng(seed)
+    shards = [3, 5, 8, 13]
+    pairs_by_shard = []
+    for _ in shards:
+        n = int(rng.integers(0, 6000))
+        ids = rng.choice(20000, size=n, replace=False).tolist()
+        pairs = [(i, int(c)) for i, c in zip(ids, rng.integers(1, 99, size=n))]
+        pairs_by_shard.append(Rankings(pairs) if snapshot else pairs)
+    carry = _ScoreCarry()
+    old = {s: [] for s in shards}
+    lo = 0
+    for _ in range(int(rng.integers(1, 4))):
+        size = _chunk_size(lo)
+        # score rows padded past the chunk's ids, as the kernels return
+        mat = rng.integers(0, 1 << 20, size=(len(shards), size), dtype=np.int32)
+        if stacked:
+            carry.add_stacked(shards, pairs_by_shard, lo, mat)
+        for i, s in enumerate(shards):
+            ids = tuple(p[0] for p in pairs_by_shard[i][lo : lo + size])
+            if not stacked:
+                carry.add(s, pairs_by_shard[i], lo, lo + size, mat[i])
+            if ids:
+                old[s].append((ids, mat[i]))
+        lo += size
+    assert bool(carry) == any(old.values())
+    for i, s in enumerate(shards):
+        scored = [p[0] for p in pairs_by_shard[i][:lo]]
+        absent = [p[0] for p in pairs_by_shard[i][lo:]][:50] + [20001, 20002]
+        rids = rng.permutation(scored[:: max(len(scored) // 40, 1)] + absent).tolist()
+        got = carry.seed(s, rids)
+        assert got == _old_seed(old[s], rids)
+        assert list(got) == list(_old_seed(old[s], rids))
+        assert all(type(v) is int for v in got.values())
+        assert carry.seed(s, []) == {}
+    assert carry.seed(99, [1, 2]) == {}
+
+
+@pytest.mark.parametrize("cache_type", ["ranked", "lru"])
+def test_top_bitmap_pairs_counts_reads_once(tmp_path, cache_type):
+    from pilosa_tpu.core import cache as cache_mod
+    from pilosa_tpu.core.field import FieldOptions
+    from pilosa_tpu.utils import metrics
+
+    h = Holder(str(tmp_path / "data"))
+    h.open()
+    fld = h.create_index("i").create_field(
+        "f", FieldOptions(cache_type=cache_type, cache_size=50)
+    )
+    rng = np.random.default_rng(7)
+    rows, cols = [], []
+    for r in range(120):
+        k = int(rng.integers(1, 30))
+        rows += [r] * k
+        cols += rng.choice(SHARD_WIDTH, size=k, replace=False).tolist()
+    fld.import_bits(rows, cols)
+    frag = h.fragment("i", "f", "standard", 0)
+    # cached ids, ids that fell out of the cache, ids with no bits
+    ids = rng.permutation(140).tolist() + [100000]
+
+    def per_id():
+        pairs, missing = [], []
+        for row_id in ids:
+            n = frag.cache.get(row_id)
+            if n > 0:
+                pairs.append((row_id, n))
+            else:
+                missing.append(row_id)
+        counts = frag.row_counts_for(np.asarray(missing, dtype=np.uint64))
+        pairs += [(r, int(c)) for r, c in zip(missing, counts) if c > 0]
+        return cache_mod.sort_pairs(pairs)
+
+    def reads():
+        return _counter(metrics.CACHE_HITS), _counter(metrics.CACHE_MISSES)
+
+    h0, m0 = reads()
+    want = per_id()
+    h1, m1 = reads()
+    got = frag._top_bitmap_pairs(ids)
+    h2, m2 = reads()
+    assert got == want
+    assert (h1 - h0) + (m1 - m0) == len(ids)
+    assert (h2 - h1, m2 - m1) == (h1 - h0, m1 - m0)
+    assert 0 < h2 - h1 < len(ids)
+    h.close()
