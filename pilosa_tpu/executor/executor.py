@@ -2882,7 +2882,10 @@ class _ChunkedLazyScores:
             self._srcs = self._srcs()
         return self._srcs
 
-    def _score_next(self) -> None:
+    def _score_next(self, ends_walk: bool = False) -> None:
+        """Stage and score the next chunk. ``ends_walk``: the caller's
+        walk cannot read past this chunk (_chunk_ends_walk), so the one
+        after it is not staged ahead."""
         lo = self._pos
         size = _chunk_size(lo)
         hi = lo + size
@@ -2902,7 +2905,7 @@ class _ChunkedLazyScores:
         # runs here, on the request's thread, before this chunk's
         # kernel is launched: it has to cost what the walk reads (one
         # number a shard), not what the next chunk holds (_prefetch).
-        if lo > 0 and hi < self._max_len:
+        if lo > 0 and hi < self._max_len and not ends_walk:
             with trace.leg(trace.WF_TOPN_CANDIDATES):
                 self._prefetch(hi)
         if staged is None:  # no shard contributed blocks — all score 0
@@ -3091,10 +3094,13 @@ class _SpmdLazyScores(_ChunkedLazyScores):
         dev = self._ex._spmd_kernel("topn_scores_sparse", size)(
             self._resolved_srcs(), blocks, brow, bslot
         )
-        # trim BEFORE the fetch: the shard axis is mesh-padded, so
-        # slicing on device transfers only the real shards' scores
-        # instead of fetching the padded plan and slicing on host
-        return _fetch(dev[: len(self._frags), :size])
+        # the kernel's [S, k] is the chunk's shape already (frags is the
+        # mesh-padded plan, k the chunk size), gathered to every device:
+        # no trim, an eager launch over the whole mesh, and the copy
+        # reads one replica. What the mesh adds on the host once the
+        # kernel is fenced has a leg of its own.
+        with trace.leg(trace.WF_MESH_FETCH):
+            return np.asarray(dev)
 
 
 class _LazyScores:
@@ -3260,7 +3266,27 @@ def _vectorized_topn_walk(pairs_by_shard, provider, opt_: TopOptions):
             # implies exhausted.all()); bail to the scalar walk rather
             # than risk looping
             return None
-        provider._score_next()
+        provider._score_next(
+            ends_walk=_chunk_ends_walk(pairs_by_shard, P, has_n, T, mth)
+        )
+
+
+def _chunk_ends_walk(pairs_by_shard, pos: int, has_n, T, mth: int) -> bool:
+    """Will every shard's walk end inside the chunk at ``pos``, whatever
+    it scores? A shard's does where the chunk holds its last candidate,
+    or where its threshold is fixed (``has_n``) and the chunk holds an
+    eligible candidate whose cached count is below it: the break.
+    Cached counts fall along a ranked list, so the chunk's last
+    candidate decides. Then no walk reads the chunk after this one, and
+    staging it ahead would build and hold a bundle for nothing (at 128
+    shards 8 GiB, assembled on a side thread while requests are
+    served). Advisory like the staging it steers: a list out of order
+    can mis-stage, never mis-answer."""
+    hi = pos + _chunk_size(pos)
+    for pairs, fixed, t in zip(pairs_by_shard, has_n.tolist(), T.tolist()):
+        if len(pairs) > hi and not (fixed and mth <= pairs[hi - 1][1] < t):
+            return False
+    return True
 
 
 def _merge_picked(ids: np.ndarray, counts: np.ndarray) -> list[tuple[int, int]]:

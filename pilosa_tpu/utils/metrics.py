@@ -419,8 +419,9 @@ METRICS: dict[str, tuple[str, str]] = {
     ),
     TOPN_PREFETCH_DECISIONS: (
         "counter",
-        "times a deep TopN walk asked whether its next candidate chunk "
-        "fits the stager without evicting (label: how = bound, settled by "
+        "times a deep TopN walk that may read on asked whether its next "
+        "candidate chunk fits the stager without evicting; a walk sure to "
+        "end in the chunk it scores does not ask (label: how = bound, settled by "
         "one block per candidate; memo, block counts read from the "
         "rankings snapshot; counted, blocks counted in the occupancy index)",
     ),

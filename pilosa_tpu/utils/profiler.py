@@ -57,7 +57,11 @@ class WaterfallAggregator:
     deque append."""
 
     # buckets that count as device-side for rtt_fraction
-    DEVICE_STAGES = (trace.WF_DEVICE_COMPUTE, trace.WF_TRANSFER_DECODE)
+    DEVICE_STAGES = (
+        trace.WF_DEVICE_COMPUTE,
+        trace.WF_TRANSFER_DECODE,
+        trace.WF_MESH_FETCH,
+    )
 
     def __init__(self, ring_size: int = 64, ema_alpha: float = 0.1) -> None:
         self._ring: deque[dict] = deque(maxlen=ring_size)

@@ -531,6 +531,7 @@ WF_GUARD_QUEUE = "guard.queue"
 WF_TOPN_CANDIDATES = "topn.candidates"
 WF_DEVICE_COMPUTE = "device.compute"
 WF_TRANSFER_DECODE = "transfer.decode"
+WF_MESH_FETCH = "mesh.fetch"
 WF_TOPN_WALK = "topn.walk"
 WF_REDUCE = "reduce"
 WF_RESPOND = "respond"
@@ -549,6 +550,7 @@ WATERFALL_STAGES: tuple = (
     WF_TOPN_CANDIDATES,
     WF_DEVICE_COMPUTE,
     WF_TRANSFER_DECODE,
+    WF_MESH_FETCH,
     WF_TOPN_WALK,
     WF_REDUCE,
     WF_RESPOND,
@@ -567,6 +569,7 @@ WATERFALL: dict = {
     WF_TOPN_CANDIDATES: "TopN ranked-cache snapshot and candidate chunk assembly",
     WF_DEVICE_COMPUTE: "host's wait on the device (launch → result ready)",
     WF_TRANSFER_DECODE: "device→host copy and result decode",
+    WF_MESH_FETCH: "mesh: copy of the gathered TopN scores from one replica",
     WF_TOPN_WALK: "TopN ranked walk, cross-shard merge, sort, pass-2 trim",
     WF_REDUCE: "host-side shard-result reduction",
     WF_RESPOND: "results → JSON bytes → last write",

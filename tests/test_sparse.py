@@ -266,10 +266,20 @@ class TestSparseTopN:
 
 class TestAdvisoryPrefetchNeverEvicts:
     """A hot set wider than the head chunk sends the TopN walk into the
-    second chunk, which stages the third ahead on a side thread. That
-    prefetch is advisory: where it does not fit it must be skipped, or
-    it pushes out the chunks the walk is scoring and every later query
-    stages all of them again."""
+    second chunk, which stages the third ahead on a side thread where
+    the walk may go on to it. That prefetch is advisory: where it does
+    not fit it must be skipped, or it pushes out the chunks the walk is
+    scoring and every later query stages all of them again; and where
+    the walk is sure to end in the second chunk it is not asked for."""
+
+    # n=5: every shard has its threshold after the head chunk and meets
+    # a cached count below it in the second, so the walk ends there.
+    # n=130: the head chunk's 128 rows fix no threshold, so the walk may
+    # go on past the second chunk and the third is asked for; the twelve
+    # hot rows left fix it there and the one-bit tail breaks the walk,
+    # so whatever holds the third chunk was staged ahead, not by the walk.
+    ENDS = "TopN(f, Row(f=0), n=5)"
+    MAY_GO_ON = "TopN(f, Row(f=0), n=130)"
 
     @staticmethod
     def _deep_walk_holder(tmp_path):
@@ -302,17 +312,37 @@ class TestAdvisoryPrefetchNeverEvicts:
             time.sleep(0.05)
         return sorted(k[2] for k in ex.stager._cache if k[1] == "sparse_stack")
 
-    def test_prefetch_runs_where_it_fits(self, tmp_path):
+    def test_prefetch_runs_where_it_fits_and_the_walk_may_go_on(self, tmp_path):
+        from pilosa_tpu.utils import metrics
+
         h = self._deep_walk_holder(tmp_path)
         ex = Executor(h, device_policy="always")
         cpu = Executor(h, device_policy="never")
-        q = "TopN(f, Row(f=0), n=5)"
-        assert ex.execute("i", q) == cpu.execute("i", q)
+        starts = _counter(metrics.TOPN_PREFETCH_STARTS)
+        assert ex.execute("i", self.MAY_GO_ON) == cpu.execute("i", self.MAY_GO_ON)
         assert self._staged_chunks(ex) == [128, 4096, 8192]
+        assert _counter(metrics.TOPN_PREFETCH_STARTS) == starts + 1
+        h.close()
+
+    def test_prefetch_is_not_asked_for_where_the_walk_ends_in_the_chunk(self, tmp_path):
+        """Without the walk's word the third chunk was built and held
+        for nothing: 8 GiB at 128 shards, assembled on a side thread
+        through the first half of the benchmark's window (PR 29)."""
+        from pilosa_tpu.utils import metrics
+
+        h = self._deep_walk_holder(tmp_path)
+        ex = Executor(h, device_policy="always")
+        cpu = Executor(h, device_policy="never")
+        before = _decisions(), _counter(metrics.TOPN_PREFETCH_STARTS)
+        for _ in range(2):
+            assert ex.execute("i", self.ENDS) == cpu.execute("i", self.ENDS)
+        assert self._staged_chunks(ex) == [128, 4096]
+        assert (_decisions(), _counter(metrics.TOPN_PREFETCH_STARTS)) == before
         h.close()
 
     def test_prefetch_is_skipped_where_it_would_evict(self, tmp_path):
         from pilosa_tpu.executor.stager import DeviceStager
+        from pilosa_tpu.utils import metrics
 
         h = self._deep_walk_holder(tmp_path)
         # room for the head chunk (2 MiB), the second (64 MiB) and the
@@ -321,12 +351,19 @@ class TestAdvisoryPrefetchNeverEvicts:
             h, device_policy="always", stager=DeviceStager(budget_bytes=70 << 20)
         )
         cpu = Executor(h, device_policy="never")
-        q = "TopN(f, Row(f=0), n=5)"
+        q = self.MAY_GO_ON
+        before = _decisions(), _counter(metrics.TOPN_PREFETCH_STARTS)
         assert ex.execute("i", q) == cpu.execute("i", q)
         assert self._staged_chunks(ex) == [128, 4096]
         misses = ex.stager.misses
         assert ex.execute("i", q) == cpu.execute("i", q)
         assert ex.stager.misses == misses  # nothing was pushed out
+        assert self._staged_chunks(ex) == [128, 4096]
+        # the third chunk was asked for by both walks and refused on the
+        # bound alone: no thread, no count of its blocks
+        after = _decisions(), _counter(metrics.TOPN_PREFETCH_STARTS)
+        assert after[0] == {**before[0], "bound": before[0]["bound"] + 2}
+        assert after[1] == before[1]
         h.close()
 
     def test_mesh_bundle_pads_every_shard_to_the_widest(self):
@@ -353,13 +390,42 @@ def _decisions():
     }
 
 
+def _ranked(counts):
+    return [(i, c) for i, c in enumerate(counts)]
+
+
+@pytest.mark.parametrize(
+    "lists, has_n, T, mth, ends",
+    [
+        # each shard has its threshold and the chunk's last cached count is under it
+        ([[9] * 130 + [1] * 5000] * 2, [True, True], [4, 7], 1, True),
+        # one shard's threshold is not fixed and its list goes on
+        ([[9] * 130 + [1] * 5000] * 2, [True, False], [4, 1 << 62], 1, False),
+        # ... but a list that ends inside the chunk ends the walk whatever it scores
+        ([[9] * 130 + [1] * 5000, [9] * 300], [True, False], [4, 1 << 62], 1, True),
+        # the last cached count still clears the threshold: the walk goes on
+        ([[9] * 5000], [True], [4], 1, False),
+        # a count under the minimum is no break, the walk reads on to the list's end
+        ([[9] * 130 + [1] * 5000], [True], [4], 2, False),
+        ([[]], [False], [1 << 62], 1, True),
+    ],
+    ids=["break_in_chunk", "no_threshold", "list_ends", "no_break", "below_minimum", "empty"],
+)
+def test_chunk_ends_walk(lists, has_n, T, mth, ends):
+    from pilosa_tpu.executor.executor import FIRST_CHUNK, _chunk_ends_walk
+
+    pairs = [_ranked(c) for c in lists]
+    got = _chunk_ends_walk(pairs, FIRST_CHUNK, np.array(has_n), np.array(T, dtype=np.int64), mth)
+    assert got is ends
+
+
 class TestPrefetchDecisionCost:
     """The advisory prefetch's question is asked by every request of a
     deep walk, so its cost follows what the walk reads: the block counts
     of the next chunk are kept with the rankings snapshot and the
     fragment generation, and a chunk the stager holds starts no thread."""
 
-    Q = "TopN(f, Row(f=0), n=5)"
+    Q = TestAdvisoryPrefetchNeverEvicts.MAY_GO_ON
 
     @staticmethod
     def _spy_threads(monkeypatch):
