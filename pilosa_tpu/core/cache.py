@@ -115,6 +115,21 @@ class Rankings(list):
             t = memo[(lo, hi)] = dict(zip(ids, range(len(ids))))
         return t
 
+    def chunk_sorted(self, lo: int, hi: int):
+        """(ids of self[lo:hi] ascending, the position in the chunk of
+        each, its cached count), memoized on the snapshot: TopN pass 2
+        finds all the winners of a shard with one ``searchsorted`` in
+        the prefix pass 1 scored (executor._ScoreCarry.answer)."""
+        memo = self._memo_of("_sorted_memo")
+        t = memo.get((lo, hi))
+        if t is None:
+            import numpy as np
+
+            ids, counts = self.chunk_arrays(lo, hi)
+            order = np.argsort(ids, kind="stable")
+            t = memo[(lo, hi)] = (ids[order], order, counts[order])
+        return t
+
     def chunk_blocks(self, lo: int, hi: int, frag) -> tuple[int, bool]:
         """(nonempty container blocks of self[lo:hi]'s rows in ``frag``,
         whether they had to be counted now). The count is kept with the
@@ -176,6 +191,14 @@ class RankCache:
         if self.entries.pop(id_, None) is not None:
             self.rankings = Rankings(p for p in self.rankings if p[0] != id_)
             self._dirty = True
+
+    def is_current(self, rankings) -> bool:
+        """Are ``entries`` what ``rankings`` was sorted from? True while
+        it is this cache's snapshot and nothing was added or removed
+        since: get() of any id in it then returns the count it ranks.
+        Ask under the lock the writers hold (Fragment.ranked_cache_is):
+        recalculate() clears the flag before it swaps the snapshot."""
+        return self.rankings is rankings and not self._dirty
 
     def __len__(self) -> int:
         return len(self.entries)

@@ -1265,6 +1265,23 @@ class Fragment:
             ]
         return cache_mod.sort_pairs(pairs)
 
+    def ranked_cache_is(self, rankings) -> bool:
+        """Is ``rankings`` (what an earlier ``_top_bitmap_pairs([])``
+        returned) still this fragment's ranked cache, entry for entry?
+        Then ``_top_bitmap_pairs(ids)`` would read, for every id in it,
+        the count it ranks, and a caller that kept the snapshot need not
+        ask again. Never true of an LRU or absent cache, whose reads
+        are not a snapshot's."""
+        if self.cache_type != cache_mod.CACHE_TYPE_RANKED:
+            return False
+        with self.mu:
+            return self.cache.is_current(rankings)
+
+    def recalculate_cache(self) -> None:
+        """Re-rank the cache now, under the lock its writers hold."""
+        with self.mu:
+            self.cache.recalculate()
+
     # -- bulk import (reference bulkImport:1296-1397) ------------------------
 
     def bulk_import(self, row_ids: Iterable[int], column_ids: Iterable[int]) -> None:

@@ -134,51 +134,49 @@ class _NotDeviceable(Exception):
 
 
 class _ScoreCarry:
-    """Cross-pass TopN score carry: pass 1's chunk scores, appended as
-    whole arrays; pass 2 looks up the ids it asks for.
+    """Cross-pass TopN score carry: the providers pass 1 scored with, as
+    they stand (score matrix, fragments, candidate lists). Nothing is
+    copied out of them and no per-id dict is built.
 
-    Pass 2 only needs the union winners' counts (~n ids per shard), so
-    nothing here is proportional to what pass 1 scored: add is O(1) per
-    chunk and shard, and seed() finds each requested id through the
-    chunk's id -> position index, which a Rankings snapshot keeps
-    (core.cache.Rankings.chunk_index) and a plain list builds."""
+    Pass 2 needs every winner's exact count in every shard. answer()
+    reads whole shards at a time from the matrices: one searchsorted a
+    shard, the gathers and compares stacked over the shards. seed()
+    serves the per-id pass of the shards answer() leaves, through the
+    scored prefix's id -> position index, which a Rankings snapshot
+    keeps (core.cache.Rankings.chunk_index) and a plain list builds."""
 
-    __slots__ = ("_by_shard", "_n")
+    __slots__ = ("_scored", "_by_shard", "answered")
 
     def __init__(self) -> None:
-        # shard -> [(pairs, lo, hi, scores), ...]: seed() is called once
-        # PER SHARD at pass-2 provider init (64 calls/query on the tall
-        # config), so a flat chunk list would be rescanned 64x
-        self._by_shard: dict[int, list] = {}
-        self._n = 0
+        self._scored: list = []  # providers, in the order they attached
+        # seed() is called once PER SHARD at a pass-2 provider's init
+        self._by_shard: dict[int, list] = {}  # shard -> [(provider, row)]
+        # shards answer() has summed: the per-id pass 2 reads nothing
+        # of them, whichever route it takes
+        self.answered: set[int] = set()
 
     def __len__(self) -> int:  # `if carry:` seeds only when non-empty
-        return self._n
+        return len(self._scored)
 
-    def add(self, shard: int, pairs, lo: int, hi: int, scores) -> None:
-        """``scores[j]`` is the score of ``pairs[lo + j]``'s id; the row
-        may be pow2- or chunk-size-padded past the chunk's ids, which
-        no position of the index reaches."""
-        if len(pairs) > lo:
-            self._by_shard.setdefault(shard, []).append((pairs, lo, hi, scores))
-            self._n += 1
-
-    def add_stacked(self, shards, pairs_by_shard, lo: int, mat) -> None:
-        hi = lo + mat.shape[1]
-        for i, pairs in enumerate(pairs_by_shard):
-            self.add(shards[i], pairs, lo, hi, mat[i])
+    def attach(self, provider) -> None:
+        """``provider.scored()`` is (shards, fragments, candidate lists,
+        scores i32[S, P] or None): ``scores[i, j]`` is the score of the
+        id at position ``j`` of list ``i``; a row may be padded past its
+        list, which no position of an index reaches."""
+        self._scored.append(provider)
+        for row, shard in enumerate(provider.scored()[0]):
+            self._by_shard.setdefault(shard, []).append((provider, row))
 
     def seed(self, shard: int, rids) -> dict[int, int]:
-        """{rid: score} for the requested ids present in this carry.
-        Chunks are disjoint id ranges per shard (prefix walks), so no
-        overwrite ambiguity."""
-        chunks = self._by_shard.get(shard)
-        if not chunks or not rids:
+        """{rid: score} for the requested ids present in this carry."""
+        if not rids:
             return {}
-        found = [
-            (_chunk_index(pairs, lo, hi).get, scores)
-            for pairs, lo, hi, scores in chunks
-        ]
+        found = []
+        for provider, row in self._by_shard.get(shard, ()):
+            _, _, pairs_by_shard, scores = provider.scored()
+            if scores is not None and pairs_by_shard[row]:
+                index = _chunk_index(pairs_by_shard[row], 0, scores.shape[1])
+                found.append((index.get, scores[row]))
         out: dict[int, int] = {}
         for rid in rids:
             for position, scores in found:
@@ -187,6 +185,65 @@ class _ScoreCarry:
                     out[rid] = int(scores[j])
                     break
         return out
+
+    def answer(self, winners: list[int], mth: int) -> list[tuple[int, int]]:
+        """Pass 2 of a plain threshold walk for the shards it can be
+        read off for, which ``answered`` then names: the winners' pairs
+        summed over them.
+
+        A shard is answered here where its ranked cache still is the
+        snapshot pass 1 walked (Fragment.ranked_cache_is: the counts
+        _top_bitmap_pairs(winners) would read are then the snapshot's)
+        and every winner sits in the prefix pass 1 scored. There the
+        per-id pass picks a winner whose cached count and score both
+        reach ``mth``, and so does this; its ``sort_pairs`` order never
+        reaches the result, which the caller sorts. Any other shard is
+        left whole to the per-id pass: one a write reached, one that
+        lacks a winner or ranks it beyond the prefix (recounted from
+        storage, scored on the device), an LRU cache. A shard with no
+        fragment has nothing to read either way."""
+        w = np.asarray(winners, dtype=np.int64)
+        sums = np.zeros(w.size, dtype=np.int64)
+        done = self.answered
+        read = 0
+        for provider in self._scored:
+            shards, frags, pairs_by_shard, scores = provider.scored()
+            if scores is None:
+                continue
+            rows = []
+            for i, (shard, frag) in enumerate(zip(shards, frags)):
+                if shard in done:
+                    continue
+                if frag is None:
+                    done.add(shard)
+                elif pairs_by_shard[i] and frag.ranked_cache_is(pairs_by_shard[i]):
+                    rows.append(i)
+            if not rows:
+                continue
+            ids = np.empty((len(rows), w.size), dtype=np.int64)
+            at = np.empty_like(ids)
+            cached = np.empty_like(ids)
+            prefix = scores.shape[1]
+            for i, ids_i, at_i, cached_i in zip(rows, ids, at, cached):
+                sids, position, counts = pairs_by_shard[i].chunk_sorted(0, prefix)
+                k = sids.searchsorted(w)
+                sids.take(k, out=ids_i, mode="clip")
+                position.take(k, out=at_i, mode="clip")
+                counts.take(k, out=cached_i, mode="clip")
+            # a cached count of 0 is one _top_bitmap_pairs would recount
+            held = (ids == w) & (cached > 0)
+            whole = held.all(axis=1)
+            sc = scores[np.asarray(rows)[:, None], at]
+            picked = whole[:, None] & (cached >= mth) & (sc >= mth)
+            sums += np.where(picked, sc, 0).sum(axis=0, dtype=np.int64)
+            covered = [shards[i] for i, ok in zip(rows, whole.tolist()) if ok]
+            done.update(covered)
+            read += len(covered)
+        if read:
+            metrics.count(metrics.CACHE_HITS, value=read * w.size)
+            metrics.count(metrics.TOPN_PASS2_IDS, value=read * w.size, how="vector")
+        some = np.flatnonzero(sums)
+        return list(zip(w[some].tolist(), sums[some].tolist()))
 
 
 def _eval_tree(t, leaves):
@@ -2304,10 +2361,31 @@ class Executor:
             )
             if not pairs or ids_arg or opt.remote:
                 return _pairs_result(pairs)
-            # Pass 2: re-query the union of candidate ids for exact counts.
-            other = c.clone()
-            other.args["ids"] = sorted(p[0] for p in pairs)
-            trimmed = self._execute_topn_shards(index, other, shards, opt, carry)
+            # Pass 2: the union of candidate ids, counted exactly in
+            # every shard. Pass 1's matrices answer the shards they can
+            # (_ScoreCarry.answer); the rest are re-queried per id.
+            winners = sorted(p[0] for p in pairs)
+            trimmed = []
+            if _plain_threshold_walk(c):
+                threshold, _ = c.uint_arg("threshold")
+                trimmed = carry.answer(
+                    winners, max(int(threshold), DEFAULT_MIN_THRESHOLD)
+                )
+            rest = sum(s not in carry.answered for s in shards)
+            if rest:
+                # over all the shards, the answered ones empty-handed:
+                # routes, shapes and staging keys stay what a per-id
+                # pass 2 over every shard has
+                metrics.count(
+                    metrics.TOPN_PASS2_IDS, value=rest * len(winners), how="scalar"
+                )
+                other = c.clone()
+                other.args["ids"] = winners
+                trimmed = pairs_add(
+                    trimmed,
+                    self._execute_topn_shards(index, other, shards, opt, carry),
+                )
+            trimmed = sort_pairs(trimmed)
             if n and n < len(trimmed):
                 trimmed = trimmed[:n]
             return _pairs_result(trimmed)
@@ -2382,10 +2460,7 @@ class Executor:
                 for s in shards
             )
             with trace.leg(trace.WF_TOPN_CANDIDATES):
-                pairs_by_shard = [
-                    f._top_bitmap_pairs(row_ids) if f is not None else []
-                    for f in frags
-                ]
+                pairs_by_shard = _candidate_pairs(frags, shards, row_ids, carry)
         if not any(pairs_by_shard):
             return []
         # lazy: a pass 2 fully covered by the carry never resolves the
@@ -2412,7 +2487,6 @@ class Executor:
             provider._mats.append(mat0)
             provider._chunk_meta.append((0, mat0.shape[1], ids0))
             provider._pos = mat0.shape[1]
-            provider._publish(0, mat0)
         opt_ = TopOptions(
             n=int(n),
             src=None,
@@ -2465,10 +2539,7 @@ class Executor:
             self.holder.fragment(index, field, VIEW_STANDARD, s) for s in batch
         )
         with trace.leg(trace.WF_TOPN_CANDIDATES):
-            pairs_by_shard = [
-                f._top_bitmap_pairs(row_ids) if f is not None else []
-                for f in frags
-            ]
+            pairs_by_shard = _candidate_pairs(frags, batch, row_ids, carry)
         if not any(pairs_by_shard):
             return []
         # carry-seeded provider: pass 2's id subset was scored by pass 1
@@ -2513,6 +2584,8 @@ class Executor:
         min_threshold, has_threshold = c.uint_arg("threshold")
         attr_values = c.args.get("attrValues") or []
         tanimoto, _ = c.uint_arg("tanimotoThreshold")
+        if carry is not None and shard in carry.answered:
+            return []
 
         src = None
         if len(c.children) == 1:
@@ -2854,20 +2927,22 @@ class _ChunkedLazyScores:
         self._mats: list[np.ndarray] = []
         self._chunk_meta: list[tuple] = []  # (lo, size, ids_by_shard)
         self._fanned = 0
+        self._smat = None
         self._mat_cache = None
         # cross-pass score carry: TopN pass 2 re-reads counts pass 1
         # already computed (same source bitmap, same fragment snapshot —
         # both constant within one _execute_topn) — seeding from the
-        # carry makes pass 2 dispatch only for (shard, id) pairs no
-        # pass-1 chunk covered
+        # carry makes a per-id pass 2 dispatch only for (shard, id)
+        # pairs no pass-1 chunk covered
         self._shards = list(shards) if shards is not None else list(range(len(frags)))
-        self._carry = carry
         self._prefetching = False  # one prefetch in flight at a time
         if carry:
             for i, s in enumerate(self._shards):
                 seed = carry.seed(s, [rid for rid, _ in pairs_by_shard[i]])
                 if seed:
                     self._scores[i].update(seed)
+        if carry is not None:
+            carry.attach(self)
 
     def _stage(self, ids_by_shard, size: int, peek: bool = False):
         """The staged bundle for a chunk; ``peek``: whether the stager
@@ -2914,7 +2989,6 @@ class _ChunkedLazyScores:
             mat = self._score(staged, size)
         self._mats.append(mat)
         self._chunk_meta.append((lo, size, ids_by_shard))
-        self._publish(lo, mat)
 
     def _fanout(self) -> None:
         """Populate the per-shard id->score dicts from chunk matrices
@@ -2927,6 +3001,17 @@ class _ChunkedLazyScores:
                     self._scores[i].update(zip(ids, mat[i].tolist()))
             self._fanned += 1
 
+    def scored(self):
+        """What the cross-pass carry reads (_ScoreCarry.attach): scores
+        i32[S, P] over the scored prefix, memoized per chunk count."""
+        k = len(self._mats)
+        if self._smat is None or self._smat[0] != k:
+            smat = None
+            if k:
+                smat = np.concatenate(self._mats, axis=1) if k > 1 else self._mats[0]
+            self._smat = (k, smat)
+        return self._shards, self._frags, self._pairs, self._smat[1]
+
     def matrices(self):
         """(scores i32[S, P], ids i64[S, P], counts i64[S, P],
         valid bool[S, P]) over the scored prefix; memoized per chunk
@@ -2936,9 +3021,7 @@ class _ChunkedLazyScores:
             return self._mat_cache[1]
         with trace.leg(trace.WF_TOPN_CANDIDATES):
             S = len(self._frags)
-            smat = (
-                np.concatenate(self._mats, axis=1) if k > 1 else self._mats[0]
-            )
+            smat = self.scored()[3]
             P = smat.shape[1]
             idm = np.full((S, P), -1, dtype=np.int64)
             cntm = np.zeros((S, P), dtype=np.int64)
@@ -3012,11 +3095,6 @@ class _ChunkedLazyScores:
         threading.Thread(
             target=warm, name="stage-prefetch", daemon=True
         ).start()
-
-    def _publish(self, lo: int, mat) -> None:
-        if self._carry is None:
-            return
-        self._carry.add_stacked(self._shards, self._pairs, lo, mat)
 
     def view(self, shard_index: int) -> "_ShardScoreView":
         return _ShardScoreView(self, shard_index)
@@ -3133,9 +3211,17 @@ class _LazyScores:
         # cross-pass carry, same contract as _StackedLazyScores: pass 2
         # reads counts pass 1 computed for this (shard, src) pair
         self._shard = shard
-        self._carry = carry
+        self._chunks: list[np.ndarray] = []
         if carry:
             self._scores.update(carry.seed(shard, [rid for rid, _ in pairs]))
+        if carry is not None:
+            carry.attach(self)
+
+    def scored(self):
+        """One shard's _ChunkedLazyScores.scored(): a chunk's scores end
+        with its ids, and only the list's last chunk is short."""
+        scores = np.concatenate(self._chunks)[None, :] if self._chunks else None
+        return (self._shard,), (self._frag,), (self._pairs,), scores
 
     def _score_chunk(self) -> None:
         # ids materialise per chunk, never as one huge tuple — on a 50k-
@@ -3167,13 +3253,31 @@ class _LazyScores:
                 (id(frag), id(mat)), mat, self._src, trim=len(ids)
             )
         self._scores.update(zip(ids, (int(s) for s in scores)))
-        if self._carry is not None:
-            self._carry.add(self._shard, self._pairs, lo, lo + size, scores)
+        self._chunks.append(scores)
 
     def __getitem__(self, row_id: int) -> int:
         while row_id not in self._scores and self._next < len(self._pairs):
             self._score_chunk()
         return self._scores[row_id]
+
+
+def _candidate_pairs(frags, shards, row_ids, carry) -> list:
+    """Each shard's candidate list for a cross-shard TopN pass; none for
+    a shard without a fragment or one pass 2 is already answered for."""
+    answered = carry.answered if carry is not None else ()
+    return [
+        f._top_bitmap_pairs(row_ids) if f is not None and s not in answered else []
+        for f, s in zip(frags, shards)
+    ]
+
+
+def _plain_threshold_walk(c: Call) -> bool:
+    """Does TopN call ``c`` pick by cached count and score against the
+    threshold alone? A tanimoto or attribute filter looks at each
+    (shard, id) again in pass 2 (_ranked_walk)."""
+    tanimoto, _ = c.uint_arg("tanimotoThreshold")
+    attr_name, _ = c.string_arg("attrName")
+    return not tanimoto and not (attr_name and c.args.get("attrValues"))
 
 
 def _vectorized_topn_walk(pairs_by_shard, provider, opt_: TopOptions):

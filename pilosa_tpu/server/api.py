@@ -965,7 +965,7 @@ class API:
             for f in idx.fields.values():
                 for v in f.views.values():
                     for frag in v.fragments.values():
-                        frag.cache.recalculate()
+                        frag.recalculate_cache()
         # rank reorders can change TopN candidate walks without any
         # fragment generation bump — cached TopN results are stale
         pc = getattr(self.executor, "plan_cache", None)

@@ -1330,7 +1330,7 @@ class Server:
                 for f in idx.fields.values():
                     for v in f.views.values():
                         for frag in v.fragments.values():
-                            frag.cache.recalculate()
+                            frag.recalculate_cache()
         elif typ == "schema":
             self.holder.apply_schema(msg.get("schema", []))
         elif typ == "translate-keys":

@@ -121,6 +121,8 @@ PREFETCH_EVICTED = "tiering.prefetch_evicted"
 # the TopN walk's advisory stage-ahead of its next candidate chunk
 TOPN_PREFETCH_DECISIONS = "topn.prefetch_decisions"
 TOPN_PREFETCH_STARTS = "topn.prefetch_starts"
+# (shard, id) reads of a TopN's exact-count pass, by how they were answered
+TOPN_PASS2_IDS = "topn.pass2_ids"
 # TopN rank/LRU caches
 CACHE_HITS = "cache.hits"
 CACHE_MISSES = "cache.misses"
@@ -429,6 +431,16 @@ METRICS: dict[str, tuple[str, str]] = {
         "counter",
         "stage-prefetch threads started: the next chunk fits and the "
         "stager does not hold it yet",
+    ),
+    TOPN_PASS2_IDS: (
+        "counter",
+        "(shard, id) reads of a TopN's exact-count second pass, counted "
+        "once a request (label: how = vector, whole shards answered "
+        "from the score matrices and rankings snapshots the first pass "
+        "left; scalar, shards sent through the per-id pass: an LRU or "
+        "absent cache, a write since the snapshot, a winner outside the "
+        "scored prefix, a tanimoto or attribute filter, a shard the "
+        "device did not score)",
     ),
     CACHE_HITS: ("counter", "TopN rank/LRU cache hits"),
     CACHE_MISSES: ("counter", "TopN rank/LRU cache misses"),

@@ -91,6 +91,8 @@ def test_the_manifest_names_the_cell_with_four_chips_and_its_metrics():
     assert "shards" in CONFIG["assumed"]  # twice the source's 64: stated, not listed as a cut
     one_chip = {m["name"] for m in run.metrics_of(manifest, "per_layer", "tall64.topn")}
     assert "kernels.hbm_roofline_per_chip" not in one_chip
+    # pass 2's counter moves query_p50_ms, which every cell reports: no list
+    assert "executor.topn_pass2_vector_ids_per_query" in names & one_chip
 
 
 # -- (e) the per-chip reading -------------------------------------------------

@@ -558,10 +558,25 @@ def _old_seed(chunks, rids):
     return {rid: int(lut[rid]) for rid in rids if rid in lut}
 
 
+class _Scored:
+    """What a lazy-score provider shows the cross-pass carry."""
+
+    def __init__(self, carry, shards, pairs_by_shard):
+        self.shards, self.pairs, self.mats = shards, pairs_by_shard, []
+        carry.attach(self)
+
+    def scored(self):
+        scores = np.concatenate(self.mats, axis=1) if self.mats else None
+        return self.shards, [None] * len(self.shards), self.pairs, scores
+
+
 @pytest.mark.parametrize("snapshot", [True, False], ids=["rankings", "list"])
 @pytest.mark.parametrize("stacked", [True, False], ids=["stacked", "per_shard"])
 @pytest.mark.parametrize("seed", [1, 2, 3])
 def test_carry_seed_equals_the_old_form(seed, stacked, snapshot):
+    """seed() reads the providers' matrices as they stand (one provider
+    over all shards, or one a shard as the per-shard scorer attaches)
+    and answers like the per-id dict fan-out it replaced."""
     from pilosa_tpu.core.cache import Rankings
     from pilosa_tpu.executor.executor import _ScoreCarry, _chunk_size
 
@@ -574,6 +589,15 @@ def test_carry_seed_equals_the_old_form(seed, stacked, snapshot):
         pairs = [(i, int(c)) for i, c in zip(ids, rng.integers(1, 99, size=n))]
         pairs_by_shard.append(Rankings(pairs) if snapshot else pairs)
     carry = _ScoreCarry()
+    assert not carry
+    if stacked:
+        providers = [_Scored(carry, shards, pairs_by_shard)] * len(shards)
+    else:
+        providers = [
+            _Scored(carry, [s], [ps]) for s, ps in zip(shards, pairs_by_shard)
+        ]
+    assert carry
+    assert carry.seed(shards[0], [1, 2]) == {}  # attached, nothing scored yet
     old = {s: [] for s in shards}
     lo = 0
     for _ in range(int(rng.integers(1, 4))):
@@ -581,15 +605,14 @@ def test_carry_seed_equals_the_old_form(seed, stacked, snapshot):
         # score rows padded past the chunk's ids, as the kernels return
         mat = rng.integers(0, 1 << 20, size=(len(shards), size), dtype=np.int32)
         if stacked:
-            carry.add_stacked(shards, pairs_by_shard, lo, mat)
+            providers[0].mats.append(mat)
         for i, s in enumerate(shards):
             ids = tuple(p[0] for p in pairs_by_shard[i][lo : lo + size])
             if not stacked:
-                carry.add(s, pairs_by_shard[i], lo, lo + size, mat[i])
+                providers[i].mats.append(mat[i : i + 1])
             if ids:
                 old[s].append((ids, mat[i]))
         lo += size
-    assert bool(carry) == any(old.values())
     for i, s in enumerate(shards):
         scored = [p[0] for p in pairs_by_shard[i][:lo]]
         absent = [p[0] for p in pairs_by_shard[i][lo:]][:50] + [20001, 20002]
@@ -649,3 +672,234 @@ def test_top_bitmap_pairs_counts_reads_once(tmp_path, cache_type):
     assert (h2 - h1, m2 - m1) == (h1 - h0, m1 - m0)
     assert 0 < h2 - h1 < len(ids)
     h.close()
+
+
+# -- pass 2 from pass 1's matrices (_ScoreCarry.answer) ---------------------
+
+PASS2_SHARDS = 3
+PASS2_Q = "TopN(f, Row(f=0), n=5)"
+
+
+def _pass2_holder(cache_type="ranked", beyond_prefix=False, absent=False):
+    """Three shards whose top five under Row(f=0) differ, so the union
+    pass 2 re-reads is wider than any shard's own list; 300 singleton
+    rows behind the 20 hot ones, so a walk ends in its head chunk and
+    the scored prefix is shorter than the ranked list."""
+    from pilosa_tpu.core.field import FieldOptions
+
+    rng = np.random.default_rng(11)
+    h = Holder()
+    h.open()
+    fld = h.create_index("i").create_field(
+        "f", FieldOptions(cache_type=cache_type, cache_size=50000)
+    )
+    rows, cols = [], []
+
+    def put(row, shard, columns):
+        rows.extend([row] * len(columns))
+        cols.extend((shard * SHARD_WIDTH + np.asarray(columns)).tolist())
+
+    for shard in range(PASS2_SHARDS):
+        src = rng.choice(SHARD_WIDTH, size=3000, replace=False)
+        put(0, shard, src)
+        for r in range(1, 20):
+            if absent and r == 18 and shard == 1:
+                continue  # a winner elsewhere, no bit here
+            if beyond_prefix and r == 19 and shard == 2:
+                put(r, shard, src[:2])  # a winner elsewhere, ranked ~170th here
+                continue
+            # the shard's favourites overlap the source most
+            inside = 100 + 60 * ((r + 7 * shard) % 19)
+            if r == 19 and shard == 0 or r == 18 and shard == 2:
+                inside = 1500
+            put(r, shard, src[:inside])
+            put(r, shard, SHARD_WIDTH - 1 - rng.choice(2000, size=300, replace=False))
+        if beyond_prefix and shard == 2:
+            for r in range(2000, 2150):  # 150 rows of 50 bits outrank row 19
+                put(r, shard, 10000 + rng.choice(50000, size=50, replace=False))
+        for r in range(300):
+            put(1000 + r, shard, [(r * 7919) % SHARD_WIDTH])
+    fld.import_bits(rows, cols)
+    return h
+
+
+def _pass2_ids():
+    from pilosa_tpu.utils import metrics
+
+    return {
+        how: _counter(metrics.TOPN_PASS2_IDS, how=how) for how in ("vector", "scalar")
+    }
+
+
+def _scalar_pass2(monkeypatch):
+    """Send every shard through the per-id pass 2, as before answer()."""
+    from pilosa_tpu.executor.executor import _ScoreCarry
+
+    monkeypatch.setattr(_ScoreCarry, "answer", lambda self, winners, mth: [])
+
+
+def _source_column(h, shard, row, held):
+    """A column of the source row (f=0) in ``shard`` that ``row`` holds,
+    or does not: writing it moves the row's score."""
+    frag = h.fragment("i", "f", "standard", shard)
+    has = set(frag.row(row).columns())
+    return next(c for c in frag.row(0).columns() if (c in has) == held)
+
+
+# case -> (holder arguments, query, (write, row) for shards 0.. after the
+#          warm-up query, shards answered from the matrices)
+PASS2_CASES = {
+    "ranked": ({}, PASS2_Q, [], 3),
+    "lru": ({"cache_type": "lru"}, PASS2_Q, [], 0),
+    "none": ({"cache_type": "none"}, PASS2_Q, [], 0),
+    "threshold": ({}, "TopN(f, Row(f=0), n=5, threshold=400)", [], 3),
+    "beyond_prefix": ({"beyond_prefix": True}, PASS2_Q, [], 2),
+    "absent": ({"absent": True}, PASS2_Q, [], 2),
+    # a write after the ranked snapshot, inside the 10 s debounce: pass 1
+    # walks the snapshot, pass 2 has to read the cache as it now is
+    "set_after": ({}, PASS2_Q, [("Set", 19)], 2),
+    "clear_after": ({}, PASS2_Q, [("Clear", 19)], 2),
+    "set_and_clear_after": ({}, PASS2_Q, [("Set", 18), ("Clear", 19)], 1),
+}
+
+
+@pytest.mark.parametrize("route", ["one_chip", "mesh4", "fused"])
+@pytest.mark.parametrize("case", list(PASS2_CASES))
+def test_vector_pass2_equals_scalar_pass2(case, route, monkeypatch):
+    """Pass 2 read off pass 1's matrices answers like the per-id pass 2
+    and like the CPU, whatever mix of shards each of them serves."""
+    import jax
+
+    from pilosa_tpu.executor.executor import _ScoreCarry
+    from pilosa_tpu.parallel.spmd import make_mesh
+
+    kwargs, q, writes, vector_shards = PASS2_CASES[case]
+    h = _pass2_holder(**kwargs)
+    cpu = Executor(h, device_policy="never", dispatch_enabled=False)
+    mesh = make_mesh(jax.devices()[:4]) if route == "mesh4" else None
+    dev = Executor(h, device_policy="always", dispatch_enabled=False, mesh=mesh)
+    if route == "fused":
+        q = "Count(Row(f=0))" + q
+    asked = []
+    answer = _ScoreCarry.answer
+
+    def spy(self, winners, mth):
+        asked.append(len(winners))
+        return answer(self, winners, mth)
+
+    try:
+        dev.execute("i", q)  # ranks every cache, stages, compiles
+        for shard, (write, row) in enumerate(writes):
+            col = _source_column(h, shard, row, held=write == "Clear")
+            assert dev.execute("i", f"{write}({col}, f={row})") == [True]
+        want = cpu.execute("i", q)
+        monkeypatch.setattr(_ScoreCarry, "answer", spy)
+        before = _pass2_ids()
+        got = dev.execute("i", q)
+        after = _pass2_ids()
+        assert got == want
+        if case == "none":  # no candidates: nothing to fuse, no pass 2
+            assert want[-1] == [] and asked == [] and after == before
+        else:
+            if route == "fused":  # the head chunk came from the fused launch
+                assert dev.fuser.stats()["fused_launches"] >= 2
+            (winners,) = asked
+            assert winners > len(want[-1]) == 5  # the union is wider than n
+            assert after["vector"] - before["vector"] == vector_shards * winners
+            assert after["scalar"] - before["scalar"] == (
+                PASS2_SHARDS - vector_shards
+            ) * winners
+        _scalar_pass2(monkeypatch)
+        assert dev.execute("i", q) == want
+        assert _pass2_ids()["vector"] == after["vector"]
+    finally:
+        dev.close()
+        cpu.close()
+        h.close()
+
+
+@pytest.mark.parametrize("dirty", [False, True], ids=["clean", "dirty"])
+def test_pass2_ids_say_which_way_and_a_clean_pass2_builds_no_provider(
+    dirty, monkeypatch
+):
+    """On clean ranked caches one request reads shards x winners ids off
+    the matrices and builds pass 1's provider alone; after a write into
+    every shard it is the other way round."""
+    from pilosa_tpu.executor import executor as ex_mod
+
+    h = _pass2_holder()
+    dev = Executor(h, device_policy="always", dispatch_enabled=False)
+    built, asked = [], []
+    init = ex_mod._StackedLazyScores.__init__
+    answer = ex_mod._ScoreCarry.answer
+
+    def spy_init(self, ex, frags, pairs_by_shard, *a, **kw):
+        built.append(len(frags))
+        init(self, ex, frags, pairs_by_shard, *a, **kw)
+
+    def spy_answer(self, winners, mth):
+        asked.append(len(winners))
+        return answer(self, winners, mth)
+
+    try:
+        want = dev.execute("i", PASS2_Q)
+        if dirty:
+            for shard in range(PASS2_SHARDS):
+                col = _source_column(h, shard, 5, held=False)
+                assert dev.execute("i", f"Set({col}, f=5)") == [True]
+            want = Executor(h, device_policy="never").execute("i", PASS2_Q)
+        monkeypatch.setattr(ex_mod._StackedLazyScores, "__init__", spy_init)
+        monkeypatch.setattr(ex_mod._ScoreCarry, "answer", spy_answer)
+        before = _pass2_ids()
+        assert dev.execute("i", PASS2_Q) == want
+        after = _pass2_ids()
+        (winners,) = asked
+        reads = PASS2_SHARDS * winners
+        grown = {how: after[how] - before[how] for how in after}
+        if dirty:
+            assert grown == {"vector": 0, "scalar": reads}
+            assert built == [PASS2_SHARDS, PASS2_SHARDS]  # pass 1, pass 2
+        else:
+            assert grown == {"vector": reads, "scalar": 0}
+            assert built == [PASS2_SHARDS]
+    finally:
+        dev.close()
+        h.close()
+
+
+def test_the_benchmarks_metric_reads_the_counter_and_0_where_it_is_not_published():
+    """``executor.topn_pass2_vector_ids_per_query`` by its files alone:
+    the growth of ``topn.pass2_ids{how=vector}`` a request, and 0 (not
+    an error) from a program that never publishes the sample."""
+    import json
+    import os
+
+    from benchmark import layer_metrics, run
+    from benchmark.server import parse_metrics
+    from pilosa_tpu.utils import metrics
+
+    name = "executor.topn_pass2_vector_ids_per_query"
+    with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = manifest["per_layer"][-1]
+    assert entry["name"] == name and entry["moves"] == "query_p50_ms"
+    assert "workloads" not in entry  # every cell that reports query_p50_ms
+    spec = layer_metrics.load(name)
+
+    h = _pass2_holder()
+    dev = Executor(h, device_policy="always", dispatch_enabled=False)
+    try:
+        dev.execute("i", PASS2_Q)
+        before = parse_metrics(metrics.render_prometheus())
+        vector = _pass2_ids()["vector"]
+        for _ in range(4):
+            dev.execute("i", PASS2_Q)
+        after = parse_metrics(metrics.render_prometheus())
+        grown = _pass2_ids()["vector"] - vector
+    finally:
+        dev.close()
+        h.close()
+    assert grown > 0 and grown % (4 * PASS2_SHARDS) == 0
+    assert layer_metrics.evaluate(spec, before, after, 4, None) == grown / 4
+    parent = [m for m in after if m[0] != "topn_pass2_ids"]
+    assert layer_metrics.evaluate(spec, parent, parent, 4, None) == 0
