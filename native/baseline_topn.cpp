@@ -7,12 +7,11 @@
 // toolchain (BASELINE.md), so this C++ program re-implements that
 // algorithm shape 1:1 — sorted-u16 array containers, merge-walk
 // intersection counts, threshold-pruned heap walk — and measures it on
-// the SAME synthetic workloads bench.py / bench_tall.py run on TPU.
+// three synthetic workloads it builds itself (main, below).
 // Optimised C++ on one core is a fair stand-in for (and a bit faster
 // than) the Go binary's single-node per-query cost; the recorded
-// numbers land in BASELINE_NATIVE.json and bench.py quotes them so the
-// headline vs_baseline ratio is defensible rather than a comparison
-// against a Python loop.
+// numbers land in BASELINE_NATIVE.json, so a comparison with the
+// reference is against its algorithm, not against a Python loop.
 //
 // Build: g++ -O3 -march=native -std=c++17 -o baseline_topn baseline_topn.cpp
 // Run:   ./baseline_topn            (prints one JSON line)
@@ -197,7 +196,7 @@ static u32 cdiff_count(const std::vector<u16>& a, const std::vector<u16>& b) {
   return n + (u32)(a.size() - i);
 }
 
-// The three bench_tall chain shapes (bench_tall.py _queries; reference
+// The three chain shapes of workload 3 (reference
 // executeBitmapCallShard -> Row algebra -> row.Count,
 // executor.go:704-996), per shard:
 //   1. Count(Intersect(Union(a,b), Union(c,d)))
@@ -231,7 +230,7 @@ static u64 chain_query3(const Row& a, const Row& b, const Row& c,
 }
 
 int main() {
-  // ---- workload 1: bench.py kernel shape — 4096 rows x 1M cols,
+  // ---- workload 1: kernel shape — 4096 rows x 1M cols,
   // ~1.6% density, every row a candidate (cache covers all rows).
   {
     const int R = 4096, N = 10, QUERIES = 32;
@@ -251,7 +250,7 @@ int main() {
     printf("{\"workload\": \"kernel_4096x1M\", \"native_cpu_qps\": %.2f}\n", qps);
   }
 
-  // ---- workload 2: bench_tall shape — per shard: 32 hot rows
+  // ---- workload 2: tall shape — per shard: 32 hot rows
   // (~50k bits) + singleton tail in the ranked cache (50k candidates,
   // count 1 — the early break prunes them after the hot head).
   // 64 shards walked sequentially, as one Go process on one core would
@@ -290,9 +289,8 @@ int main() {
            "cores\"}\n",
            QUERIES / dt);
 
-    // ---- workload 3: bench_tall chain family on the same data —
-    // the SAME three shapes bench_tall's chain_qps averages over,
-    // across 64 shards, 4 distinct hot rows per query.
+    // ---- workload 3: chain family on the same data — three
+    // shapes, averaged, across 64 shards, 4 distinct hot rows per query.
     volatile u64 sink3 = 0;
     const int CQUERIES = 15;  // 5 iterations x 3 shapes
     double t1 = now_s();

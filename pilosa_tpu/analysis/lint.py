@@ -44,6 +44,12 @@ Rules (ids are what ``# check: disable=<rule>`` names):
   ``maybe_faulty``) parse under that family's knob grammar. A typo'd
   knob in a chaos schedule otherwise surfaces as a ValueError at the
   worst time: inside the fault window it was supposed to open.
+* ``env-config`` — no ``os.environ`` / ``os.getenv`` under
+  ``pilosa_tpu/executor/``, ``pilosa_tpu/plan/`` or
+  ``pilosa_tpu/server/pipeline.py``: the hot path is configured by
+  constructor arguments the server fills from its config
+  (``PILOSA_TPU_*``, read in ``server/config.py``), never by a second
+  set of switches read where they act.
 
 Suppressions: ``# check: disable=<rule>[,<rule>…] (<reason>)`` on the
 flagged line or alone on the line above. ``--strict`` additionally
@@ -67,6 +73,7 @@ RULES = (
     "donation-safety",
     "metrics-sync",
     "fault-spec",
+    "env-config",
 )
 
 # modules migrated to OrderedLock — the five lock-heaviest (ISSUE 9);
@@ -757,6 +764,38 @@ def rule_fault_spec(tree: ast.Module, ctx: "FileContext") -> list[Finding]:
     return findings
 
 
+# -- rule: env-config --------------------------------------------------------
+
+_ENV_FREE = ("pilosa_tpu/executor/", "pilosa_tpu/plan/", "pilosa_tpu/server/pipeline.py")
+
+
+def rule_env_config(tree: ast.Module, ctx: "FileContext") -> list[Finding]:
+    rel = ctx.relpath.replace(os.sep, "/")
+    if not any(p in rel for p in _ENV_FREE):
+        return []
+    findings: list[Finding] = []
+    for n in ast.walk(tree):
+        if isinstance(n, ast.Attribute):
+            hit = _dotted(n) in ("os.environ", "os.getenv")
+        elif isinstance(n, ast.ImportFrom):
+            hit = n.module == "os" and any(
+                a.name in ("environ", "getenv") for a in n.names
+            )
+        else:
+            continue
+        if hit:
+            findings.append(
+                ctx.finding(
+                    n.lineno,
+                    "env-config",
+                    "environment read on the hot path — take the value as "
+                    "a constructor argument; the server fills it from its "
+                    "config (server/config.py reads PILOSA_TPU_*)",
+                )
+            )
+    return findings
+
+
 _RULE_FNS: dict[str, Callable] = {
     "lock-discipline": rule_lock_discipline,
     "lock-wrapper": rule_lock_wrapper,
@@ -766,6 +805,7 @@ _RULE_FNS: dict[str, Callable] = {
     "donation-safety": rule_donation_safety,
     "metrics-sync": rule_metrics_sync,
     "fault-spec": rule_fault_spec,
+    "env-config": rule_env_config,
 }
 
 

@@ -115,21 +115,12 @@ class BatchedScorer:
         max_batch: int = 32,
         single_fn=None,
         batch_fn=None,
-        pad_fn=None,
-        kind: Optional[str] = "topn_score_dense",
+        kind: str = "topn_score_dense",
     ) -> None:
         self.max_batch = max_batch
         # the kernel's name in spmd.execute_seconds{kind} and
-        # kernel.operand_bytes{kind}, launch → fetched; None where the
-        # kernel pair is already wrapped by the executor's _timed_kernel
+        # kernel.operand_bytes{kind}, launch → fetched
         self.kind = kind
-        # pow2 padding strategy: None = cached zeros_like (sources are
-        # single arrays; a zero source scores 0 and is sliced off).
-        # Callers whose src is NOT one array (the chain path's tuple of
-        # leaf arrays) supply pad_fn(proto_src) -> pad_src; padding with
-        # a repeat of a real source is always semantically safe because
-        # pad lanes' results are never assigned to a slot.
-        self._pad_fn = pad_fn
         self._single_fn = single_fn or (
             lambda src, staged: ops.intersection_counts_matrix(src, staged)
         )
@@ -151,7 +142,7 @@ class BatchedScorer:
         # that enqueued this key's work
         self._pending: dict[tuple, tuple] = {}
         self._dispatching = False
-        # telemetry (read by tests/bench; no lock — monotonic counters)
+        # telemetry (read by tests; no lock — monotonic counters)
         self.dispatches = 0
         self.batched_queries = 0
 
@@ -345,19 +336,16 @@ class BatchedScorer:
                 q = _next_pow2(len(chunk))
                 srcs = [s.src for s in chunk]
                 if q > len(chunk):
-                    if self._pad_fn is not None:
-                        srcs = srcs + [self._pad_fn(srcs[0])] * (q - len(chunk))
-                    else:
-                        proto = srcs[0]
-                        zkey = (getattr(proto, "shape", None), str(getattr(proto, "dtype", "")))
-                        zero = self._pad_zeros.get(zkey)
-                        if zero is None:
-                            zero = self._pad_zeros[zkey] = jnp.zeros_like(proto)
-                            if self.governor is not None:
-                                self.governor.reserve(
-                                    "batcher", int(getattr(zero, "nbytes", 0))
-                                )
-                        srcs = srcs + [zero] * (q - len(chunk))
+                    proto = srcs[0]
+                    zkey = (getattr(proto, "shape", None), str(getattr(proto, "dtype", "")))
+                    zero = self._pad_zeros.get(zkey)
+                    if zero is None:
+                        zero = self._pad_zeros[zkey] = jnp.zeros_like(proto)
+                        if self.governor is not None:
+                            self.governor.reserve(
+                                "batcher", int(getattr(zero, "nbytes", 0))
+                            )
+                    srcs = srcs + [zero] * (q - len(chunk))
                 t0 = self._note_launch(srcs, mat)
                 dev = self._batch_fn(srcs, mat)
                 # transfer hygiene: pad query lanes never reach the
@@ -378,8 +366,7 @@ class BatchedScorer:
     def _note_launch(self, srcs, mat) -> float:
         """One launch's operand bytes (padding included) under the
         scorer's kernel name; returns the launch time for _finish."""
-        if self.kind is not None:
-            profiler.count_operands(self.kind, (srcs, mat))
+        profiler.count_operands(self.kind, (srcs, mat))
         return time.monotonic()
 
     def _finish(self, launched: list[tuple]) -> None:
@@ -389,12 +376,11 @@ class BatchedScorer:
         try:
             for chunk, dev_scores, t0 in launched:
                 scores = np.asarray(dev_scores)
-                if self.kind is not None:
-                    metrics.observe(
-                        metrics.SPMD_EXECUTE_SECONDS,
-                        time.monotonic() - t0,
-                        kind=self.kind,
-                    )
+                metrics.observe(
+                    metrics.SPMD_EXECUTE_SECONDS,
+                    time.monotonic() - t0,
+                    kind=self.kind,
+                )
                 if len(chunk) == 1 and scores.ndim == 1:
                     chunk[0].result = scores
                     chunk[0].event.set()

@@ -19,7 +19,6 @@ local loop over shards.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from dataclasses import dataclass
@@ -268,34 +267,14 @@ def _eval_tree(t, leaves):
     return acc
 
 
-def _make_chain_scorer(ex: "Executor") -> BatchedScorer:
-    """Coalescing scorer for fused Count(chain) dispatches: concurrent
-    same-shape chains (identical boolean tree + leaf shapes — the key)
-    stack their leaves into ONE batched kernel, i32[Q] counts back.
-    OFF by default (PILOSA_CHAIN_BATCH=1 enables). Which of the two
-    serves more chains per second is not measured on the current
-    machine: the chain kernel is cheap, so coalescing pays only where
-    per-dispatch overhead is the scarce resource. Pads with a repeat
-    of a real source (a leaves tuple has no zeros_like); pad lanes'
-    counts are never read."""
-    return BatchedScorer(
-        max_batch=int(os.environ.get("PILOSA_CHAIN_MAX_BATCH", 32)),
-        single_fn=ex._chain_count_single,
-        batch_fn=ex._chain_count_batch,
-        pad_fn=lambda proto: proto,
-        kind=None,  # both are _timed_kernel-wrapped (tree_count*)
-    )
-
-
 def _make_stacked_scorer() -> BatchedScorer:
     """Coalescing scorer for the cross-shard stacked-sparse TopN path.
-    max_batch bounds the lax.map sweep (default 32, a value not
-    measured on the current machine; PILOSA_STACKED_MAX_BATCH tunes
-    it); num_rows rides in the staged tuple. A factory because
-    the device health gate rebuilds it on restore (its queue may be
-    held by abandoned workers)."""
+    max_batch bounds the lax.map sweep (32, a value not measured on
+    the current machine); num_rows rides in the staged tuple. A factory
+    because the device health gate rebuilds it on restore (its queue
+    may be held by abandoned workers)."""
     return BatchedScorer(
-        max_batch=int(os.environ.get("PILOSA_STACKED_MAX_BATCH", 32)),
+        max_batch=32,
         single_fn=lambda src, st: ops.sparse_intersection_counts_stacked(src, *st),
         batch_fn=lambda srcs, st: ops.sparse_intersection_counts_stacked_batch_list(
             srcs, *st
@@ -421,15 +400,15 @@ class Executor:
         health=None,
         auto_min_containers: Optional[int] = None,
         plan_cache=None,
-        dispatch_enabled: Optional[bool] = None,
+        dispatch_enabled: bool = True,
         dispatch_max_wave: int = 16,
         dispatch_max_inflight: int = 2,
         dispatch_stage_ahead: int = 1,
-        prefetch_enabled: Optional[bool] = None,
+        prefetch_enabled: bool = True,
         prefetch_depth: int = 2,
-        fusion_enabled: Optional[bool] = None,
+        fusion_enabled: bool = True,
         fusion_max_calls: int = 64,
-        plan_cache_device_bytes: Optional[int] = None,
+        plan_cache_device_bytes: int = 256 << 20,
         governor: Optional[HbmGovernor] = None,
         analytics_max_groups: Optional[int] = None,
     ) -> None:
@@ -465,15 +444,10 @@ class Executor:
         # cache-rankings prefix) coalesce into one stacked kernel launch
         # — one device round-trip serves the whole batch.
         self.stacked_scorer = _make_stacked_scorer()
-        # concurrent same-shape Count(chain) queries CAN coalesce into
-        # one batched tree-count launch (see _make_chain_scorer); off by
-        # default (rationale at the _execute_count call site)
-        self._chain_batch = os.environ.get("PILOSA_CHAIN_BATCH", "0") == "1"
-        self.chain_scorer = _make_chain_scorer(self)
         # optional device health gate (executor/devicehealth.py):
         # serving deployments pass one so a wedged accelerator degrades
         # reads to the CPU roaring path instead of hanging them; bare
-        # executors (tests, benches) skip the per-call guard hop
+        # executors (tests) skip the per-call guard hop
         self.health = health
         if health is not None:
             health.on_restore = self._on_device_restore
@@ -486,30 +460,23 @@ class Executor:
         # SPMD kernel IS a multi-process collective program.
         self.gang = None
         # generation-stamped query result cache (plan/cache.py). None =
-        # disabled (the default for bare executors, so tests and benches
-        # opt in explicitly); the server wires one per process. Only
+        # disabled (the default for bare executors, so tests opt in
+        # explicitly); the server wires one per process. Only
         # consulted for locally-executed reads — on a cluster each
         # shard owner caches its own remote legs, because only IT can
         # see its fragments' generations.
         self.plan_cache = plan_cache
         # fused count-of-tree programs keyed by query structure
         self._tree_jits: dict[str, Any] = {}
-        # batched variants keyed by (structure, pow2 width)
-        self._tree_batch_jits: dict[tuple, Any] = {}
         # auto-policy crossover, in estimated touched containers (see
         # _touched_containers). The default is not measured on the
         # current machine; executor/autotune.py measures the crossover
-        # at server open. Precedence: explicit constructor value (the
-        # server plumbs its config knob here) >
-        # PILOSA_AUTO_DEVICE_MIN_CONTAINERS env > the default.
-        if auto_min_containers is not None:
-            self.auto_min_containers = int(auto_min_containers)
-        else:
-            self.auto_min_containers = int(
-                os.environ.get(
-                    "PILOSA_AUTO_DEVICE_MIN_CONTAINERS", AUTO_DEVICE_MIN_CONTAINERS
-                )
-            )
+        # at server open; the server plumbs its config knob here.
+        self.auto_min_containers = (
+            int(auto_min_containers)
+            if auto_min_containers is not None
+            else AUTO_DEVICE_MIN_CONTAINERS
+        )
         self._read_pool = None  # lazy; see execute()
         self._read_pool_mu = threading.Lock()
         # checkout refcount + closing flag: close() drains active
@@ -523,11 +490,7 @@ class Executor:
         # eligible local reads entering execute() submit a future and
         # wait instead of blocking through the call tree, so concurrent
         # heterogeneous plans coalesce into device waves. The loop
-        # thread starts lazily on first submit. PILOSA_DISPATCH=0 turns
-        # it off for bare executors (benches A/B it); the server passes
-        # its dispatch-* knobs explicitly.
-        if dispatch_enabled is None:
-            dispatch_enabled = os.environ.get("PILOSA_DISPATCH", "1") != "0"
+        # thread starts lazily on first submit.
         if dispatch_enabled:
             from pilosa_tpu.executor.dispatch import DispatchEngine
 
@@ -543,9 +506,7 @@ class Executor:
         # dispatch engine's wave builder hands it queued plans so the
         # NEXT waves' Row blocks promote T1/T2 → T0 ahead of compute,
         # with accuracy attribution. Replaces the thunk-based advisory
-        # warm when enabled; PILOSA_PREFETCH=0 reverts for A/B.
-        if prefetch_enabled is None:
-            prefetch_enabled = os.environ.get("PILOSA_PREFETCH", "1") != "0"
+        # warm when enabled.
         if prefetch_enabled and self.dispatch_engine is not None:
             from pilosa_tpu.executor.tiering import PrefetchScheduler
 
@@ -555,11 +516,7 @@ class Executor:
         # whole-query device fusion (fusion.py): multi-call read queries
         # — and the multi-call Queries the dispatch engine combines a
         # wave into — lower to ONE jitted program, intermediates stay in
-        # HBM, only final scalars/score heads transfer. PILOSA_FUSION=0
-        # turns it off for bare executors (benches A/B it); the server
-        # passes its fusion-* knobs explicitly.
-        if fusion_enabled is None:
-            fusion_enabled = os.environ.get("PILOSA_FUSION", "1") != "0"
+        # HBM, only final scalars/score heads transfer.
         if fusion_enabled:
             from pilosa_tpu.executor.fusion import QueryFuser
 
@@ -571,10 +528,6 @@ class Executor:
         # through host Row decode + re-pack + re-upload. 0 disables;
         # single-device only (mesh placement differs — gated at the
         # probe site in _device_bitmap_stack).
-        if plan_cache_device_bytes is None:
-            plan_cache_device_bytes = int(
-                os.environ.get("PILOSA_PLAN_CACHE_DEVICE_BYTES", 256 << 20)
-            )
         if plan_cache_device_bytes > 0 and self.plan_cache is not None:
             from pilosa_tpu.plan.cache import DevicePlanCache
 
@@ -594,15 +547,13 @@ class Executor:
         self.stager.set_governor(self.governor)
         if self.device_cache is not None:
             self.device_cache.set_governor(self.governor)
-        for sc in (self.scorer, self.stacked_scorer, self.chain_scorer):
+        for sc in (self.scorer, self.stacked_scorer):
             sc.set_governor(self.governor)
         # OOM recovery policy shared by every device-call boundary:
         # evict → retry once → degrade this call to the CPU leg; the
         # health gate trips only on repeat unrecovered failures
         self._oom_cpu_until = 0.0
-        self.oom_cpu_cooldown_s = float(
-            os.environ.get("PILOSA_OOM_CPU_COOLDOWN_S", OOM_CPU_COOLDOWN_S)
-        )
+        self.oom_cpu_cooldown_s = OOM_CPU_COOLDOWN_S
         self._oom = OomRecovery(
             governor=self.governor,
             health=self.health,
@@ -876,11 +827,10 @@ class Executor:
         mutating their orphaned predecessors harmlessly."""
         self.scorer = BatchedScorer()
         self.stacked_scorer = _make_stacked_scorer()
-        self.chain_scorer = _make_chain_scorer(self)
         # the ledger must forget the dead runtime's pad scratch with
         # the scorers; fresh instances re-register at zero
         self.governor.reset("batcher")
-        for sc in (self.scorer, self.stacked_scorer, self.chain_scorer):
+        for sc in (self.scorer, self.stacked_scorer):
             sc.set_governor(self.governor)
         self._oom_cpu_until = 0.0
         self.stager.reset_after_wedge()
@@ -1596,8 +1546,7 @@ class Executor:
     def _tree_count_jit(self, tree):
         """Jitted popcount-of-tree, cached per tree structure (bounded
         by distinct query shapes, like the reference's parsed-query
-        cache would be). Returns i32[1] so the batcher's single path
-        and the caller's unwrap are shape-uniform with the batch path."""
+        cache would be). Returns i32[1]."""
         import jax
 
         key = repr(tree)
@@ -1616,45 +1565,6 @@ class Executor:
             )
             self._tree_jits[key] = fn
         return fn
-
-    def _tree_count_batch_jit(self, tree, q: int, nleaves: int):
-        """Jitted popcount-of-tree over Q coalesced same-shape queries:
-        takes the Q queries' leaf arrays flattened (query-major), stacks
-        each leaf position to u32[Q, S, W], evaluates the boolean tree
-        once batched, and returns i32[Q] counts. One kernel dispatch
-        serves Q concurrent chain queries, the way the stacked scorer
-        does for TopN. Cache key includes Q (pow2-padded
-        by the batcher, so compile count stays bounded)."""
-        import jax
-        import jax.numpy as jnp
-
-        key = (repr(tree), q)
-        fn = self._tree_batch_jits.get(key)
-        if fn is None:
-
-            @jax.named_scope("tree_count_batch")
-            def run(*flat):
-                stacked = tuple(
-                    jnp.stack([flat[k * nleaves + l] for k in range(q)])
-                    for l in range(nleaves)
-                )
-                acc = _eval_tree(tree, stacked)  # u32[Q, S, W]
-                pc = jax.lax.population_count(acc).astype(jnp.int32)
-                return jnp.sum(pc, axis=tuple(range(1, pc.ndim)))
-
-            fn = _timed_kernel(
-                "tree_count_batch", jax.jit(run), signature=key, recovery=self._oom
-            )
-            self._tree_batch_jits[key] = fn
-        return fn
-
-    def _chain_count_single(self, leaves, tree):
-        return self._tree_count_jit(tree)(*leaves)
-
-    def _chain_count_batch(self, srcs, tree):
-        nleaves = len(srcs[0])
-        flat = [arr for leaves in srcs for arr in leaves]
-        return self._tree_count_batch_jit(tree, len(srcs), nleaves)(*flat)
 
     def _device_bitmap_stack(self, index, c: Call, shards):
         """Lower a bitmap call subtree to u32[S, W] across shards."""
@@ -1859,23 +1769,8 @@ class Executor:
         # chain is one XLA fusion + one dispatch, instead of an
         # eager op (one dispatch each) per tree node (SURVEY.md
         # §7 step 4).
-        #
-        # Default: per-query dispatch; the A/B against the
-        # coalescing scorer is not measured on the current
-        # machine. PILOSA_CHAIN_BATCH=1 opts into coalescing for
-        # deployments where dispatch COST dominates; each slot
-        # carries its own staged leaf snapshot, so coalescing
-        # never changes which data a query counts.
         leaves, tree = self._tree_leaves(index, child, batch)
-        if self._chain_batch:
-            key = (
-                "chain",
-                repr(tree),
-                tuple(getattr(a, "shape", None) for a in leaves),
-            )
-            res = self.chain_scorer.score(key, tree, tuple(leaves))
-        else:
-            res = self._tree_count_jit(tree)(*leaves)
+        res = self._tree_count_jit(tree)(*leaves)
         return int(_fetch(res).reshape(-1)[0])
 
     # -- Sum / Min / Max -----------------------------------------------------

@@ -1,12 +1,9 @@
 """Whole-query device fusion — one lowered program per multi-call read.
 
-The serving path is transport-bound, not compute-bound: a warm 3-op
-chain spends ~71 ms of its ~77 ms p50 crossing the host↔device boundary
-while the device computes in single-digit milliseconds
-(BENCH_last_good.json, chain_rtt_fraction 1.0). The per-call executor
-pays that boundary once per call: each Count/Sum/TopN in a multi-call
-query — and every query a dispatch wave coalesces into one combined
-Query — launches its own kernel and fetches its own result.
+The per-call executor crosses the host↔device boundary once per call:
+each Count/Sum/TopN in a multi-call query — and every query a dispatch
+wave coalesces into one combined Query — launches its own kernel and
+fetches its own result.
 
 This module collapses that to ONE jitted program per query: every
 fusable call lowers to a unit (Count → popcount-of-tree, Sum → BSI
@@ -98,7 +95,7 @@ class QueryFuser:
         # Bounded by distinct fused query shapes, like _tree_jits.
         self._programs: dict = {}
         self._mu = threading.Lock()
-        # telemetry (monotonic counters, read by stats()/bench)
+        # telemetry (monotonic counters, read by stats())
         self.fused_launches = 0
         self.fused_calls = 0
         self.cache_served = 0

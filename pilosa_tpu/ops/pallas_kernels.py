@@ -6,8 +6,8 @@ streams HBM→VMEM in (TILE_R, TILE_W) blocks with the src row pinned in
 VMEM, accumulating per-row partial popcounts across word tiles.
 
 No kernel here is called by the served path today: the executor and the
-stager use the XLA forms in ops/packed.py, and only bench.py and the
-tests call these (ROADMAP D4). Every kernel compiles for a described
+stager use the XLA forms in ops/packed.py, and only the tests call
+these (ROADMAP D4). Every kernel compiles for a described
 v5e (tests/test_tpu_compile.py); ``interpret=True`` runs them on the CPU
 so their semantics are tested there.
 """

@@ -166,12 +166,6 @@ class Config:
     pipeline_interactive_queue: int = 64
     pipeline_bulk_queue: int = 16
     pipeline_internal_queue: int = 128
-    # cross-request batching: max homogeneous queued queries combined
-    # into one executor call (1 disables), and an OPTIONAL artificial
-    # wait (seconds) for peers — 0 (default) batches purely from
-    # backlog, so an uncontended query pays no added latency
-    pipeline_batch_max: int = 16
-    pipeline_batch_window: float = 0.0
     # default per-request deadline in seconds when the client sends
     # neither a `timeout` param nor an X-Request-Deadline header
     # (0 = unbounded)
@@ -407,7 +401,11 @@ class Config:
             elif hasattr(cfg, key):
                 setattr(cfg, key, v)
             else:
-                raise ValueError(f"unknown config key: {k}")
+                raise ValueError(
+                    f"unknown config key: {k} (options an older version "
+                    "wrote and this one retired: docs/configuration.md, "
+                    '"Retired options")'
+                )
         return cfg
 
     def apply_env(self, env=None) -> None:
@@ -455,7 +453,6 @@ class Config:
             f"pipeline-enabled = {'true' if self.pipeline_enabled else 'false'}",
             f"pipeline-interactive-workers = {self.pipeline_interactive_workers}",
             f"pipeline-interactive-queue = {self.pipeline_interactive_queue}",
-            f"pipeline-batch-max = {self.pipeline_batch_max}",
             f"pipeline-default-timeout = {self.pipeline_default_timeout}",
             f"pipeline-drain-timeout = {self.pipeline_drain_timeout}",
             f"ingest-enabled = {'true' if self.ingest_enabled else 'false'}",

@@ -34,7 +34,7 @@ from pilosa_tpu.utils.errors import NotFoundError as ExecNotFound
 from pilosa_tpu.utils import events, heat, metrics, privateproto, profiler, publicproto, slo, trace
 from pilosa_tpu.utils.stats import NOP_STATS
 
-# conservative write detector for coalescing/batching eligibility: any
+# conservative write detector for coalescing eligibility: any
 # hit (even a false positive from a quoted key) just forfeits the
 # optimization, never correctness
 _WRITE_CALL_RE = re.compile(r"\b(?:Set\w*|Clear)\s*\(")
@@ -257,13 +257,12 @@ class Handler:
         thunk,
         dl,
         signature=None,
-        batch=None,
         trace_ctx=None,
         index="",
         nbytes=0,
     ):
         """Run ``thunk`` through the serving pipeline (admission,
-        deadline, coalescing, batching) — or directly, deadline still
+        deadline, coalescing) — or directly, deadline still
         honored, when no pipeline is wired. ``index`` is the tenant for
         per-tenant admission + weighted-fair scheduling; ``nbytes``
         charges the tenant's in-flight byte ledger for the request."""
@@ -273,7 +272,6 @@ class Handler:
                 thunk,
                 deadline=dl,
                 signature=signature,
-                batch=batch,
                 trace_ctx=trace_ctx,
                 index=index,
                 nbytes=nbytes,
@@ -326,16 +324,14 @@ class Handler:
             # permuted duplicates like Intersect(Row(a),Row(b)) vs
             # Intersect(Row(b),Row(a)) share one execution; unparseable
             # text falls back to the raw bytes so syntax errors still 400
-            # individually. Plain whole-index reads additionally gang into
-            # combined cross-request executions.
+            # individually.
             cls = pipeline_mod.classify_query(body, remote)
             default_t = self.default_timeout
             if cls == CLASS_BULK and self.analytics_timeout > 0:
                 default_t = self.analytics_timeout
             dl = deadline_mod.from_request(req.headers, q, default_t)
             signature = None
-            batch = None
-            # waterfall requests skip cross-request coalescing/batching like
+            # waterfall requests skip cross-request coalescing like
             # profile: a follower served by a leader's execution would report
             # the LEADER's split, not its own
             if not remote and not profile and not waterfall and not _WRITE_CALL_RE.search(body):
@@ -352,24 +348,6 @@ class Handler:
                     column_attrs,
                     cache,
                 )
-                # sampled-trace requests stay out of cross-request batching
-                # (a combined execution has no per-request span tree); they
-                # still coalesce — the follower records a span link
-                if (
-                    shards is None
-                    and not column_attrs
-                    and not (trace_ctx is not None and trace_ctx[2])
-                ):
-                    batch = {
-                        "key": (index, exclude_row_attrs, exclude_columns, cache),
-                        "index": index,
-                        "query": body,
-                        "kwargs": {
-                            "exclude_row_attrs": exclude_row_attrs,
-                            "exclude_columns": exclude_columns,
-                            "cache": cache,
-                        },
-                    }
 
             def thunk():
                 return self.api.query(
@@ -394,7 +372,6 @@ class Handler:
                 thunk,
                 dl,
                 signature=signature,
-                batch=batch,
                 trace_ctx=trace_ctx,
                 index=index,
                 nbytes=len(req.body) if req.body else 0,
@@ -925,7 +902,7 @@ class Handler:
 
     def get_debug_pipeline(self, req) -> dict:
         """Serving-pipeline snapshot: per-class queue depth/limit,
-        busy workers, admissions, sheds, coalesce/batch counters."""
+        busy workers, admissions, sheds, coalesce counters."""
         if self.pipeline is None:
             return {"enabled": False}
         return self.pipeline.stats()

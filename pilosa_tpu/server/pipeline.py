@@ -42,22 +42,15 @@ Orca-style continuous batching); this module is that layer:
   text means argument-order-permuted spellings of one query —
   ``Intersect(Row(a), Row(b))`` vs ``Intersect(Row(b), Row(a))`` —
   coalesce too.
-* **Cross-request batching.** When the queue backs up, a worker drains
-  every queued entry with the same batch key (same index + options,
-  read-only) in one gang and executes them as a single combined
-  multi-call query. The executor fans the combined calls through its
-  read pool, where the continuous ``BatchedScorer`` (and, when enabled,
-  the chain-batch gate) coalesces them into batched kernel launches —
-  extending the batching that previously only helped within one HTTP
-  request to the whole queue. There is no artificial wait window by
-  default (``pipeline-batch-window`` can add one): like the scorer,
-  batch width self-tunes to the backlog.
+* **One entry a worker.** A worker pops one entry and runs it; merging
+  concurrent requests' device work is the executor's dispatch engine's
+  job (executor/dispatch.py), which sees every worker's request at once.
 * **Graceful drain.** ``close()`` stops admission (503), completes
   queued + in-flight work within ``drain`` seconds, and fails whatever
   remains — a restart loses no accepted work it had time to finish.
 
 Observability: every decision lands in the process-global metric
-registry (queue depth/wait, sheds, coalesce hits, batch width, deadline
+registry (queue depth/wait, sheds, coalesce hits, deadline
 expiries — docs/administration.md §Metric reference) and in the
 ``/debug/pipeline`` snapshot.
 """
@@ -128,20 +121,16 @@ class _Entry:
         "cls",
         "thunk",
         "signature",
-        "batch_key",
-        "batch_payload",
         "deadline",
         "event",
         "result",
         "error",
         "t_enq",
-        "wait_s",
         "trace_ctx",
         "index",
         "seq",
         "vstart",
         "vft",
-        "skip",
     )
 
     def __init__(
@@ -149,8 +138,6 @@ class _Entry:
         cls: str,
         thunk: Callable[[], Any],
         signature=None,
-        batch_key=None,
-        batch_payload=None,
         deadline: Optional[Deadline] = None,
         trace_ctx: Optional[tuple] = None,
         index: str = "",
@@ -158,31 +145,26 @@ class _Entry:
         self.cls = cls
         self.thunk = thunk
         self.signature = signature
-        self.batch_key = batch_key
-        self.batch_payload = batch_payload
         self.deadline = deadline
         self.event = threading.Event()
         self.result: Any = None
         self.error: Optional[BaseException] = None
         self.t_enq = 0.0
-        self.wait_s = 0.0
         # distributed trace context (utils/trace.py tuple): carried so
         # a coalesced follower can link the leader's trace
         self.trace_ctx = trace_ctx
         # the tenant (ISSUE 19): per-tenant counters + WFQ scheduling
         self.index = index
         # _TenantFairQueue bookkeeping: arrival order, virtual
-        # start/finish time, and the lazy-removal marker
+        # start/finish time
         self.seq = 0
         self.vstart = 0.0
         self.vft = 0.0
-        self.skip = False
 
 
 class _TenantFairQueue:
     """Virtual-time weighted-fair queue over ``_Entry.index`` with the
-    small deque-ish surface the workers use (append / popleft / remove
-    / len / iteration in dequeue order).
+    small deque-ish surface the workers use (append / popleft / len).
 
     Classic WFQ collapsed to unit cost per entry: an arriving entry's
     virtual start is ``max(V, finish[tenant])``, its finish is
@@ -210,13 +192,6 @@ class _TenantFairQueue:
     def __len__(self) -> int:
         return self._len
 
-    def __iter__(self):
-        """Live entries in dequeue order — the batch-collection scans
-        (_dequeue_gang / _collect_window) see the same order popleft
-        would produce."""
-        live = sorted(t for t in self._heap if not t[2].skip)
-        return iter(e for _, _, e in live)
-
     def append(self, e: _Entry) -> None:
         e.seq = self._seq
         self._seq += 1
@@ -235,24 +210,15 @@ class _TenantFairQueue:
         self._len += 1
 
     def popleft(self) -> _Entry:
-        while self._heap:
-            _, _, e = heapq.heappop(self._heap)
-            if e.skip:
-                continue
-            self._drop(e)
-            # virtual time advances to the dequeued entry's start: a
-            # tenant arriving later starts from here, not from zero
-            if e.vstart > self._vtime:
-                self._vtime = e.vstart
-            return e
-        raise IndexError("pop from an empty _TenantFairQueue")
-
-    def remove(self, e: _Entry) -> None:
-        """Lazy removal: mark; the heap tuple is discarded at pop."""
-        if e.skip:
-            raise ValueError("entry not in queue")
-        e.skip = True
+        if not self._heap:
+            raise IndexError("pop from an empty _TenantFairQueue")
+        _, _, e = heapq.heappop(self._heap)
         self._drop(e)
+        # virtual time advances to the dequeued entry's start: a
+        # tenant arriving later starts from here, not from zero
+        if e.vstart > self._vtime:
+            self._vtime = e.vstart
+        return e
 
     def _drop(self, e: _Entry) -> None:
         self._len -= 1
@@ -308,35 +274,6 @@ class _ClassQueue:
         self.completed = 0
 
 
-def make_query_combiner(api) -> Callable:
-    """Gang executor for homogeneous read-only queries: concatenate the
-    members' PQL (PQL is whitespace-separated calls), run ONE
-    ``api.query``, and split the results back by each member's call
-    count. The combined call list flows through the executor's
-    concurrent read pool, where the batched scorers coalesce the
-    members' kernel work into single launches — cross-request batching
-    through entirely existing machinery. Any error falls back to
-    per-entry execution (the pipeline worker handles that), so a bad
-    member can never fail its gang-mates."""
-    from pilosa_tpu.pql import parse
-
-    def combine(entries: list[_Entry]) -> list[dict]:
-        p = entries[0].batch_payload
-        texts = [e.batch_payload["query"] for e in entries]
-        # per-member call counts; also surfaces a syntax error BEFORE
-        # the combined execution so the fallback gives it a proper 400
-        counts = [len(parse(t).calls) for t in texts]
-        resp = api.query(p["index"], " ".join(texts), **p["kwargs"])
-        results = resp["results"]
-        out, off = [], 0
-        for n in counts:
-            out.append({"results": results[off : off + n]})
-            off += n
-        return out
-
-    return combine
-
-
 class QueryPipeline:
     """The scheduler. ``submit`` blocks the calling (HTTP) thread until
     its entry is executed by a class worker, shed, or expired — the
@@ -347,12 +284,8 @@ class QueryPipeline:
         self,
         workers: Optional[dict[str, int]] = None,
         queue_limits: Optional[dict[str, int]] = None,
-        combine_fn: Optional[Callable] = None,
-        batch_max: int = 16,
-        batch_window: float = 0.0,
         shed_retry_after: float = 1.0,
         drain_timeout: float = 10.0,
-        dispatch_handoff: bool = False,
         tenancy=None,
     ) -> None:
         workers = workers or {}
@@ -379,24 +312,13 @@ class QueryPipeline:
             )
             for c in CLASSES
         }
-        self.combine_fn = combine_fn
-        self.batch_max = max(1, int(batch_max))
-        self.batch_window = float(batch_window)
         self.shed_retry_after = float(shed_retry_after)
         self.drain_timeout = float(drain_timeout)
-        # when the executor's continuous-batching dispatch engine owns
-        # cross-request combining (it groups heterogeneous plans by
-        # canonical signature per wave), workers hand entries off one at
-        # a time instead of gang-batching identical queries here —
-        # otherwise both layers would contend for the same backlog
-        self.dispatch_handoff = bool(dispatch_handoff)
         self._closing = False
         # signature -> leader entry (singleflight)
         self._inflight: dict = {}
         # cross-class counters (ints under _mu; snapshot is consistent)
         self.coalesce_hits = 0
-        self.batches = 0
-        self.batched_entries = 0
         self.expired = 0
         # per-tenant counters (ISSUE 19 satellite: under mixed load the
         # lumped counters above are misleading — /debug/pipeline and
@@ -421,7 +343,6 @@ class QueryPipeline:
         thunk: Callable[[], Any],
         deadline: Optional[Deadline] = None,
         signature=None,
-        batch: Optional[dict] = None,
         trace_ctx: Optional[tuple] = None,
         index: str = "",
         nbytes: int = 0,
@@ -446,7 +367,7 @@ class QueryPipeline:
             charged = True
         try:
             return self._submit_admitted(
-                cls, thunk, deadline, signature, batch, trace_ctx, index
+                cls, thunk, deadline, signature, trace_ctx, index
             )
         finally:
             if charged:
@@ -472,7 +393,6 @@ class QueryPipeline:
         thunk: Callable[[], Any],
         deadline: Optional[Deadline],
         signature,
-        batch: Optional[dict],
         trace_ctx: Optional[tuple],
         index: str,
     ) -> Any:
@@ -481,8 +401,6 @@ class QueryPipeline:
             cls,
             thunk,
             signature=signature,
-            batch_key=batch["key"] if batch else None,
-            batch_payload=batch,
             deadline=deadline,
             trace_ctx=trace_ctx,
             index=index,
@@ -574,112 +492,31 @@ class QueryPipeline:
                     self._cond.wait()
                 if not cq.q:
                     return  # closing and drained
-                gang = self._dequeue_gang(cq)
-                cq.busy += len(gang)
+                e = cq.q.popleft()
+                cq.busy += 1
                 metrics.gauge(metrics.PIPELINE_QUEUE_DEPTH, len(cq.q), cls=cq.name)
             try:
-                self._run_gang(cq, gang)
+                self._run_one(cq, e)
             finally:
                 with self._mu:
-                    cq.busy -= len(gang)
-                    cq.completed += len(gang)
-                    for e in gang:
-                        if e.index:
-                            self._tenant_counter(e.index)["completed"] += 1
-
-    def _dequeue_gang(self, cq: _ClassQueue) -> list[_Entry]:
-        """Pop the head entry plus every queued peer sharing its batch
-        key (up to batch_max) — the backlog IS the batching window.
-        The batch key carries the index, so a gang is always a single
-        tenant's work. Caller holds the lock."""
-        head = cq.q.popleft()
-        gang = [head]
-        if (
-            self.dispatch_handoff
-            or head.batch_key is None
-            or self.batch_max < 2
-            or not self.combine_fn
-        ):
-            return gang
-        if cq.q:
-            took = [e for e in cq.q if e.batch_key == head.batch_key]
-            for e in took[: self.batch_max - 1]:
-                cq.q.remove(e)
-                gang.append(e)
-        return gang
-
-    def _collect_window(self, cq: _ClassQueue, gang: list[_Entry]) -> list[_Entry]:
-        """Optional artificial batching window: wait up to
-        ``batch_window`` for same-key arrivals before executing. Off by
-        default (0) — the continuous design needs no wait under load
-        and a lone query must not pay latency for an empty queue."""
-        if self.batch_window <= 0 or len(gang) >= self.batch_max:
-            return gang
-        stop = time.monotonic() + self.batch_window
-        key = gang[0].batch_key
-        while time.monotonic() < stop and len(gang) < self.batch_max:
-            with self._mu:
-                took = [e for e in cq.q if e.batch_key == key]
-                for e in took[: self.batch_max - len(gang)]:
-                    cq.q.remove(e)
-                    gang.append(e)
-            if len(gang) >= self.batch_max:
-                break
-            time.sleep(min(0.0005, self.batch_window))
-        return gang
-
-    def _run_gang(self, cq: _ClassQueue, gang: list[_Entry]) -> None:
-        if gang and gang[0].batch_key is not None:
-            gang = self._collect_window(cq, gang)
-        now = time.monotonic()
-        live: list[_Entry] = []
-        for e in gang:
-            e.wait_s = now - e.t_enq
-            metrics.observe(metrics.PIPELINE_WAIT_SECONDS, e.wait_s, cls=cq.name)
-            if e.index:
-                metrics.observe(
-                    metrics.TENANT_QUEUE_WAIT_SECONDS,
-                    e.wait_s,
-                    tenant=e.index,
-                    cls=cq.name,
-                )
-            if e.deadline is not None and e.deadline.expired():
-                # expired while queued: cancel BEFORE any parse/executor
-                # work (its waiter already raised or will immediately)
-                with self._mu:
-                    self.expired += 1
+                    cq.busy -= 1
+                    cq.completed += 1
                     if e.index:
-                        self._tenant_counter(e.index)["expired"] += 1
-                metrics.count(metrics.PIPELINE_DEADLINE_EXPIRED, stage="queue")
-                self._finish(e, error=DeadlineExceeded("queue"))
-                continue
-            live.append(e)
-        if not live:
-            return
-        if len(live) >= 2 and self.combine_fn is not None:
-            with self._mu:
-                self.batches += 1
-                self.batched_entries += len(live)
-            metrics.count(metrics.PIPELINE_BATCHES)
-            metrics.observe(metrics.PIPELINE_BATCH_WIDTH, len(live))
-            dls = [e.deadline for e in live if e.deadline is not None]
-            gang_dl = min(dls, key=lambda d: d.at) if dls else None
-            try:
-                with deadline_mod.activate(gang_dl):
-                    results = self.combine_fn(live)
-                for e, r in zip(live, results):
-                    self._finish(e, result=r)
-                return
-            except BaseException:
-                # combined execution failed (one bad member, deadline,
-                # anything): fall back to per-entry execution so each
-                # member gets ITS OWN outcome
-                pass
-        for e in live:
-            self._run_one(e)
+                        self._tenant_counter(e.index)["completed"] += 1
 
-    def _run_one(self, e: _Entry) -> None:
+    def _run_one(self, cq: _ClassQueue, e: _Entry) -> None:
+        wait_s = time.monotonic() - e.t_enq
+        metrics.observe(metrics.PIPELINE_WAIT_SECONDS, wait_s, cls=cq.name)
+        if e.index:
+            metrics.observe(
+                metrics.TENANT_QUEUE_WAIT_SECONDS,
+                wait_s,
+                tenant=e.index,
+                cls=cq.name,
+            )
         if e.deadline is not None and e.deadline.expired():
+            # expired while queued: cancel BEFORE any parse/executor
+            # work (its waiter already raised or will immediately)
             with self._mu:
                 self.expired += 1
                 if e.index:
@@ -687,7 +524,7 @@ class QueryPipeline:
             metrics.count(metrics.PIPELINE_DEADLINE_EXPIRED, stage="queue")
             self._finish(e, error=DeadlineExceeded("queue"))
             return
-        _entry_wait.value = e.wait_s
+        _entry_wait.value = wait_s
         try:
             with deadline_mod.activate(e.deadline):
                 self._finish(e, result=e.thunk())
@@ -743,13 +580,8 @@ class QueryPipeline:
             return {
                 "enabled": True,
                 "closing": self._closing,
-                "batch_max": self.batch_max,
-                "batch_window_s": self.batch_window,
-                "dispatch_handoff": self.dispatch_handoff,
                 "coalesce_hits": self.coalesce_hits,
                 "coalesce_inflight": len(self._inflight),
-                "batches": self.batches,
-                "batched_entries": self.batched_entries,
                 "deadline_expired": self.expired,
                 "weighted_fair": any(
                     cq.q.weight_fn is not None for cq in self._classes.values()

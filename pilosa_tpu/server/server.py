@@ -360,14 +360,10 @@ class Server:
             )
         # serving pipeline (server/pipeline.py): every query/import
         # request flows through bounded per-class admission queues with
-        # deadline scheduling, singleflight coalescing, and
-        # cross-request batching into the executor's scorers
+        # deadline scheduling and singleflight coalescing
         self.pipeline = None
         if self.config.pipeline_enabled:
-            from pilosa_tpu.server.pipeline import (
-                QueryPipeline,
-                make_query_combiner,
-            )
+            from pilosa_tpu.server.pipeline import QueryPipeline
 
             self.pipeline = QueryPipeline(
                 workers={
@@ -380,18 +376,8 @@ class Server:
                     "bulk": self.config.pipeline_bulk_queue,
                     "internal": self.config.pipeline_internal_queue,
                 },
-                combine_fn=make_query_combiner(self.api),
-                batch_max=self.config.pipeline_batch_max,
-                batch_window=self.config.pipeline_batch_window,
                 shed_retry_after=self.config.pipeline_shed_retry_after,
                 drain_timeout=self.config.pipeline_drain_timeout,
-                # with the dispatch engine on, cross-request combining
-                # belongs to the engine (which also handles
-                # heterogeneous plans); pipeline workers hand items off
-                # one at a time instead of gang-batching them
-                dispatch_handoff=(
-                    self.executor.dispatch_engine is not None
-                ),
                 tenancy=self.tenancy,
             )
         # durable ingest queue (server/ingest.py): its own admission
@@ -687,14 +673,13 @@ class Server:
                 self.logger.printf("leader-uri broadcast failed: %s", e)
         # measure the device-policy crossover for THIS deployment
         # (dispatch time / per-container CPU cost) unless the operator
-        # pinned one via config or env — measured beats guessed
+        # pinned one via config — measured beats guessed
         # (executor/autotune.py). Non-blocking: serving starts on the
         # default and adopts the measurement when it lands; a wedged
         # device can't stall startup.
         if (
             self.config.device_policy == "auto"
             and self.config.auto_device_min_containers <= 0
-            and not os.environ.get("PILOSA_AUTO_DEVICE_MIN_CONTAINERS")
             # gang determinism: a per-rank MEASURED crossover would make
             # ranks disagree on device-vs-CPU routing — one rank enters
             # a collective the other skips, and the mesh deadlocks. In

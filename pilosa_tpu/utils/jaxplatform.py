@@ -3,7 +3,7 @@ and the virtual CPU mesh the tests run on.
 
 JAX honours ``JAX_PLATFORMS`` itself, so platform choice needs no code
 here. Deliberately NOT an import side effect of a library module: entry
-points (server, CLI, benches, ``chip_smoke.py``) call ``bootstrap()``
+points (server, CLI, ``chip_smoke.py``) call ``bootstrap()``
 before first backend use.
 """
 

@@ -1,6 +1,6 @@
 """Shared metric registry — ONE canonical set of metric names for the
-server's ``/metrics`` Prometheus surface, ``/debug/vars``, the bench
-scripts, and the docs table (docs/administration.md §Metric reference).
+server's ``/metrics`` Prometheus surface, ``/debug/vars`` and the docs
+table (docs/administration.md §Metric reference).
 
 Every metric name emitted anywhere in the codebase is declared in
 ``METRICS`` below and referenced through the module constants; a unit
@@ -12,8 +12,7 @@ from the deep layers (executor routing, batcher, stager, rank caches,
 device health, cluster fan-out) that have no reference to a Server —
 the same model as Prometheus client libraries' default registry. The
 server merges its per-instance expvar snapshot into the rendered
-exposition; bench scripts attach ``snapshot()`` to their JSON output so
-offline runs speak the same names as a live server.
+exposition.
 """
 
 from __future__ import annotations
@@ -165,8 +164,6 @@ TENANT_HBM_EVICTIONS = "tenant.hbm_evictions"
 PIPELINE_QUEUE_DEPTH = "pipeline.queue_depth"
 PIPELINE_WAIT_SECONDS = "pipeline.wait_seconds"
 PIPELINE_COALESCE_HITS = "pipeline.coalesce_hits"
-PIPELINE_BATCHES = "pipeline.batches"
-PIPELINE_BATCH_WIDTH = "pipeline.batch_width"
 PIPELINE_DEADLINE_EXPIRED = "pipeline.deadline_expired"
 PIPELINE_DRAIN_SECONDS = "pipeline.drain_seconds"
 # durable streaming ingest (server/ingest.py + core/fragment.py)
@@ -584,14 +581,6 @@ METRICS: dict[str, tuple[str, str]] = {
     PIPELINE_COALESCE_HITS: (
         "counter",
         "duplicate concurrent queries that attached to an in-flight execution",
-    ),
-    PIPELINE_BATCHES: (
-        "counter",
-        "cross-request gangs executed as one combined query",
-    ),
-    PIPELINE_BATCH_WIDTH: (
-        "summary",
-        "requests per cross-request combined execution",
     ),
     PIPELINE_DEADLINE_EXPIRED: (
         "counter",
@@ -1087,8 +1076,8 @@ class Registry:
 
     def snapshot(self) -> dict:
         """JSON-safe flat snapshot: ``name[;k:v,...]`` -> number or
-        histogram summary dict (the expvar key convention, so bench
-        output and /debug/vars read the same way)."""
+        histogram summary dict (the expvar key convention, as
+        /debug/vars reads)."""
         out = {}
         with self._mu:
             for (name, lbl), v in self._counters.items():

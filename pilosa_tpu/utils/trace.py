@@ -617,14 +617,6 @@ def attrib_current() -> Optional[dict]:
     return _attrib.get()
 
 
-def attrib_add(stage: str, seconds: float) -> None:
-    """Credit ``seconds`` to a waterfall bucket of the active request;
-    no-op (one contextvar get) when attribution is off."""
-    d = _attrib.get()
-    if d is not None:
-        d[stage] = d.get(stage, 0.0) + seconds
-
-
 class _AttribActivation:
     """Install (or re-enter) an attribution dict for a scope — the
     request root passes a fresh dict, pool/wave workers pass the

@@ -4,6 +4,7 @@ dynamic lock-order detector (AB/BA cycle, Condition integration,
 self-deadlock), suppression handling, the repo-clean CI gate, and the
 OrderedLock overhead bound on the executor-style hot path."""
 
+import os
 import threading
 import time
 
@@ -433,6 +434,43 @@ class TestMetricsSync:
     def test_non_metrics_receiver_ignored(self):
         src = "collections.Counter().count('whatever')\nstats.gauge('x', 1)\n"
         assert run_rule(src, "metrics-sync") == []
+
+
+# -- env-config --------------------------------------------------------------
+
+
+class TestEnvConfig:
+    READS = (
+        "import os\nX = os.environ.get('PILOSA_TPU_DEVICE_POLICY', 'auto')\n",
+        "import os\nX = os.getenv('PILOSA_LOCK_STRICT')\n",
+        "from os import environ\n",
+    )
+
+    def test_planted_read_on_hot_path_flagged(self):
+        for relpath in (
+            "pilosa_tpu/executor/executor.py",
+            "pilosa_tpu/plan/cache.py",
+            "pilosa_tpu/server/pipeline.py",
+        ):
+            for src in self.READS:
+                fs = run_rule(src, "env-config", relpath=relpath)
+                assert len(fs) == 1, (relpath, src)
+
+    def test_deployment_setting_read_elsewhere_clean(self):
+        for relpath in ("pilosa_tpu/server/config.py", "pilosa_tpu/analysis/locks.py"):
+            for src in self.READS:
+                assert run_rule(src, "env-config", relpath=relpath) == []
+
+    def test_hot_path_reads_no_environment(self):
+        pkg = os.path.join(lint.repo_root(), "pilosa_tpu")
+        roots = [
+            os.path.join(pkg, "executor"),
+            os.path.join(pkg, "plan"),
+            os.path.join(pkg, "server", "pipeline.py"),
+        ]
+        assert len(lint.iter_py_files(roots)) > 10
+        fs = [f for f in lint.check_paths(roots) if f.rule == "env-config"]
+        assert fs == [], "\n".join(f.format() for f in fs)
 
 
 # -- suppressions ------------------------------------------------------------

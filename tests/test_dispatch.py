@@ -6,7 +6,7 @@ determinism contract), engine drain on close (bare and via server),
 the read-pool close/submit race regression, and the /debug/dispatch +
 metrics surface.
 
-The engine is ON by default for bare executors (PILOSA_DISPATCH), so
+The engine is ON by default for bare executors, so
 the whole tier-1 suite exercises the routed path implicitly; these
 tests pin the engine-specific behaviors explicitly."""
 
@@ -544,8 +544,6 @@ class TestServerSurface:
         s = self._mkserver(tmp_path)
         try:
             assert s.executor.dispatch_engine is not None
-            # engine owns cross-request combining -> pipeline hands off
-            assert s.pipeline.stats()["dispatch_handoff"] is True
             self._post(s, "/index/ds", b"{}")
             self._post(s, "/index/ds/field/f", b"{}")
             self._post(
@@ -599,7 +597,6 @@ class TestServerSurface:
         s = self._mkserver(tmp_path, dispatch_enabled=False)
         try:
             assert s.executor.dispatch_engine is None
-            assert s.pipeline.stats()["dispatch_handoff"] is False
             snap = json.loads(self._get(s, "/debug/dispatch"))
             assert snap == {"enabled": False}
         finally:

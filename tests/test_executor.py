@@ -368,3 +368,56 @@ class TestBatchedShardPath:
         e_dev = execu(holder, "always")
         for q in queries:
             assert e_cpu.execute("i", q) == e_dev.execute("i", q), q
+
+
+def _configured(ex):
+    """What the retired environment switches used to decide."""
+    return {
+        "dispatch": ex.dispatch_engine is not None,
+        "prefetch": ex.prefetcher is not None,
+        "fusion": ex.fuser is not None,
+        "stacked_max_batch": ex.stacked_scorer.max_batch,
+        "auto_min_containers": ex.auto_min_containers,
+        "device_cache_bytes": ex.device_cache.max_bytes,
+        "oom_cpu_cooldown_s": ex.oom_cpu_cooldown_s,
+    }
+
+
+@pytest.mark.parametrize(
+    "suffix,value",
+    [
+        ("DISPATCH", "0"),
+        ("FUSION", "0"),
+        ("PREFETCH", "0"),
+        ("CHAIN_BATCH", "1"),
+        ("CHAIN_MAX_BATCH", "7"),
+        ("STACKED_MAX_BATCH", "7"),
+        ("AUTO_DEVICE_MIN_CONTAINERS", "7"),
+        ("PLAN_CACHE_DEVICE_BYTES", "0"),
+        ("OOM_CPU_COOLDOWN_S", "0.5"),
+    ],
+)
+def test_executor_reads_no_environment_switch(holder, monkeypatch, suffix, value):
+    """The nine variables the executor once read straight from the
+    environment decide nothing: the constructor's arguments (the
+    server's config) are the one configuration surface."""
+    from pilosa_tpu.plan.cache import PlanCache
+
+    monkeypatch.delenv("PILOSA_" + suffix, raising=False)
+    base = Executor(holder, plan_cache=PlanCache())
+    monkeypatch.setenv("PILOSA_" + suffix, value)
+    ex = Executor(holder, plan_cache=PlanCache())
+    try:
+        assert _configured(ex) == _configured(base)
+        assert _configured(ex) == {
+            "dispatch": True,
+            "prefetch": True,
+            "fusion": True,
+            "stacked_max_batch": 32,
+            "auto_min_containers": 64,
+            "device_cache_bytes": 256 << 20,
+            "oom_cpu_cooldown_s": 30.0,
+        }
+    finally:
+        ex.close()
+        base.close()

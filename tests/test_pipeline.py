@@ -1,12 +1,13 @@
 """Serving pipeline (ISSUE 2): bounded admission with 503 + Retry-After
 sheds, deadline propagation/cancellation at stage boundaries,
-singleflight coalescing, cross-request batching, graceful drain, and
-the /debug/pipeline + metrics surface.
+singleflight coalescing, one execution a queued request, graceful
+drain, and the /debug/pipeline + metrics surface.
 
 Server-level tests run a real in-process server on :0 under
 JAX_PLATFORMS=cpu (the tier-1 environment)."""
 
 import json
+import os
 import threading
 import time
 import urllib.error
@@ -67,7 +68,7 @@ def seed(server, index="pl", n_rows=4):
     rows, cols = [], []
     for r in range(n_rows):
         # row r gets r+1 bits in shard 0 and r+1 in shard 1 — distinct
-        # per-row counts so combined-batch result splitting is provable
+        # per-row counts, so an answer handed to the wrong request shows
         for c in range(r + 1):
             rows.append(r)
             cols.append(c * 13 + r)
@@ -221,7 +222,7 @@ def test_overload_sheds_503_with_retry_after(tmp_path):
         lock = threading.Lock()
 
         def client(i):
-            # writes: never coalesced or batch-combined, so each one
+            # writes: never coalesced, so each one
             # occupies a real worker/queue slot
             st, body, hd = req(s, "POST", "/index/ov/query", f"Set({i}, f=9)".encode())
             with lock:
@@ -340,16 +341,19 @@ def test_permuted_argument_order_queries_coalesce(tmp_path):
         s.close()
 
 
-# -- cross-request batching -------------------------------------------------
+# -- a backlog behind one worker --------------------------------------------
 
 
-def test_homogeneous_queued_queries_batch_into_one_execution(tmp_path):
-    # dispatch_enabled=False pins the legacy pipeline gang-batching
-    # path: with the dispatch engine on, cross-request combining moves
-    # into the engine (dispatch_handoff) and is covered by
-    # tests/test_dispatch.py instead
+@pytest.mark.parametrize("dispatch_enabled", [True, False])
+def test_queued_same_shape_queries_run_one_execution_each(tmp_path, dispatch_enabled):
+    """A lone worker stalled on the first request, the rest piled up
+    behind it: the worker pops one entry and runs it, so every request
+    is answered right by its own execution, with or without a dispatch
+    engine (wide waves are tests/test_dispatch.py's)."""
     s = make_server(
-        tmp_path, pipeline_interactive_workers=1, dispatch_enabled=False
+        tmp_path,
+        pipeline_interactive_workers=1,
+        dispatch_enabled=dispatch_enabled,
     )
     try:
         seed(s, "ba", n_rows=4)
@@ -397,10 +401,17 @@ def test_homogeneous_queued_queries_batch_into_one_execution(tmp_path):
             st, body = results[row]
             assert st == 200, body
             assert body == {"results": [2 * (row + 1)]}, (row, body)
-        stats = s.pipeline.stats()
-        assert stats["batches"] >= 1
-        assert stats["batched_entries"] >= 2
-        assert metrics.snapshot().get("pipeline.batches", 0) >= 1
+        assert len(exec_calls) == 4  # one execution a request
+        # `completed` is counted after the waiter is released
+        deadline = time.monotonic() + 5
+        while time.monotonic() < deadline:
+            stats = s.pipeline.stats()
+            if stats["classes"]["interactive"]["completed"] >= 4:
+                break
+            time.sleep(0.005)
+        assert stats["classes"]["interactive"]["completed"] == 4
+        assert stats["classes"]["interactive"]["sheds"] == 0
+        assert stats["deadline_expired"] == 0
     finally:
         s.close()
 
@@ -491,7 +502,7 @@ def test_pipeline_disabled_still_serves_with_deadlines(tmp_path):
 
 
 def test_closed_loop_smoke_populates_queue_wait_metrics(tmp_path):
-    """test_bench_headline-style smoke: a short closed-loop window
+    """A short closed-loop window
     through the full HTTP path populates the pipeline's queue-wait and
     admission metrics, /debug/pipeline, and the Prometheus families."""
     s = make_server(tmp_path, pipeline_interactive_workers=2)
@@ -563,7 +574,36 @@ def test_debug_pipeline_snapshot_shape(tmp_path):
                 "sheds",
                 "completed",
             } <= set(cls)
-        for k in ("coalesce_hits", "batches", "batched_entries", "deadline_expired"):
+        for k in ("coalesce_hits", "deadline_expired"):
             assert k in stats
     finally:
         s.close()
+
+
+def test_generated_config_loads_back_as_the_defaults():
+    """What `generate-config` renders today is what the version before
+    the pipeline's gang batching went rendered, less its one
+    `pipeline-batch-max = 16` line: a file mended as
+    docs/configuration.md "Retired options" says loads, unchanged."""
+    from pilosa_tpu.server import config as config_mod
+
+    toml = Config().to_toml()
+    assert "pipeline-batch" not in toml
+    assert Config.from_dict(config_mod.tomllib.loads(toml)) == Config()
+
+
+@pytest.mark.parametrize("line", ["pipeline-batch-max = 16", "pipeline-batch-window = 0.002"])
+def test_a_retired_pipeline_option_is_refused_by_name(line):
+    """The loader's rule has no exception for keys an older version
+    wrote: the server refuses to start, names the key and says where
+    the way out is written down (and the docs do hold it)."""
+    from pilosa_tpu.server import config as config_mod
+
+    key = line.split(" = ")[0]
+    with pytest.raises(ValueError) as ei:
+        Config.from_dict(config_mod.tomllib.loads(line + "\n" + Config().to_toml()))
+    assert key in str(ei.value) and "Retired options" in str(ei.value)
+    docs = os.path.join(os.path.dirname(__file__), "..", "docs", "configuration.md")
+    with open(docs) as f:
+        retired = f.read().split("## Retired options")[1]
+    assert f"`{key}`" in retired

@@ -183,18 +183,6 @@ def test_wfq_idle_tenant_gets_no_banked_credit():
     assert 3 <= first10.count("b") <= 7, first10
 
 
-def test_wfq_remove_is_respected():
-    q = _TenantFairQueue(lambda t: 1.0)
-    es = [entry("a") for _ in range(5)]
-    for e in es:
-        q.append(e)
-    q.remove(es[1])
-    assert len(q) == 4
-    assert es[1] not in list(q)
-    out = [q.popleft() for _ in range(4)]
-    assert es[1] not in out
-
-
 def test_starved_tenant_queue_wait_stays_inside_deadline_budget():
     """End-to-end pipeline regression: a 100x flooder on one tenant
     must not push the other tenant's queue wait past its deadline
