@@ -127,7 +127,10 @@ class DeviceHealth:
         # guarded call runs to its end as it did before the hand-over)
         fn = trace.carried(fn)
 
+        picked_up: list[float] = []
+
         def run():
+            picked_up.append(time.monotonic())
             started.set()
             return fn()
 
@@ -146,8 +149,13 @@ class DeviceHealth:
         # during a burst; the probe distinguishes the two cases cheaply:
         # only a failed probe condemns the device, a healthy one degrades
         # just this call to CPU.
-        with trace.leg(trace.WF_GUARD_QUEUE):
+        # ... and it ends when the worker picks the call up: this thread
+        # wakes later than that (the worker holds the interpreter's lock
+        # and is inside the call's first legs by then)
+        with trace.leg(trace.WF_GUARD_QUEUE) as queued:
             admitted = started.wait(timeout=min(timeout, self.admission_timeout_s))
+            if picked_up:
+                queued.until = picked_up[0]
         if not admitted:
             fut.cancel()
             self.saturations += 1

@@ -19,6 +19,7 @@ local loop over shards.
 
 from __future__ import annotations
 
+import bisect
 import threading
 import time
 from dataclasses import dataclass
@@ -2715,25 +2716,29 @@ class Executor:
 
 
 # Lazy-scoring chunk schedule, shared by both providers: a small head
-# (the walk usually prunes inside it) then large chunks for deep walks.
-# The sizes are not measured on the current machine. A head smaller
-# than the hot candidate set sends the walk into the second chunk: with
-# 256 hot rows per shard chip_smoke.py's TopN stages 4096 more
-# candidates per shard, nearly all of them one-bit rows (PERF.md).
+# (the walk usually prunes inside it), then the ladder's large chunks
+# for a walk that knows no end yet. The sizes are not measured on the
+# current machine. They are a cap, not the schedule: where every
+# shard's threshold is fixed the cached counts say where the walk
+# ends, and the next chunk stops there (_walk_ends, _score_next). With
+# 256 hot rows a shard over a one-bit tail the second chunk holds the
+# 128 hot rows the head left, not 4096 candidates (PERF.md, PR 32).
 FIRST_CHUNK = 128
 SCORE_CHUNK = 4096
 MAX_CHUNK = 16384
 
 
 def _chunk_size(pos: int) -> int:
-    """Chunk size at scored-prefix position ``pos``: a small head (most
-    walks prune inside it on skewed data), then geometric growth
-    SCORE_CHUNK → MAX_CHUNK so a deep/full walk over the reference's
-    50k-entry ranked cache pays ~6 dispatches instead of ~13. Sizes
-    stay pow2 (bounded XLA compile cache) and the schedule is a pure
-    function of pos, so the chunk boundaries — and therefore the
-    stager's content-derived staging keys — are identical across
-    queries and the HBM cache keeps hitting."""
+    """The ladder: the most candidates a shard the chunk at scored-prefix
+    position ``pos`` may hold. A small head (most walks prune inside it
+    on skewed data), then geometric growth SCORE_CHUNK → MAX_CHUNK so a
+    deep/full walk over the reference's 50k-entry ranked cache pays ~6
+    dispatches instead of ~13. A walk whose thresholds are fixed ends
+    its chunk sooner (_score_next's ``need``), at a power of two from
+    FIRST_CHUNK up: sizes stay pow2 (bounded XLA compile cache), and a
+    chunk is a function of its position and where the cached counts
+    end the walk, so the stager's content-derived staging keys repeat
+    with the request and the HBM cache keeps hitting."""
     if pos == 0:
         return FIRST_CHUNK
     boundary, size = FIRST_CHUNK, SCORE_CHUNK
@@ -2742,6 +2747,16 @@ def _chunk_size(pos: int) -> int:
         if size < MAX_CHUNK:
             size *= 2
     return size
+
+
+def _bounded_chunk_size(pos: int, need: Optional[int]) -> int:
+    """Size of the chunk at ``pos`` of a walk that reads no candidate
+    at or past ``need`` (None: no end known): the least power of two
+    that reaches it, not under FIRST_CHUNK, not over the ladder's."""
+    ladder = _chunk_size(pos)
+    if need is None:
+        return ladder
+    return min(ladder, max(FIRST_CHUNK, _next_pow2(need - pos)))
 
 
 def _chunk_ids(pairs, lo: int, hi: int) -> tuple[int, ...]:
@@ -2797,8 +2812,10 @@ class _ChunkedLazyScores:
     hot head (reference threshold break, fragment.go:969), so staging
     4096 candidates x S shards up front wastes HBM upload — at the 1B
     scale that is the difference between ~0.5 GB and ~2.3 GB of cold
-    staging. Later chunks grow to amortize dispatch count on deep
-    walks.
+    staging. A later chunk ends where the caller's walk says it reads
+    no further (``need``: the vectorized walk knows it from the fixed
+    thresholds and the cached counts), and grows along the ladder
+    (_chunk_size) to amortize dispatch count where no end is known.
 
     ``srcs`` may be a thunk: it resolves only when a chunk actually
     dispatches, so a pass 2 fully covered by the cross-pass carry pays
@@ -2852,12 +2869,19 @@ class _ChunkedLazyScores:
             self._srcs = self._srcs()
         return self._srcs
 
-    def _score_next(self, ends_walk: bool = False) -> None:
-        """Stage and score the next chunk. ``ends_walk``: the caller's
-        walk cannot read past this chunk (_chunk_ends_walk), so the one
-        after it is not staged ahead."""
+    def _score_next(self, need: Optional[int] = None) -> None:
+        """Stage and score the next chunk. ``need``: no shard's walk
+        reads a candidate at or past this position (_walk_ends); a
+        caller that knows no such bound leaves it out. A chunk that
+        reaches ``need`` is the walk's last, so the one after it is not
+        staged ahead."""
         lo = self._pos
-        size = _chunk_size(lo)
+        size = _bounded_chunk_size(lo, need)
+        ends_walk = need is not None and need <= lo + size
+        metrics.count(
+            metrics.TOPN_CHUNKS,
+            how="head" if lo == 0 else "bounded" if size < _chunk_size(lo) else "ladder",
+        )
         hi = lo + size
         self._pos = hi
         with trace.leg(trace.WF_TOPN_CANDIDATES):
@@ -2869,7 +2893,7 @@ class _ChunkedLazyScores:
         # Deep walks thus pipeline host packing with device compute
         # instead of alternating them serially. NOT from the head
         # chunk (lo == 0): most walks prune inside it on skewed data —
-        # eagerly staging the 4096-candidate chunk behind it would
+        # eagerly staging the ladder's chunk behind it would
         # re-introduce exactly the cold-staging cost the small head
         # chunk was measured to avoid (class docstring). The decision
         # runs here, on the request's thread, before this chunk's
@@ -2877,7 +2901,7 @@ class _ChunkedLazyScores:
         # number a shard), not what the next chunk holds (_prefetch).
         if lo > 0 and hi < self._max_len and not ends_walk:
             with trace.leg(trace.WF_TOPN_CANDIDATES):
-                self._prefetch(hi)
+                self._prefetch(hi, need)
         if staged is None:  # no shard contributed blocks — all score 0
             mat = np.zeros((len(self._frags), size), dtype=np.int32)
         else:
@@ -2938,9 +2962,11 @@ class _ChunkedLazyScores:
         whose shards hold ``blocks_by_shard`` nonempty blocks."""
         raise NotImplementedError
 
-    def _prefetch(self, lo: int) -> None:
+    def _prefetch(self, lo: int, need: Optional[int] = None) -> None:
         """Stage the chunk at ``lo`` ahead on a side thread, where that
-        pushes nothing out. Advisory means it may not evict: staging
+        pushes nothing out; ``need`` ends it where it will end the
+        walk's own (_score_next), or another bundle than the one the
+        walk asks for is built. Advisory means it may not evict: staging
         ahead a chunk that does not fit would push out the chunks this
         walk is scoring, and every later query would stage all of them
         again. Every request of a deep walk asks, so the question is
@@ -2951,7 +2977,7 @@ class _ChunkedLazyScores:
         racing write can mis-stage, never mis-answer."""
         if self._prefetching:
             return
-        size = _chunk_size(lo)
+        size = _bounded_chunk_size(lo, need)
         hi = lo + size
         block_bytes = ops.packed.CONTAINER_WORDS * 4
         has_room = self._ex.stager.has_room
@@ -3192,7 +3218,14 @@ def _vectorized_topn_walk(pairs_by_shard, provider, opt_: TopOptions):
     Shards with fewer than n qualifying candidates scan their whole
     pairs list (the scalar loop never leaves phase 1). The cross-shard
     merge (pairs_add + final sort_pairs) is order-insensitive, so the
-    picked SETS being identical makes the result bit-identical."""
+    picked SETS being identical makes the result bit-identical.
+
+    The break needs no score: cached counts are in the ranked lists.
+    So where the scored prefix holds no shard's break yet, the lists
+    say how far each walk can still read (_walk_ends): a shard whose
+    end is the prefix's is done without its break candidate ever being
+    scored, and the furthest end over the shards bounds the next chunk
+    (_score_next)."""
     if opt_.tanimoto_threshold > 0:
         return None
     if opt_.filter_name and opt_.filter_values:
@@ -3247,6 +3280,11 @@ def _vectorized_topn_walk(pairs_by_shard, provider, opt_: TopOptions):
         has_brk = brk_mask.any(axis=1)
         exhausted = P >= lengths
         done = (has_n & has_brk) | exhausted
+        need = None
+        if not done.all():
+            ends = _walk_ends(pairs_by_shard, P, done, has_n, T, mth)
+            done |= ends <= P
+            need = int(ends.max())
         if done.all():
             brk = np.where(has_brk, np.argmax(brk_mask, axis=1), P)
             phase2 = (
@@ -3265,27 +3303,36 @@ def _vectorized_topn_walk(pairs_by_shard, provider, opt_: TopOptions):
             # implies exhausted.all()); bail to the scalar walk rather
             # than risk looping
             return None
-        provider._score_next(
-            ends_walk=_chunk_ends_walk(pairs_by_shard, P, has_n, T, mth)
-        )
+        provider._score_next(need)
 
 
-def _chunk_ends_walk(pairs_by_shard, pos: int, has_n, T, mth: int) -> bool:
-    """Will every shard's walk end inside the chunk at ``pos``, whatever
-    it scores? A shard's does where the chunk holds its last candidate,
-    or where its threshold is fixed (``has_n``) and the chunk holds an
-    eligible candidate whose cached count is below it: the break.
-    Cached counts fall along a ranked list, so the chunk's last
-    candidate decides. Then no walk reads the chunk after this one, and
-    staging it ahead would build and hold a bundle for nothing (at 128
-    shards 8 GiB, assembled on a side thread while requests are
-    served). Advisory like the staging it steers: a list out of order
-    can mis-stage, never mis-answer."""
-    hi = pos + _chunk_size(pos)
-    for pairs, fixed, t in zip(pairs_by_shard, has_n.tolist(), T.tolist()):
-        if len(pairs) > hi and not (fixed and mth <= pairs[hi - 1][1] < t):
-            return False
-    return True
+def _walk_ends(pairs_by_shard, pos: int, done, has_n, T, mth: int) -> np.ndarray:
+    """Per shard, the position at and past which its walk reads (scores)
+    no candidate, whatever the candidates from ``pos`` on score:
+    int64[S], never under ``pos``. A shard that is ``done`` reads none.
+    One whose threshold is fixed (``has_n``) reads up to the first
+    candidate whose cached count is under it: its break, or, under the
+    minimum too, the first of a tail it skips to the list's end. One
+    that has not pushed n candidates yet reads every candidate that
+    reaches the minimum: nothing tighter is known of it. Cached counts
+    fall along a ranked list (cache.sort_pairs, RankCache.recalculate),
+    so one search a shard finds the place."""
+    ends = np.full(len(pairs_by_shard), pos, dtype=np.int64)
+    for i, (pairs, over, fixed, t) in enumerate(
+        zip(pairs_by_shard, done.tolist(), has_n.tolist(), T.tolist())
+    ):
+        if not over and len(pairs) > pos:
+            # descending by count: the first position whose count is
+            # under the threshold is where the negated counts pass its
+            # negation
+            ends[i] = bisect.bisect_right(
+                pairs, -(t if fixed else mth), pos, len(pairs), key=_neg_count
+            )
+    return ends
+
+
+def _neg_count(pair) -> int:
+    return -pair[1]
 
 
 def _merge_picked(ids: np.ndarray, counts: np.ndarray) -> list[tuple[int, int]]:

@@ -122,6 +122,8 @@ TOPN_PREFETCH_DECISIONS = "topn.prefetch_decisions"
 TOPN_PREFETCH_STARTS = "topn.prefetch_starts"
 # (shard, id) reads of a TopN's exact-count pass, by how they were answered
 TOPN_PASS2_IDS = "topn.pass2_ids"
+# candidate chunks a cross-shard TopN scored, by what set their size
+TOPN_CHUNKS = "topn.chunks"
 # TopN rank/LRU caches
 CACHE_HITS = "cache.hits"
 CACHE_MISSES = "cache.misses"
@@ -438,6 +440,14 @@ METRICS: dict[str, tuple[str, str]] = {
         "absent cache, a write since the snapshot, a winner outside the "
         "scored prefix, a tanimoto or attribute filter, a shard the "
         "device did not score)",
+    ),
+    TOPN_CHUNKS: (
+        "counter",
+        "candidate chunks a cross-shard TopN staged and scored, counted "
+        "once a chunk (label: how = head, the first 128 candidates a "
+        "shard; bounded, a later chunk that the walk's fixed thresholds "
+        "and the cached counts ended short of the ladder's size, the "
+        "walk's last; ladder, a later chunk of the ladder's size)",
     ),
     CACHE_HITS: ("counter", "TopN rank/LRU cache hits"),
     CACHE_MISSES: ("counter", "TopN rank/LRU cache misses"),

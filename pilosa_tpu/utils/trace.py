@@ -686,12 +686,17 @@ def set_capturing(on: bool) -> None:
 
 class leg:
     """``t0`` and ``seconds`` (the whole interval, inner legs included)
-    are there for a site that also feeds a histogram or a span."""
+    are there for a site that also feeds a histogram or a span.
+    ``until``: a stamp of another thread's clock the leg ends at, where
+    that is sooner than this thread's own exit: a wait that the other
+    thread ended is over when it says so, not when this one wakes up,
+    and the seconds between are the other thread's legs already."""
 
-    __slots__ = ("stage", "t0", "seconds", "_inner", "_parent", "_ann")
+    __slots__ = ("stage", "t0", "seconds", "until", "_inner", "_parent", "_ann")
 
     def __init__(self, stage: str) -> None:
         self.stage = stage
+        self.until = None
 
     def __enter__(self) -> "leg":
         self._inner = 0.0
@@ -707,7 +712,10 @@ class leg:
         return self
 
     def __exit__(self, *exc) -> bool:
-        dt = self.seconds = time.monotonic() - self.t0
+        end = time.monotonic()
+        if self.until is not None:
+            end = min(end, self.until)
+        dt = self.seconds = end - self.t0
         if self._ann is not None:
             self._ann.__exit__(*exc)
         parent = _open_leg.leg = self._parent

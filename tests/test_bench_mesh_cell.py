@@ -50,13 +50,15 @@ def _pow2(n: int) -> int:
 
 def test_the_toml_loads_and_its_budget_holds_what_the_configuration_stages():
     """Counted from the file's own numbers: per shard the head chunk is
-    the first FIRST_CHUNK hot rows, the second the other hot rows and
-    singletons up to SCORE_CHUNK ids; a hot row fills all 16 blocks, a
-    singleton one. Blocks are padded to a power of two a shard. The
-    cell's walks end in the second chunk; a walk that went on would
-    stage the third, 2 x SCORE_CHUNK singletons. Over one default share
-    (so one chip's share cannot hold it), under the TOML's budget with
-    the third chunk too."""
+    the first FIRST_CHUNK hot rows, and the cell's walks, their
+    thresholds fixed by the head, end the second at the last hot row
+    (PR 32: it held the ladder's SCORE_CHUNK ids, singletons up to
+    there, before). A hot row fills all 16 blocks, a singleton one.
+    Blocks are padded to a power of two a shard. A walk that knew no
+    end would stage the ladder's second chunk, and going on its third,
+    2 x SCORE_CHUNK singletons: the TOML's budget holds those too, one
+    default share does not. What the cell's own walks stage is under
+    one default share since PR 32 (PERF.md section 7)."""
     cfg = Config.from_toml(TOML)
     assert cfg.device_policy == "always" and cfg.mesh_devices == DEVICES
     assert CONFIG["server_flags"] == ["-c", os.path.relpath(TOML, ROOT)]
@@ -66,18 +68,19 @@ def test_the_toml_loads_and_its_budget_holds_what_the_configuration_stages():
     first, second = executor_mod.FIRST_CHUNK, executor_mod.SCORE_CHUNK
     assert hot > first and f["hot_bits"] > 16 * 1024  # every block of a hot row is set
     assert f["tail_rows"] >= first + second + 2 * second  # the ranked cache reaches the third chunk
+    assert executor_mod._bounded_chunk_size(first, hot) == hot - first == first
     per_shard = (
         _pow2(16 * first),
+        _pow2(16 * (hot - first)),
         _pow2(16 * (hot - first) + second - (hot - first)),
         _pow2(2 * second),
     )
-    bundles = [shards * b * (BLOCK_BYTES + 8) for b in per_shard]  # + brow, bslot
+    head, bounded, ladder2, ladder3 = [shards * b * (BLOCK_BYTES + 8) for b in per_shard]  # + brow, bslot
     row_stacks = 16 * shards * (1 << 20) // 8  # the 16 group-base filter rows
-    staged = sum(bundles) + row_stacks
-    assert bundles[0] == pytest.approx(2 << 30, rel=0.01)
-    assert bundles[1] == bundles[2] == pytest.approx(8 << 30, rel=0.01)
-    assert Config().stager_budget_bytes < sum(bundles[:2]) + row_stacks
-    assert staged < cfg.stager_budget_bytes == DEVICES * Config().stager_budget_bytes
+    assert head == bounded == pytest.approx(2 << 30, rel=0.01)
+    assert ladder2 == ladder3 == pytest.approx(8 << 30, rel=0.01)
+    assert head + bounded + row_stacks < Config().stager_budget_bytes < head + ladder2 + row_stacks
+    assert head + ladder2 + ladder3 + row_stacks < cfg.stager_budget_bytes == DEVICES * Config().stager_budget_bytes
 
 
 def test_the_manifest_names_the_cell_with_four_chips_and_its_metrics():
@@ -143,7 +146,8 @@ def test_the_cell_runs_correct_on_a_four_device_mesh(monkeypatch, tmp_path):
     w = phases["window"]
     assert w["server_exit_code"] == 0 and w["fallbacks_in_window"] == {}
     assert w["compiles_in_window"] == 0 and w["stager_restaged_bytes_in_window"] == 0
-    assert phases["warm_up"]["compiles_by_kind"].get("topn_scores_sparse") == 2  # head and second chunk
+    # the second chunk is the hot rows the head left: the head's shape, one program
+    assert phases["warm_up"]["compiles_by_kind"].get("topn_scores_sparse") == 1
     assert w["stage_ms_per_request"]["mesh.fetch"] > 0
     assert w["stage_ms_per_request"]["stager"] == 0
 
@@ -162,7 +166,7 @@ SMALL = {
     "shards": 8,
     "fields": [
         # the cell's shape, thinner: 256 hot rows in groups of 16 (more than
-        # FIRST_CHUNK, so the walk enters the second chunk), 6,000 singletons
+        # FIRST_CHUNK, so the walk enters a second chunk), 6,000 singletons
         {**datagen.field_of(CONFIG, "f"), "hot_bits": 3000, "tail_rows": 6000},
     ],
 }
@@ -223,9 +227,16 @@ def test_mesh_and_one_chip_paths_equal_the_plain_reference(build, mesh, seed):
             assert same_answer(call, got_four, want), (q, got_four, want)
             assert same_answer(call, got_one, want), (q, got_one, want)
             assert same_answer(call, got_four, got_one)
-        # the walk went past the head chunk on the mesh: both chunk sizes compiled
+        # the walk went past the head chunk on the mesh, as far as the last
+        # hot row: two chunks of the head's size, one compiled program
+        staged = sorted(k[-2] for k in four.stager._cache if "sparse_rows_stack" in k)
+        assert staged[:2] == [128, 128]
+        # at this size two rows can meet in one column: a threshold of 1 is
+        # not over the one-bit tail, and that walk reads its whole list, the
+        # ladder's chunk and then one to the list's end (6,256)
+        assert staged[2:] in ([], [2048, 4096])
         sizes = {k[1] for k in four._spmd_kernels if k[0] == "topn_scores_sparse"}
-        assert {executor_mod.FIRST_CHUNK, executor_mod.SCORE_CHUNK} <= sizes
+        assert sizes == set(staged)
         assert not any(k[0] == "topn_scores_sparse" for k in one._spmd_kernels)
         # and on one device no second goes to the mesh's leg
         wf: dict = {}
@@ -242,9 +253,9 @@ def _counter(name: str, **labels) -> float:
 
 
 def test_a_second_pass_stages_nothing_and_every_stack_lies_a_quarter_a_device(built, mesh):
-    """And no pass stages the third chunk ahead: every walk of the cell
-    ends in the second (128 hot rows, then singletons under any
-    threshold), and says so. At 128 shards that chunk is 8 GiB, and its
+    """And no pass stages a third chunk ahead: every walk of the cell
+    ends in the second, and ends it (128 hot rows; the singletons
+    behind them are under any threshold), one bounded chunk a request. At 128 shards that chunk is 8 GiB, and its
     assembly on a side thread ran through the first half of the
     measured window (my chip run, PR 29)."""
     _, h, calls = built
@@ -252,9 +263,12 @@ def test_a_second_pass_stages_nothing_and_every_stack_lies_a_quarter_a_device(bu
     try:
         queries = [traffic.pql(c) for c in calls]
         starts = _counter(metrics.TOPN_PREFETCH_STARTS)
+        chunks = [_counter(metrics.TOPN_CHUNKS, how=how) for how in ("head", "bounded", "ladder")]
         for q in queries:
             ex.execute(SMALL["index"], q)
         assert _counter(metrics.TOPN_PREFETCH_STARTS) == starts
+        grown = [_counter(metrics.TOPN_CHUNKS, how=how) for how in ("head", "bounded", "ladder")]
+        assert [g - c for g, c in zip(grown, chunks)] == [len(queries), len(queries), 0]
         assert not any(t.name == "stage-prefetch" for t in threading.enumerate())
         st = ex.stager
         before = (st.misses, st._bytes, _counter(metrics.STAGER_RESTAGED_BYTES),

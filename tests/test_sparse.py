@@ -270,10 +270,12 @@ class TestAdvisoryPrefetchNeverEvicts:
     the walk may go on to it. That prefetch is advisory: where it does
     not fit it must be skipped, or it pushes out the chunks the walk is
     scoring and every later query stages all of them again; and where
-    the walk is sure to end in the second chunk it is not asked for."""
+    the walk is sure to end in the second chunk it is not asked for,
+    and the second chunk ends where the walk does."""
 
     # n=5: every shard has its threshold after the head chunk and meets
-    # a cached count below it in the second, so the walk ends there.
+    # a cached count below it at position 140, so the second chunk is
+    # the 12 hot rows left (padded to 128) and the walk ends there.
     # n=130: the head chunk's 128 rows fix no threshold, so the walk may
     # go on past the second chunk and the third is asked for; the twelve
     # hot rows left fix it there and the one-bit tail breaks the walk,
@@ -320,7 +322,7 @@ class TestAdvisoryPrefetchNeverEvicts:
         cpu = Executor(h, device_policy="never")
         starts = _counter(metrics.TOPN_PREFETCH_STARTS)
         assert ex.execute("i", self.MAY_GO_ON) == cpu.execute("i", self.MAY_GO_ON)
-        assert self._staged_chunks(ex) == [128, 4096, 8192]
+        assert self._staged_chunks(ex) == [128, 512, 4096]
         assert _counter(metrics.TOPN_PREFETCH_STARTS) == starts + 1
         h.close()
 
@@ -336,8 +338,30 @@ class TestAdvisoryPrefetchNeverEvicts:
         before = _decisions(), _counter(metrics.TOPN_PREFETCH_STARTS)
         for _ in range(2):
             assert ex.execute("i", self.ENDS) == cpu.execute("i", self.ENDS)
-        assert self._staged_chunks(ex) == [128, 4096]
+        assert self._staged_chunks(ex) == [128, 128]
         assert (_decisions(), _counter(metrics.TOPN_PREFETCH_STARTS)) == before
+        h.close()
+
+    @pytest.mark.parametrize(
+        "query, grown",
+        [
+            (ENDS, {"head": 1, "bounded": 1, "ladder": 0}),
+            # no threshold after the head: the ladder's 4096, which fixes
+            # every threshold and holds every break
+            (MAY_GO_ON, {"head": 1, "bounded": 0, "ladder": 1}),
+            # every walk ends inside the head
+            ("TopN(f, Row(f=0), n=5, threshold=51)", {"head": 1, "bounded": 0, "ladder": 0}),
+        ],
+        ids=["ends", "may_go_on", "head_only"],
+    )
+    def test_a_chunk_is_counted_by_what_set_its_size(self, tmp_path, query, grown):
+        h = self._deep_walk_holder(tmp_path)
+        ex = Executor(h, device_policy="always")
+        cpu = Executor(h, device_policy="never")
+        before = _chunks()
+        assert ex.execute("i", query) == cpu.execute("i", query)
+        after = _chunks()
+        assert {how: after[how] - before[how] for how in after} == grown
         h.close()
 
     def test_prefetch_is_skipped_where_it_would_evict(self, tmp_path):
@@ -390,33 +414,213 @@ def _decisions():
     }
 
 
+def _chunks():
+    from pilosa_tpu.utils import metrics
+
+    return {
+        how: _counter(metrics.TOPN_CHUNKS, how=how)
+        for how in ("head", "bounded", "ladder")
+    }
+
+
 def _ranked(counts):
     return [(i, c) for i, c in enumerate(counts)]
 
 
+def _table_scores(pairs_by_shard, table):
+    """The cross-shard chunk provider (executor._ChunkedLazyScores) over
+    a table of scores a shard: stages a chunk's ids, scores them from
+    the table, records the chunks it was asked to stage ahead."""
+    from pilosa_tpu.executor.executor import _bounded_chunk_size, _ChunkedLazyScores
+
+    class Provider(_ChunkedLazyScores):
+        def _stage(self, ids_by_shard, size, peek=False):
+            return ids_by_shard if table is not None else None  # None: all score 0
+
+        def _score(self, staged, size):
+            mat = np.zeros((len(staged), size), dtype=np.int32)
+            for i, ids in enumerate(staged):
+                mat[i, : len(ids)] = [table[i][rid] for rid in ids]
+            return mat
+
+        def _prefetch(self, lo, need=None):
+            self.prefetched.append((lo, _bounded_chunk_size(lo, need)))
+
+    p = Provider(None, [object()] * len(pairs_by_shard), pairs_by_shard, None)
+    p.prefetched = []
+    return p
+
+
+def _chunk_sizes(provider):
+    return [size for _, size, _ in provider._chunk_meta]
+
+
 @pytest.mark.parametrize(
-    "lists, has_n, T, mth, ends",
+    "lists, has_n, T, mth, need, size, ends",
     [
-        # each shard has its threshold and the chunk's last cached count is under it
-        ([[9] * 130 + [1] * 5000] * 2, [True, True], [4, 7], 1, True),
-        # one shard's threshold is not fixed and its list goes on
-        ([[9] * 130 + [1] * 5000] * 2, [True, False], [4, 1 << 62], 1, False),
+        # each shard has its threshold and meets a cached count under it at 130
+        ([[9] * 130 + [1] * 5000] * 2, [True, True], [4, 7], 1, 130, 128, True),
+        # one shard's threshold is not fixed and its list goes on: the ladder's chunk
+        ([[9] * 130 + [1] * 5000] * 2, [True, False], [4, 1 << 62], 1, 5130, 4096, False),
         # ... but a list that ends inside the chunk ends the walk whatever it scores
-        ([[9] * 130 + [1] * 5000, [9] * 300], [True, False], [4, 1 << 62], 1, True),
+        ([[9] * 130 + [1] * 5000, [9] * 300], [True, False], [4, 1 << 62], 1, 300, 256, True),
         # the last cached count still clears the threshold: the walk goes on
-        ([[9] * 5000], [True], [4], 1, False),
-        # a count under the minimum is no break, the walk reads on to the list's end
-        ([[9] * 130 + [1] * 5000], [True], [4], 2, False),
-        ([[]], [False], [1 << 62], 1, True),
+        ([[9] * 5000], [True], [4], 1, 5000, 4096, False),
+        # a count under the minimum is no break, but nothing under it is ever
+        # scored: the walk skips to the list's end and reads no further
+        ([[9] * 130 + [1] * 5000], [True], [4], 2, 130, 128, True),
+        ([[9] * 130 + [1] * 5000], [False], [1 << 62], 2, 130, 128, True),
+        ([[]], [False], [1 << 62], 1, 128, 128, True),
+        # a count equal to the threshold is read; the first under it is not
+        ([[9] * 128 + [4] * 300 + [3] * 5000], [True], [4], 1, 428, 512, True),
+        # the bound is a power of two from the prefix: the chunk ends on the
+        # break candidate, which no chunk scores
+        ([[9] * 256 + [1] * 5000], [True], [4], 1, 256, 128, True),
+        ([[9] * 640 + [1] * 5000], [True], [4], 1, 640, 512, True),
+        # the break is the ladder's chunk's last candidate, or the first behind it
+        ([[9] * 4223 + [1] * 5000], [True], [4], 1, 4223, 4096, True),
+        ([[9] * 4224 + [1] * 5000], [True], [4], 1, 4224, 4096, True),
+        # ... or further: the ladder's chunk, and the walk asks again behind it
+        ([[9] * 4225 + [1] * 5000], [True], [4], 1, 4225, 4096, False),
+        # the furthest shard decides
+        ([[9] * 130 + [1] * 5000, [9] * 700 + [1] * 10], [True, True], [4, 4], 1, 700, 1024, True),
     ],
-    ids=["break_in_chunk", "no_threshold", "list_ends", "no_break", "below_minimum", "empty"],
+    ids=[
+        "break_in_chunk", "no_threshold", "list_ends", "no_break", "below_minimum",
+        "below_minimum_no_threshold", "empty", "tie_at_threshold", "pow2_bound",
+        "pow2_bound_512", "break_ends_ladder_chunk", "break_behind_ladder_chunk",
+        "break_past_ladder_chunk", "furthest_shard",
+    ],
 )
-def test_chunk_ends_walk(lists, has_n, T, mth, ends):
-    from pilosa_tpu.executor.executor import FIRST_CHUNK, _chunk_ends_walk
+def test_walk_ends_bound_the_next_chunk(lists, has_n, T, mth, need, size, ends):
+    """What was _chunk_ends_walk's question (PR 29: will every walk end
+    inside the next chunk?) is now where: the chunk ends there, is the
+    walk's last, and asks for no chunk to be staged ahead."""
+    from pilosa_tpu.executor.executor import FIRST_CHUNK, _walk_ends
 
     pairs = [_ranked(c) for c in lists]
-    got = _chunk_ends_walk(pairs, FIRST_CHUNK, np.array(has_n), np.array(T, dtype=np.int64), mth)
-    assert got is ends
+    done = np.zeros(len(pairs), dtype=bool)
+    got = _walk_ends(pairs, FIRST_CHUNK, done, np.array(has_n), np.array(T, dtype=np.int64), mth)
+    assert got.dtype == np.int64 and (got >= FIRST_CHUNK).all()
+    assert int(got.max()) == need
+    assert (_walk_ends(pairs, FIRST_CHUNK, ~done, np.array(has_n), np.array(T), mth) == FIRST_CHUNK).all()
+    # lists that go on behind every chunk here, so that staging ahead is asked for
+    provider = _table_scores([p + [(10**6, 1)] * 20000 for p in pairs], None)
+    provider._pos = FIRST_CHUNK
+    provider._score_next(need)
+    assert _chunk_sizes(provider) == [size]
+    # what is staged ahead ends where the walk does, too
+    ahead = min(8192, max(128, 1 << (need - 4224 - 1).bit_length()))
+    assert provider.prefetched == ([] if ends else [(4224, ahead)])
+
+
+def _walk_case(rng, kind):
+    """(cached counts a shard, scores a shard by position, n, threshold,
+    the chunk sizes the walk must score) of one named shape."""
+    S = 3
+    hot = lambda k: np.sort(rng.integers(40000, 60000, size=k))[::-1].tolist()
+    warm = lambda k: rng.integers(2000, 3000, size=k).tolist()
+    if kind == "cliff":  # tall64's shape: hot rows, then a one-bit tail
+        k = int(rng.integers(129, 257))
+        return [hot(k) + [1] * 5000] * S, [warm(k) + [0] * 5000] * S, 10, 0, [128, 128]
+    if kind == "pow2_bound":  # the break candidate is in no scored chunk
+        return [hot(640) + [1] * 5000] * S, [warm(640) + [1] * 5000] * S, 10, 0, [128, 512]
+    if kind == "ties_at_threshold":
+        # every shard's threshold is 40; cached 40 is read, 39 is the break
+        counts = [100] * 128 + [40] * 172 + [39] * 5000
+        scores = [40] * 10 + rng.integers(0, 80, size=len(counts) - 10).tolist()
+        return [counts] * S, [scores] * S, 10, 0, [128, 256]
+    if kind == "min_threshold":
+        # the tail is under the minimum: never scored, and no break
+        counts = hot(150) + [3] * 5000
+        scores = rng.integers(0, 9, size=150).tolist() + [3] * 5000
+        return [counts] * S, [scores] * S, 10, 5, [128, 128]
+    if kind == "short_shard":
+        k = int(rng.integers(129, 257))
+        return (
+            [hot(k) + [1] * 5000, hot(50), hot(k) + [1] * 300],
+            [warm(k) + [0] * 5000, warm(50), warm(k) + [1] * 300],
+            10, 0, [128, 128],
+        )
+    if kind == "few_qualify":
+        # one shard never pushes n: nothing bounds it but its list's end
+        return (
+            [hot(200) + [1] * 5000, hot(200) + [1] * 4800],
+            [warm(200) + [0] * 5000, [7] * 3 + [0] * 4997],
+            10, 0, [128, 4096, 1024],
+        )
+    if kind == "past_ladder_chunk":
+        return [hot(5128) + [1] * 5000] * S, [warm(5128) + [0] * 5000] * S, 10, 0, [128, 4096, 1024]
+    if kind == "head_only":
+        return [hot(100) + [1] * 5000] * S, [warm(100) + [0] * 5000] * S, 10, 0, [128]
+    raise ValueError(kind)
+
+
+def _scalar_walks(pairs_by_shard, table, opt_):
+    from pilosa_tpu.executor.executor import _ranked_walk, pairs_add
+
+    out = []
+    for pairs, scores in zip(pairs_by_shard, table):
+        out = pairs_add(out, _ranked_walk(None, opt_, pairs, scores))
+    return sorted(out)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+@pytest.mark.parametrize(
+    "kind",
+    ["cliff", "pow2_bound", "ties_at_threshold", "min_threshold", "short_shard",
+     "few_qualify", "past_ladder_chunk", "head_only"],
+)
+def test_bounded_walk_equals_the_scalar_walk(kind, seed):
+    from pilosa_tpu.core.cache import Rankings
+    from pilosa_tpu.core.fragment import TopOptions
+    from pilosa_tpu.executor.executor import _vectorized_topn_walk
+
+    rng = np.random.default_rng([seed, sum(map(ord, kind))])
+    counts, scores, n, threshold, sizes = _walk_case(rng, kind)
+    pairs_by_shard, table = [], []
+    for c, sc in zip(counts, scores):
+        ids = rng.permutation(len(c) + 50)[: len(c)].tolist()  # shards share ids
+        pairs_by_shard.append(Rankings(zip(ids, c)) if seed % 2 else list(zip(ids, c)))
+        table.append(dict(zip(ids, sc)))
+    opt_ = TopOptions(n=n, min_threshold=max(threshold, 1))
+    provider = _table_scores(pairs_by_shard, table)
+    got = _vectorized_topn_walk(pairs_by_shard, provider, opt_)
+    assert sorted(got) == _scalar_walks(pairs_by_shard, table, opt_)
+    assert _chunk_sizes(provider) == sizes
+    # staged ahead only behind a ladder chunk that was not the walk's
+    # last, and at the size the walk then asks for
+    assert provider.prefetched == ([(4224, 1024)] if len(sizes) == 3 else [])
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_bounded_walk_equals_the_scalar_walk_on_random_lists(seed):
+    """No shape in mind: lengths, counts with runs of ties, scores at or
+    over the cached count, n and the minimum drawn at random."""
+    from pilosa_tpu.core.fragment import TopOptions
+    from pilosa_tpu.executor.executor import _chunk_size, _vectorized_topn_walk
+
+    rng = np.random.default_rng(3200 + seed)
+    pairs_by_shard, table = [], []
+    for _ in range(int(rng.integers(1, 5))):
+        k = int(rng.choice([0, 40, 128, 129, 256, 700, 4224, 6000]))
+        c = np.sort(rng.integers(1, int(rng.choice([4, 60, 5000])), size=k))[::-1]
+        if k and rng.random() < 0.5:  # a cliff somewhere
+            c[int(rng.integers(0, k)) :] = 1
+        ids = rng.permutation(k + 50)[:k].tolist()
+        pairs_by_shard.append(list(zip(ids, c.tolist())))
+        table.append(dict(zip(ids, rng.integers(0, c + 1).tolist())))
+    opt_ = TopOptions(
+        n=int(rng.choice([1, 3, 10, 130, 300])),
+        min_threshold=int(rng.choice([1, 1, 2, 30])),
+    )
+    provider = _table_scores(pairs_by_shard, table)
+    got = _vectorized_topn_walk(pairs_by_shard, provider, opt_)
+    assert sorted(got) == _scalar_walks(pairs_by_shard, table, opt_)
+    lo = 0
+    for size in _chunk_sizes(provider):
+        assert size & (size - 1) == 0 and 128 <= size <= _chunk_size(lo)
+        lo += size
 
 
 class TestPrefetchDecisionCost:
@@ -444,7 +648,7 @@ class TestPrefetchDecisionCost:
     @pytest.mark.parametrize(
         "budget, first, staged",
         [
-            (None, "counted", [128, 4096, 8192]),
+            (None, "counted", [128, 512, 4096]),
             (70 << 20, "bound", [128, 4096]),
         ],
         ids=["with_room", "without_room"],
@@ -518,7 +722,7 @@ class TestPrefetchDecisionCost:
         frag = h.fragment("i", "f", "standard", 0)
         snap = frag.cache.top()
         third = snap[4224 + 10][0]
-        assert snap.chunk_blocks(4224, 12416, frag) == (512, False)
+        assert snap.chunk_blocks(4224, 4736, frag) == (512, False)
         assert ex.execute("i", f"Set({3 * 65536 + 7}, f={third})") == [True]
         assert frag.cache.top() is snap
         before = _decisions()
@@ -529,7 +733,7 @@ class TestPrefetchDecisionCost:
         assert after["memo"] == before["memo"]
         # the bound still says 1024, the count says 1025 -> 2048
         assert asked == [1024 * block, 2048 * block]
-        assert snap.chunk_blocks(4224, 12416, frag) == (513, False)
+        assert snap.chunk_blocks(4224, 4736, frag) == (513, False)
         TestAdvisoryPrefetchNeverEvicts._staged_chunks(ex)
         h.close()
 
@@ -867,10 +1071,19 @@ def test_pass2_ids_say_which_way_and_a_clean_pass2_builds_no_provider(
         h.close()
 
 
-def test_the_benchmarks_metric_reads_the_counter_and_0_where_it_is_not_published():
-    """``executor.topn_pass2_vector_ids_per_query`` by its files alone:
-    the growth of ``topn.pass2_ids{how=vector}`` a request, and 0 (not
-    an error) from a program that never publishes the sample."""
+@pytest.mark.parametrize(
+    "name, sample, how",
+    [
+        ("executor.topn_pass2_vector_ids_per_query", "topn.pass2_ids", "vector"),
+        ("executor.topn_chunks_bounded_per_query", "topn.chunks", "bounded"),
+    ],
+)
+def test_the_benchmarks_metric_reads_the_counter_and_0_where_it_is_not_published(
+    tmp_path, name, sample, how
+):
+    """A counter's metric by its files alone: the growth of
+    ``sample{how=...}`` a request, and 0 (not an error) from a program
+    that never publishes the sample."""
     import json
     import os
 
@@ -878,28 +1091,34 @@ def test_the_benchmarks_metric_reads_the_counter_and_0_where_it_is_not_published
     from benchmark.server import parse_metrics
     from pilosa_tpu.utils import metrics
 
-    name = "executor.topn_pass2_vector_ids_per_query"
     with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    entry = manifest["per_layer"][-1]
-    assert entry["name"] == name and entry["moves"] == "query_p50_ms"
+    (entry,) = [m for m in manifest["per_layer"] if m["name"] == name]
+    assert entry["moves"] == "query_p50_ms" and entry["layer"] == "executor host side"
     assert "workloads" not in entry  # every cell that reports query_p50_ms
     spec = layer_metrics.load(name)
 
-    h = _pass2_holder()
+    if sample == "topn.pass2_ids":
+        h, q = _pass2_holder(), PASS2_Q
+    else:  # a walk that goes past its head and knows where it ends
+        h, q = TestAdvisoryPrefetchNeverEvicts._deep_walk_holder(tmp_path), TestAdvisoryPrefetchNeverEvicts.ENDS
     dev = Executor(h, device_policy="always", dispatch_enabled=False)
     try:
-        dev.execute("i", PASS2_Q)
+        dev.execute("i", q)
         before = parse_metrics(metrics.render_prometheus())
-        vector = _pass2_ids()["vector"]
+        was = _counter(sample, how=how)
         for _ in range(4):
-            dev.execute("i", PASS2_Q)
+            dev.execute("i", q)
         after = parse_metrics(metrics.render_prometheus())
-        grown = _pass2_ids()["vector"] - vector
+        grown = _counter(sample, how=how) - was
     finally:
         dev.close()
         h.close()
-    assert grown > 0 and grown % (4 * PASS2_SHARDS) == 0
+    if sample == "topn.pass2_ids":
+        assert grown > 0 and grown % (4 * PASS2_SHARDS) == 0
+    else:
+        assert grown == 4  # one bounded chunk a request
     assert layer_metrics.evaluate(spec, before, after, 4, None) == grown / 4
-    parent = [m for m in after if m[0] != "topn_pass2_ids"]
+    parent = [m for m in after if m[0] != sample.replace(".", "_")]
+    assert len(parent) < len(after)
     assert layer_metrics.evaluate(spec, parent, parent, 4, None) == 0

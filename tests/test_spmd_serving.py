@@ -169,6 +169,63 @@ def test_spmd_topn_staging_is_lazy_and_bounded(mesh):
     assert total_staged_rows <= FIRST_CHUNK + SCORE_CHUNK
 
 
+@pytest.mark.parametrize("provider", ["stacked", "mesh"])
+@pytest.mark.parametrize(
+    "field, q, sizes",
+    [
+        # 200 hot rows, then a one-bit tail: the thresholds the head fixes
+        # end the second chunk at the last hot row
+        ("cliff", "TopN(cliff, Row(cliff=0), n=10)", [128, 128]),
+        # 256 hot rows: the break candidate is the first of no scored chunk
+        ("pow2", "TopN(pow2, Row(pow2=0), n=10)", [128, 128]),
+        # the tail is under the minimum: skipped, never scored
+        ("cliff", "TopN(cliff, Row(cliff=0), n=10, threshold=3)", [128, 128]),
+        # n over the head: no threshold yet, so the ladder's chunk (which
+        # holds the list's end: nothing is staged ahead behind it)
+        ("cliff", "TopN(cliff, Row(cliff=0), n=150)", [128, 4096]),
+        # every walk ends inside the head
+        ("cliff", "TopN(cliff, Row(cliff=0), n=10, threshold=41)", [128]),
+    ],
+    ids=["cliff", "pow2_bound", "min_threshold", "no_threshold", "head_only"],
+)
+def test_bounded_walk_answers_like_the_cpu_walk(bounded_holder, mesh, provider, field, q, sizes):
+    """One walk over both cross-shard providers: the chunk sizes are
+    what the thresholds and the cached counts say, the answers what
+    the reference walk gives (fragment.top on the CPU)."""
+    h = bounded_holder
+    cpu = Executor(h, device_policy="never")
+    dev = Executor(h, device_policy="always", mesh=mesh if provider == "mesh" else None)
+    try:
+        assert _normalize(dev.execute("b", q)) == _normalize(cpu.execute("b", q))
+        kind = "sparse_rows_stack" if provider == "mesh" else "sparse_stack"
+        staged = sorted(k[-2] if provider == "mesh" else k[2] for k in dev.stager._cache if kind in k)
+        assert staged == sizes
+    finally:
+        dev.close()
+
+
+@pytest.fixture(scope="module")
+def bounded_holder():
+    h = Holder()
+    h.open()
+    idx = h.create_index("b")
+    for name, hot in (("cliff", 200), ("pow2", 256)):
+        f = idx.create_field(name)
+        rows, cols = [], []
+        for shard in range(3):  # not a multiple of the mesh: one padded slot
+            base = shard * SHARD_WIDTH
+            for r in range(hot):  # hot rows share 40 columns, less a few
+                k = 40 - (r + shard) % 7
+                rows += [r] * k
+                cols += (base + np.arange(k)).tolist()
+            for r in range(4224 - hot):
+                rows.append(1000 + r)
+                cols.append(base + 100 + r)
+        f.import_bits(rows, cols)
+    yield h
+    h.close()
+
+
 def test_stack_is_mesh_sharded(spmd_exec, mesh):
     """Staged shard stacks carry a NamedSharding over the mesh axis."""
     spmd_exec.execute("i", "Count(Row(general=1))")

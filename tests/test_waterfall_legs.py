@@ -50,6 +50,51 @@ def test_leg_credits_each_second_once_to_the_innermost_leg():
     assert sum(wf.values()) == pytest.approx(outer.seconds)
 
 
+@pytest.mark.parametrize("stamp", ["sooner", "later"])
+def test_leg_ends_at_another_threads_stamp_where_that_is_sooner(stamp):
+    wf: dict = {}
+    with trace.attrib_activate(wf):
+        with trace.leg(trace.WF_GUARD_QUEUE) as lg:
+            mid = time.monotonic() + (0.0 if stamp == "sooner" else 1.0)
+            time.sleep(0.02)
+            lg.until = mid
+    if stamp == "sooner":
+        assert lg.seconds == mid - lg.t0 < 0.02
+    else:
+        assert 0.02 <= lg.seconds < 1.0
+    assert wf[trace.WF_GUARD_QUEUE] == pytest.approx(lg.seconds)
+
+
+def test_guard_queue_ends_when_the_worker_picks_the_call_up():
+    """Not when the caller wakes: the worker holds the interpreter's
+    lock by then and is inside the call's first legs, so the seconds
+    between were counted twice and a request's legs summed to more than
+    its wall (PR 32: in half the lone runs of
+    test_bench_mesh_cell.py::test_mesh_fetch_is_credited_once_on_the_guard_thread
+    once a TopN left 5 ms outside every leg, not 20)."""
+    health = DeviceHealth(timeout_s=30.0)
+    spins = []
+
+    def busy():  # holds the lock from its first statement: the caller wakes late
+        with trace.leg(trace.WF_TOPN_WALK):
+            t0 = time.monotonic()
+            while time.monotonic() - t0 < 0.03:
+                spins.append(1)
+
+    try:
+        health.guard(lambda: None)  # the worker exists and waits, as in a served request
+        wf: dict = {}
+        with trace.attrib_activate(wf):
+            t0 = time.monotonic()
+            health.guard(busy)
+            total = time.monotonic() - t0
+        assert wf[trace.WF_TOPN_WALK] >= 0.03
+        # it read the interpreter's switch interval, 5 ms, more
+        assert wf[trace.WF_GUARD_QUEUE] + wf[trace.WF_TOPN_WALK] <= total
+    finally:
+        health.close()
+
+
 def test_leg_without_attribution_is_a_timer_only():
     assert trace.attrib_current() is None
     with trace.leg(trace.WF_REDUCE) as lg:
