@@ -99,7 +99,9 @@ class BSIGroup:
             if value > self.min:
                 base = value - self.min
         elif op in ("<", "<="):
-            if value < self.min:
+            # nothing stored is under the minimum: the LT recurrence at
+            # predicate 0 would answer the columns equal to it
+            if value < self.min or (op == "<" and value == self.min):
                 return 0, True
             if value > self.max:
                 base = self.max - self.min

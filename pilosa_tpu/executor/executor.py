@@ -284,6 +284,35 @@ def _make_stacked_scorer() -> BatchedScorer:
     )
 
 
+# boolean PQL call -> (filter.launches op, the eager fold of two stacks)
+_STACK_FOLDS = {
+    "Intersect": ("and", ops.and_),
+    "Union": ("or", ops.or_),
+    "Xor": ("xor", ops.xor_),
+    "Difference": ("andnot", ops.andnot),
+}
+
+
+def _range_kernel(op: str, depth: int):
+    """The one-shard BSI compare for a Range operator: planes
+    u32[D+1, W] and the predicate(s) in base-value form -> u32[W]."""
+    if op == BETWEEN:
+        return lambda p, lo, hi: ops.bsi_range_between(p, lo, hi, bit_depth=depth)
+    if op == "==":
+        return lambda p, pred: ops.bsi_range_eq(p, pred, bit_depth=depth)
+    if op == NEQ:
+        return lambda p, pred: ops.bsi_range_neq(p, pred, bit_depth=depth)
+    if op in ("<", "<="):
+        return lambda p, pred: ops.bsi_range_lt(
+            p, pred, bit_depth=depth, allow_equality=op == "<="
+        )
+    if op in (">", ">="):
+        return lambda p, pred: ops.bsi_range_gt(
+            p, pred, bit_depth=depth, allow_equality=op == ">="
+        )
+    raise ValueError(f"invalid range operation: {op}")
+
+
 def _timed_kernel(kind: str, fn, signature=None, recovery=None):
     """Wrap a cached jitted kernel with the compile-vs-execute timing
     split: the FIRST invocation traces + compiles inside XLA (observed
@@ -469,6 +498,8 @@ class Executor:
         self.plan_cache = plan_cache
         # fused count-of-tree programs keyed by query structure
         self._tree_jits: dict[str, Any] = {}
+        # shard-batched BSI compares keyed by (operator, bit depth)
+        self._range_jits: dict[tuple, Any] = {}
         # auto-policy crossover, in estimated touched containers (see
         # _touched_containers). The default is not measured on the
         # current machine; executor/autotune.py measures the crossover
@@ -1568,7 +1599,13 @@ class Executor:
         return fn
 
     def _device_bitmap_stack(self, index, c: Call, shards):
-        """Lower a bitmap call subtree to u32[S, W] across shards."""
+        """Lower a bitmap call subtree to u32[S, W] across shards. The
+        host's time in it is the request's ``filter.eval``; a staged
+        row's probe inside stays ``stager.lookup``, a miss ``stager``."""
+        with trace.leg(trace.WF_FILTER_EVAL):
+            return self._bitmap_stack(index, c, shards)
+
+    def _bitmap_stack(self, index, c: Call, shards):
         name = c.name
         if name == "__cached":
             # device-resident plan cache: serve the packed stack from
@@ -1616,25 +1653,47 @@ class Executor:
                 if name in ("Intersect", "Difference"):
                     raise ValueError(f"empty {name} query is currently not supported")
                 return np.zeros((len(shards), _W32), dtype=np.uint32)
-            acc = self._device_bitmap_stack(index, c.children[0], shards)
+            op, fold = _STACK_FOLDS[name]
+            acc = self._bitmap_stack(index, c.children[0], shards)
             for child in c.children[1:]:
-                w = self._device_bitmap_stack(index, child, shards)
-                if name == "Intersect":
-                    acc = ops.and_(acc, w)
-                elif name == "Union":
-                    acc = ops.or_(acc, w)
-                elif name == "Xor":
-                    acc = ops.xor_(acc, w)
-                else:
-                    acc = ops.andnot(acc, w)
+                # eager: one launch a child, the host between them
+                acc = fold(acc, self._bitmap_stack(index, child, shards))
+                metrics.count(metrics.FILTER_LAUNCHES, op=op)
             return acc
         if name == "Range":
             return self._device_range_stack(index, c, shards)
         raise _NotDeviceable(name)
 
-    def _device_range_stack(self, index, c: Call, shards):
-        import jax
+    def _range_launch(self, op: str, depth: int, planes, *preds):
+        """One shard-batched BSI compare over ``planes`` u32[S, D+1, W].
+        The vmapped kernel is jitted once per (operator, depth) and
+        kept, as ``_tree_jits`` keeps tree programs; predicates are
+        traced, so one program serves every constant. Not fenced: the
+        leaf's consumer waits, and leaves dispatch one behind another."""
+        key = (op, depth)
+        fn = self._range_jits.get(key)
+        first = fn is None
+        if first:
+            import jax
 
+            fn = self._range_jits[key] = jax.jit(
+                jax.vmap(_range_kernel(op, depth), in_axes=(0,) + (None,) * len(preds))
+            )
+        profiler.count_operands("bsi_range", (planes,))
+        metrics.count(metrics.FILTER_LAUNCHES, op="range")
+        t0 = time.monotonic()
+        out = self._oom.run(lambda: fn(planes, *preds), kind="bsi_range")
+        if first:
+            profiler.COMPILES.note("bsi_range", key, time.monotonic() - t0)
+        return out
+
+    def _exists_stack(self, planes):
+        """The existence plane of every shard, where the field's bounds
+        decide a Range: a copy, launched like a compare."""
+        metrics.count(metrics.FILTER_LAUNCHES, op="range")
+        return planes[:, -1, :]
+
+    def _device_range_stack(self, index, c: Call, shards):
         zeros = np.zeros((len(shards), _W32), dtype=np.uint32)
         if not c.has_condition_arg():
             field_name = c.field_arg()
@@ -1659,7 +1718,11 @@ class Executor:
                 if not any(frags):
                     continue
                 w = self.stager.row_stack(frags, row_id)
-                acc = w if acc is None else ops.or_(acc, w)
+                if acc is None:
+                    acc = w
+                else:
+                    acc = ops.or_(acc, w)
+                    metrics.count(metrics.FILTER_LAUNCHES, op="or")
             return acc if acc is not None else zeros
 
         ((field_name, cond),) = c.args.items()
@@ -1681,19 +1744,17 @@ class Executor:
         planes = self.stager.planes_stack(frags, depth)
 
         if cond.op == NEQ and cond.value is None:
-            return planes[:, -1, :]
+            return self._exists_stack(planes)
         if cond.op == BETWEEN:
             predicates = cond.int_slice_value()
             base_min, base_max, out_of_range = bsig.base_value_between(*predicates)
             if out_of_range:
                 return zeros
             if predicates[0] <= bsig.min and predicates[1] >= bsig.max:
-                return planes[:, -1, :]
-            return jax.vmap(
-                lambda p: ops.bsi_range_between(
-                    p, np.uint32(base_min), np.uint32(base_max), bit_depth=depth
-                )
-            )(planes)
+                return self._exists_stack(planes)
+            return self._range_launch(
+                BETWEEN, depth, planes, np.uint32(base_min), np.uint32(base_max)
+            )
         value = cond.value
         if not isinstance(value, int) or isinstance(value, bool):
             raise ValueError("Range(): conditions only support integer values")
@@ -1706,23 +1767,10 @@ class Executor:
             or (cond.op == ">" and value < bsig.min)
             or (cond.op == ">=" and value <= bsig.min)
         ):
-            return planes[:, -1, :]
+            return self._exists_stack(planes)
         if out_of_range and cond.op == NEQ:
-            return planes[:, -1, :]
-        pred = np.uint32(base_value)
-        if cond.op == "==":
-            kern = lambda p: ops.bsi_range_eq(p, pred, bit_depth=depth)
-        elif cond.op == "!=":
-            kern = lambda p: ops.bsi_range_neq(p, pred, bit_depth=depth)
-        elif cond.op in ("<", "<="):
-            kern = lambda p: ops.bsi_range_lt(
-                p, pred, bit_depth=depth, allow_equality=cond.op == "<="
-            )
-        else:
-            kern = lambda p: ops.bsi_range_gt(
-                p, pred, bit_depth=depth, allow_equality=cond.op == ">="
-            )
-        return jax.vmap(kern)(planes)
+            return self._exists_stack(planes)
+        return self._range_launch(cond.op, depth, planes, np.uint32(base_value))
 
     # -- Count ---------------------------------------------------------------
 

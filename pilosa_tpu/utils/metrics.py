@@ -124,6 +124,8 @@ TOPN_PREFETCH_STARTS = "topn.prefetch_starts"
 TOPN_PASS2_IDS = "topn.pass2_ids"
 # candidate chunks a cross-shard TopN scored, by what set their size
 TOPN_CHUNKS = "topn.chunks"
+# device launches made for a filter before the program that consumes it
+FILTER_LAUNCHES = "filter.launches"
 # TopN rank/LRU caches
 CACHE_HITS = "cache.hits"
 CACHE_MISSES = "cache.misses"
@@ -448,6 +450,13 @@ METRICS: dict[str, tuple[str, str]] = {
         "shard; bounded, a later chunk that the walk's fixed thresholds "
         "and the cached counts ended short of the ladder's size, the "
         "walk's last; ladder, a later chunk of the ladder's size)",
+    ),
+    FILTER_LAUNCHES: (
+        "counter",
+        "device launches made for a call's filter, shard-batched, before "
+        "the program that consumes it (label: op = range, a BSI compare "
+        "or the copy of the existence plane that stands for one; and, "
+        "or, xor, andnot, an eager boolean op between two stacks)",
     ),
     CACHE_HITS: ("counter", "TopN rank/LRU cache hits"),
     CACHE_MISSES: ("counter", "TopN rank/LRU cache misses"),
@@ -932,7 +941,9 @@ METRICS: dict[str, tuple[str, str]] = {
     KERNEL_OPERAND_BYTES: (
         "counter",
         "bytes of the device operands handed to kernel launches, padding "
-        "included: attempted bytes, against the bytes a query needs (label: kind)",
+        "included: attempted bytes, against the bytes a query needs (label: "
+        "kind; bsi_range is a shard-batched Range leaf's plane stack, counted "
+        "at its launch, which is not fenced)",
     ),
     PROFILER_COMPILES: (
         "counter",

@@ -529,6 +529,7 @@ WF_DISPATCH_QUEUE = "dispatch.queue"
 WF_WAVE_MATES = "dispatch.wave_mates"
 WF_GUARD_QUEUE = "guard.queue"
 WF_TOPN_CANDIDATES = "topn.candidates"
+WF_FILTER_EVAL = "filter.eval"
 WF_DEVICE_COMPUTE = "device.compute"
 WF_TRANSFER_DECODE = "transfer.decode"
 WF_MESH_FETCH = "mesh.fetch"
@@ -548,6 +549,7 @@ WATERFALL_STAGES: tuple = (
     WF_WAVE_MATES,
     WF_GUARD_QUEUE,
     WF_TOPN_CANDIDATES,
+    WF_FILTER_EVAL,
     WF_DEVICE_COMPUTE,
     WF_TRANSFER_DECODE,
     WF_MESH_FETCH,
@@ -567,6 +569,7 @@ WATERFALL: dict = {
     WF_WAVE_MATES: "combined wave: the wave-mates' share of its measured legs, waited through",
     WF_GUARD_QUEUE: "device-guard pool: wait for a worker to pick the call up",
     WF_TOPN_CANDIDATES: "TopN ranked-cache snapshot and candidate chunk assembly",
+    WF_FILTER_EVAL: "a call's filter lowered to one shard stack: Range launches and eager boolean ops, on the host",
     WF_DEVICE_COMPUTE: "host's wait on the device (launch → result ready)",
     WF_TRANSFER_DECODE: "device→host copy and result decode",
     WF_MESH_FETCH: "mesh: copy of the gathered TopN scores from one replica",

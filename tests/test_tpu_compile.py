@@ -119,15 +119,26 @@ def _bsi_sum(s):
 
 
 def _bsi_range(s):
-    fn = jax.jit(
-        jax.vmap(
-            lambda p, pred: ops.bsi_range_lt(
-                p, pred, bit_depth=DEPTH, allow_equality=False
-            ),
-            in_axes=(0, None),
-        )
-    )
+    # the Range leaf as the executor launches it: the kept jit of the
+    # vmapped kernel, the predicate traced
+    fn = jax.jit(jax.vmap(executor_mod._range_kernel("<", DEPTH), in_axes=(0, None)))
     return fn.lower(s((S, DEPTH + 1, W)), s((), jnp.uint32))
+
+
+SSB_S = 58  # shards of benchmark/configs/ssb10.json
+
+
+def _ssb_range_between(s):
+    # ssb10.flight1: Range(lo_quantity >< [26, 35]), 6 value planes + existence
+    fn = jax.jit(jax.vmap(executor_mod._range_kernel("><", 6), in_axes=(0, None, None)))
+    return fn.lower(s((SSB_S, 7, W)), s((), jnp.uint32), s((), jnp.uint32))
+
+
+def _ssb_sum(s):
+    # ssb10.flight1: Sum(..., field=lo_revenue_computed), 27 value planes + existence
+    return ops.bsi_plane_counts_batched.lower(
+        s((SSB_S, 28, W)), s((SSB_S, W)), bit_depth=27, has_filter=True
+    )
 
 
 def _groupby_plane_counts(s):
@@ -163,6 +174,8 @@ def _fused_query(s):
         _expand_blocks,
         _bsi_sum,
         _bsi_range,
+        _ssb_range_between,
+        _ssb_sum,
         _groupby_plane_counts,
         _fused_query,
     ],
