@@ -22,6 +22,7 @@ from __future__ import annotations
 import bisect
 import threading
 import time
+from collections import Counter
 from dataclasses import dataclass
 from datetime import datetime
 from typing import Any, Optional
@@ -247,25 +248,53 @@ class _ScoreCarry:
 
 
 def _eval_tree(t, leaves):
-    """Evaluate a lowered boolean call tree over leaf word arrays.
-    Traced inside jit: the whole chain becomes one XLA fusion. Works
-    unbatched (leaves u32[S, W]) and batched (u32[Q, S, W]) — the
-    boolean ops are elementwise (reference executor.go:704-1000)."""
+    """Evaluate a lowered filter tree (``Executor._tree_leaves``) over
+    its inputs. Traced inside the jit of the program that consumes the
+    filter: boolean nodes, BSI compares and that program are one launch
+    (reference executor.go:704-1000). ``leaves`` are shard stacks
+    u32[S, W] and, under a ``range`` or ``exists`` node, a field's plane
+    stack u32[S, D+1, W]; a tree with ``range`` nodes takes their
+    predicates, in base-value form, as one u32 vector after its leaves
+    (``leaves[-1]``), traced, so one program serves every constant."""
     tag = t[0]
     if tag == "leaf":
         return leaves[t[1]]
+    if tag == "range":
+        import jax
+
+        _, op, depth, at, slots = t
+        compare = jax.vmap(_range_kernel(op, depth), in_axes=(0,) + (None,) * len(slots))
+        return compare(leaves[at], *(leaves[-1][k] for k in slots))
+    if tag == "exists":
+        return leaves[t[1]][:, -1, :]
+    if tag == "zeros":
+        import jax.numpy as jnp
+
+        return jnp.zeros((t[1], _W32), dtype=jnp.uint32)
+    fold = _STACK_FOLDS[tag][1]
     acc = _eval_tree(t[1][0], leaves)
     for sub in t[1][1:]:
-        v = _eval_tree(sub, leaves)
-        if tag == "Intersect":
-            acc = ops.and_(acc, v)
-        elif tag == "Union":
-            acc = ops.or_(acc, v)
-        elif tag == "Xor":
-            acc = ops.xor_(acc, v)
-        else:
-            acc = ops.andnot(acc, v)
+        acc = fold(acc, _eval_tree(sub, leaves))
     return acc
+
+
+def _eval_filter(tree, inputs, planes):
+    """The optional filter of a BSI aggregate over ``planes``, inside
+    its program: (filter words, has_filter) as the ``ops.bsi_*`` take
+    them. Without a filter the words are the existence plane, which the
+    kernels do not read."""
+    if tree is None:
+        return planes[:, -1, :], False
+    return _eval_tree(tree, inputs), True
+
+
+def _trace_bsi_sum(depth: int, tree, planes, inputs):
+    """Plane counts of a Sum under its filter's structure: the body of
+    the lone program (``Executor._bsi_sum_jit``) and of a fused unit."""
+    filt, has_filter = _eval_filter(tree, inputs, planes)
+    return ops.bsi_plane_counts_batched(
+        planes, filt, bit_depth=depth, has_filter=has_filter
+    )
 
 
 def _make_stacked_scorer() -> BatchedScorer:
@@ -284,7 +313,8 @@ def _make_stacked_scorer() -> BatchedScorer:
     )
 
 
-# boolean PQL call -> (filter.launches op, the eager fold of two stacks)
+# boolean PQL call -> (the op label of filter.launches and filter.inlined,
+# the fold of two stacks)
 _STACK_FOLDS = {
     "Intersect": ("and", ops.and_),
     "Union": ("or", ops.or_),
@@ -497,7 +527,7 @@ class Executor:
         # see its fragments' generations.
         self.plan_cache = plan_cache
         # fused count-of-tree programs keyed by query structure
-        self._tree_jits: dict[str, Any] = {}
+        self._tree_jits: dict[tuple, Any] = {}
         # shard-batched BSI compares keyed by (operator, bit depth)
         self._range_jits: dict[tuple, Any] = {}
         # auto-policy crossover, in estimated touched containers (see
@@ -1561,47 +1591,109 @@ class Executor:
         return total >= self.auto_min_containers
 
     def _tree_leaves(self, index, c: Call, batch):
-        """Lower a bitmap call tree to (leaf device arrays, structure):
-        boolean nodes become structure tuples, anything else (Row /
-        Range / time-range) stages or evaluates to a leaf array."""
-        leaves: list = []
+        """Lower a filter to (inputs, structure) for a consumer that is
+        itself one jitted program on one device (``_eval_tree`` traces
+        the structure inside it). Boolean calls and BSI Ranges become
+        structure: a Range is its field's staged plane stack, a leaf,
+        and its predicates in base-value form, slots of one u32 vector
+        that follows the leaves; where the field's bounds decide it, the
+        existence plane of that stack or all-zero. A Row, a time-quantum
+        Range and a ``__cached`` node stage or evaluate to a leaf array.
+        Every node folded into the consumer's program counts to
+        ``filter.inlined{op}`` as its eager launches would have counted
+        to ``filter.launches{op}``."""
+        inputs: list = []
+        preds: list[int] = []
+        inlined: Counter = Counter()
+
+        def leaf(arr) -> int:
+            for k, held in enumerate(inputs):
+                if held is arr:  # two Ranges of one field read one stack
+                    return k
+            inputs.append(arr)
+            return len(inputs) - 1
 
         def build(call: Call):
-            if call.name in ("Intersect", "Union", "Xor", "Difference") and call.children:
+            if call.name in _STACK_FOLDS and call.children:
+                inlined[_STACK_FOLDS[call.name][0]] += len(call.children) - 1
                 return (call.name, tuple(build(ch) for ch in call.children))
-            arr = self._device_bitmap_stack(index, call, batch)
-            leaves.append(arr)
-            return ("leaf", len(leaves) - 1)
+            if call.name == "Range" and call.has_condition_arg():
+                plan = self._range_plan(index, call, batch)
+                if plan[0] == "zeros":
+                    return ("zeros", len(batch))
+                inlined["range"] += 1
+                if plan[0] == "exists":
+                    return ("exists", leaf(plan[1]))
+                _, op, depth, planes, values = plan
+                slots = tuple(range(len(preds), len(preds) + len(values)))
+                preds.extend(values)
+                return ("range", op, depth, leaf(planes), slots)
+            return ("leaf", leaf(self._device_bitmap_stack(index, call, batch)))
 
-        return leaves, build(c)
+        with trace.leg(trace.WF_FILTER_EVAL):
+            tree = build(c)
+            if preds:
+                inputs.append(np.asarray(preds, dtype=np.uint32))
+        for op, n in inlined.items():
+            if n:
+                metrics.count(metrics.FILTER_INLINED, value=n, op=op)
+        return inputs, tree
 
-    def _tree_count_jit(self, tree):
-        """Jitted popcount-of-tree, cached per tree structure (bounded
-        by distinct query shapes, like the reference's parsed-query
-        cache would be). Returns i32[1]."""
+    def _filter_tree(self, index, c: Call, batch):
+        """``_tree_leaves`` of a BSI aggregate's optional filter child:
+        ([], None) without one."""
+        if len(c.children) == 1:
+            return self._tree_leaves(index, c.children[0], batch)
+        return [], None
+
+    def _tree_jit(self, kind: str, key: tuple, body):
+        """A jitted program of a filter's structure with one small
+        result, kept under ``key`` (bounded by distinct query shapes,
+        like the reference's parsed-query cache would be)."""
         import jax
 
-        key = repr(tree)
         fn = self._tree_jits.get(key)
         if fn is None:
+            program = jax.jit(jax.named_scope(kind)(body))
 
-            @jax.named_scope("tree_count")
-            def run(*ls):
-                return ops.count_bits(_eval_tree(tree, ls))[None]
+            def launch(*args):
+                # the caller reads the few words of the result at once:
+                # with the copy started behind the launch, the fence's
+                # wait covers it, one round trip to the device, not two
+                out = program(*args)
+                out.copy_to_host_async()
+                return out
 
-            fn = _timed_kernel(
-                "tree_count",
-                jax.jit(run),
-                signature=key,
-                recovery=self._oom,
+            fn = self._tree_jits[key] = _timed_kernel(
+                kind, launch, signature=key, recovery=self._oom
             )
-            self._tree_jits[key] = fn
         return fn
 
+    def _tree_count_jit(self, tree):
+        """Jitted popcount-of-tree: ``fn(*inputs)`` -> i32[1]."""
+        return self._tree_jit(
+            "tree_count",
+            ("tree_count", tree),
+            lambda *ls: ops.count_bits(_eval_tree(tree, ls))[None],
+        )
+
+    def _bsi_sum_jit(self, depth: int, tree):
+        """Jitted plane counts of a lone Sum with its filter traced
+        inside: ``fn(planes, *inputs)`` -> i32[depth + 1]."""
+        return self._tree_jit(
+            "bsi_sum",
+            ("bsi_sum", depth, tree),
+            lambda planes, *ls: _trace_bsi_sum(depth, tree, planes, ls),
+        )
+
     def _device_bitmap_stack(self, index, c: Call, shards):
-        """Lower a bitmap call subtree to u32[S, W] across shards. The
-        host's time in it is the request's ``filter.eval``; a staged
-        row's probe inside stays ``stager.lookup``, a miss ``stager``."""
+        """Lower a bitmap call subtree to one materialised u32[S, W]
+        across shards, for a consumer that reads it as an array (a
+        TopN's source, a mesh kernel, a leaf of ``_tree_leaves``):
+        boolean calls fold eagerly and a Range launches its compare,
+        each counted to ``filter.launches``. The host's time in it is
+        the request's ``filter.eval``; a staged row's probe inside
+        stays ``stager.lookup``, a miss ``stager``."""
         with trace.leg(trace.WF_FILTER_EVAL):
             return self._bitmap_stack(index, c, shards)
 
@@ -1725,6 +1817,21 @@ class Executor:
                     metrics.count(metrics.FILTER_LAUNCHES, op="or")
             return acc if acc is not None else zeros
 
+        plan = self._range_plan(index, c, shards)
+        if plan[0] == "zeros":
+            return zeros
+        if plan[0] == "exists":
+            return self._exists_stack(plan[1])
+        _, op, depth, planes, values = plan
+        return self._range_launch(op, depth, planes, *map(np.uint32, values))
+
+    def _range_plan(self, index, c: Call, shards) -> tuple:
+        """A BSI Range's condition decided on the host as far as the
+        field's bounds decide it: ``("zeros",)`` (out of range, or no
+        fragment), ``("exists", planes)`` (every column that has a
+        value), or ``("range", op, depth, planes, predicates)`` with
+        the predicates in base-value form; ``planes`` is the field's
+        staged stack u32[S, D+1, W]."""
         ((field_name, cond),) = c.args.items()
         f = self.holder.field(index, field_name)
         if f is None:
@@ -1740,37 +1847,34 @@ class Executor:
             for s in shards
         )
         if not any(frags):
-            return zeros
+            return ("zeros",)
         planes = self.stager.planes_stack(frags, depth)
 
         if cond.op == NEQ and cond.value is None:
-            return self._exists_stack(planes)
+            return ("exists", planes)
         if cond.op == BETWEEN:
             predicates = cond.int_slice_value()
             base_min, base_max, out_of_range = bsig.base_value_between(*predicates)
             if out_of_range:
-                return zeros
+                return ("zeros",)
             if predicates[0] <= bsig.min and predicates[1] >= bsig.max:
-                return self._exists_stack(planes)
-            return self._range_launch(
-                BETWEEN, depth, planes, np.uint32(base_min), np.uint32(base_max)
-            )
+                return ("exists", planes)
+            return ("range", BETWEEN, depth, planes, (base_min, base_max))
         value = cond.value
         if not isinstance(value, int) or isinstance(value, bool):
             raise ValueError("Range(): conditions only support integer values")
         base_value, out_of_range = bsig.base_value(cond.op, value)
         if out_of_range and cond.op != NEQ:
-            return zeros
+            return ("zeros",)
         if (
             (cond.op == "<" and value > bsig.max)
             or (cond.op == "<=" and value >= bsig.max)
             or (cond.op == ">" and value < bsig.min)
             or (cond.op == ">=" and value <= bsig.min)
+            or (out_of_range and cond.op == NEQ)
         ):
-            return self._exists_stack(planes)
-        if out_of_range and cond.op == NEQ:
-            return self._exists_stack(planes)
-        return self._range_launch(cond.op, depth, planes, np.uint32(base_value))
+            return ("exists", planes)
+        return ("range", cond.op, depth, planes, (base_value,))
 
     # -- Count ---------------------------------------------------------------
 
@@ -1921,26 +2025,24 @@ class Executor:
 
     def _sum_device_batched(self, index, c: Call, batch, bsig, frags) -> ValCount:
         depth = bsig.bit_depth()
-        if len(c.children) == 1:
-            filt = self._device_bitmap_stack(index, c.children[0], batch)
-            has_filter = True
-        else:
-            filt = np.zeros((len(batch), _W32), dtype=np.uint32)
-            has_filter = False
-        planes = self.stager.planes_stack(frags, depth)
         if self.mesh is not None:
+            # an SPMD kernel takes its filter as an array
+            if len(c.children) == 1:
+                filt = self._device_bitmap_stack(index, c.children[0], batch)
+                has_filter = True
+            else:
+                filt = np.zeros((len(batch), _W32), dtype=np.uint32)
+                has_filter = False
+            planes = self.stager.planes_stack(frags, depth)
             counts = _fetch(
                 self._spmd_kernel("plane_counts", depth, has_filter)(planes, filt)
             )
         else:
-            counts = _launch(
-                "bsi_sum",
-                ops.bsi_plane_counts_batched,
-                planes,
-                filt,
-                bit_depth=depth,
-                has_filter=has_filter,
-            )
+            # one program a filter structure: the filter's compares and
+            # folds are traced into the sum's launch, as Count's are
+            inputs, tree = self._filter_tree(index, c, batch)
+            planes = self.stager.planes_stack(frags, depth)
+            counts = _fetch(self._bsi_sum_jit(depth, tree)(planes, *inputs))
         vsum = sum(int(counts[i]) << i for i in range(depth))
         vcount = int(counts[depth])
         if vcount == 0:

@@ -344,11 +344,11 @@ class QueryFuser:
     def _lower_count(self, index, i, c, shards) -> Optional[_Unit]:
         if len(c.children) != 1:
             return None
-        leaves, tree = self.ex._tree_leaves(index, c.children[0], shards)
+        inputs, tree = self.ex._tree_leaves(index, c.children[0], shards)
         return _Unit(
             i,
-            ("count", tree, len(leaves)),
-            tuple(leaves),
+            ("count", tree, len(inputs)),
+            tuple(inputs),
             lambda res: int(np.asarray(res).reshape(-1)[0]),
         )
 
@@ -370,12 +370,7 @@ class QueryFuser:
         )
         if not any(frags):
             return None
-        if len(c.children) == 1:
-            filt = ex._device_bitmap_stack(index, c.children[0], shards)
-            has_filter = True
-        else:
-            filt = np.zeros((len(shards), _ex._W32), dtype=np.uint32)
-            has_filter = False
+        inputs, tree = ex._filter_tree(index, c, shards)
         planes = ex.stager.planes_stack(frags, depth)
 
         def finish(counts):
@@ -385,7 +380,9 @@ class QueryFuser:
                 return ValCount()
             return ValCount(vsum + vcount * bsig.min, vcount)
 
-        return _Unit(i, ("sum", depth, has_filter), (planes, filt), finish)
+        return _Unit(
+            i, ("sum", depth, tree, len(inputs)), (planes, *inputs), finish
+        )
 
     def _lower_groupby(self, index, i, c, shards) -> Optional[_Unit]:
         """Whole GroupBy panel as one segmented-reduction unit: every
@@ -413,13 +410,14 @@ class QueryFuser:
             rows = [ex.stager.row_stack(frags, rid) for rid in ids]
             inputs.append(jnp.stack(rows).reshape(len(ids), wf))
             k *= len(ids)
-        has_filter = plan.filter is not None
-        if has_filter:
-            inputs.append(
-                jnp.asarray(
-                    ex._device_bitmap_stack(index, plan.filter, shards)
-                ).reshape(wf)
-            )
+        # the filter's inputs follow the dimensions', its structure
+        # rides in the descriptor
+        finputs, tree = (
+            ex._tree_leaves(index, plan.filter, shards)
+            if plan.filter is not None
+            else ([], None)
+        )
+        inputs.extend(finputs)
         rcounts = tuple(len(ids) for _, ids in dims)
         extra = k * wf * 4  # the [K, S·W] cross-product transient
         if plan.agg_field is None:
@@ -432,7 +430,7 @@ class QueryFuser:
 
             return _Unit(
                 i,
-                ("groupby_count", rcounts, has_filter),
+                ("groupby_count", rcounts, tree, len(finputs)),
                 tuple(inputs),
                 finish,
                 extra_bytes=extra,
@@ -466,7 +464,7 @@ class QueryFuser:
 
         return _Unit(
             i,
-            ("groupby_sum", rcounts, has_filter, depth),
+            ("groupby_sum", rcounts, tree, len(finputs), depth),
             tuple(inputs),
             finish,
             extra_bytes=extra,
@@ -490,19 +488,16 @@ class QueryFuser:
         )
         if not any(frags):
             return _Unit(i, None, (), lambda _res: [])
-        if len(c.children) == 1:
-            filt = ex._device_bitmap_stack(index, c.children[0], shards)
-            has_filter = True
-        else:
-            filt = np.zeros((len(shards), _ex._W32), dtype=np.uint32)
-            has_filter = False
+        inputs, tree = ex._filter_tree(index, c, shards)
         planes = ex.stager.planes_stack(frags, depth)
 
         def finish(words):
             metrics.count(metrics.ANALYTICS_QUERIES, call="Distinct")
             return analytics.decode_presence_words(words, bsig.min)
 
-        return _Unit(i, ("distinct", depth, has_filter), (planes, filt), finish)
+        return _Unit(
+            i, ("distinct", depth, tree, len(inputs)), (planes, *inputs), finish
+        )
 
     def _lower_percentile(self, index, i, c, shards) -> Optional[_Unit]:
         ex = self.ex
@@ -518,15 +513,10 @@ class QueryFuser:
         )
         if not any(frags):
             return _Unit(i, None, (), lambda _res: ValCount())
-        if len(c.children) == 1:
-            filt = ex._device_bitmap_stack(index, c.children[0], shards)
-            has_filter = True
-        else:
-            filt = np.zeros((len(shards), _ex._W32), dtype=np.uint32)
-            has_filter = False
+        inputs, tree = ex._filter_tree(index, c, shards)
         planes = ex.stager.planes_stack(frags, depth)
         # nth rides as a TRACED i32 input so every percentile of the
-        # same (depth, filter) shape shares one compiled program
+        # same (depth, filter structure) shares one compiled program
         nth = np.asarray(nth_bp, dtype=np.int32)
 
         def finish(out):
@@ -538,7 +528,10 @@ class QueryFuser:
             return ValCount(val + bsig.min, count)
 
         return _Unit(
-            i, ("percentile", depth, has_filter), (planes, filt, nth), finish
+            i,
+            ("percentile", depth, tree, len(inputs)),
+            (planes, nth, *inputs),
+            finish,
         )
 
     def _lower_topn(self, index, i, c, shards, opt) -> Optional[_Unit]:
@@ -675,21 +668,18 @@ def _trace_unit(d: tuple, flat: tuple, off: int):
         leaves = flat[off : off + nleaves]
         return ops.count_bits(_ex._eval_tree(tree, leaves))[None], off + nleaves
     if kind == "sum":
-        depth, has_filter = d[1], d[2]
-        planes, filt = flat[off], flat[off + 1]
-        out = ops.bsi_plane_counts_batched(
-            planes, filt, bit_depth=depth, has_filter=has_filter
-        )
-        return out, off + 2
+        depth, tree, n = d[1], d[2], d[3]
+        end = off + 1 + n
+        return _ex._trace_bsi_sum(depth, tree, flat[off], flat[off + 1 : end]), end
     if kind in ("groupby_count", "groupby_sum"):
-        rcounts, has_filter = d[1], d[2]
+        rcounts, tree, n = d[1], d[2], d[3]
         nd = len(rcounts)
         dims = tuple(flat[off : off + nd])
         off += nd
         filt = None
-        if has_filter:
-            filt = flat[off]
-            off += 1
+        if tree is not None:
+            filt = _ex._eval_tree(tree, flat[off : off + n]).reshape(-1)
+        off += n
         if kind == "groupby_count":
             return ops.groupby_counts(dims, filt), off
         counts, pc = ops.groupby_sum_reduce(dims, filt, flat[off])
@@ -697,22 +687,26 @@ def _trace_unit(d: tuple, flat: tuple, off: int):
         # column 0, plane counts after
         return jnp.concatenate([counts[:, None], pc], axis=1), off + 1
     if kind == "distinct":
-        depth, has_filter = d[1], d[2]
-        planes, filt = flat[off], flat[off + 1]
+        depth, tree, n = d[1], d[2], d[3]
+        end = off + 1 + n
+        planes = flat[off]
+        filt, has_filter = _ex._eval_filter(tree, flat[off + 1 : end], planes)
         out = ops.bsi_distinct_presence(
             planes, filt, bit_depth=depth, has_filter=has_filter
         )
-        return out, off + 2
+        return out, end
     if kind == "percentile":
-        depth, has_filter = d[1], d[2]
-        planes, filt, nth = flat[off : off + 3]
+        depth, tree, n = d[1], d[2], d[3]
+        end = off + 2 + n
+        planes, nth = flat[off], flat[off + 1]
+        filt, has_filter = _ex._eval_filter(tree, flat[off + 2 : end], planes)
         bits, count = ops.bsi_percentile_batched(
             planes, filt, nth, bit_depth=depth, has_filter=has_filter
         )
         out = jnp.concatenate(
             [bits.astype(jnp.int32), count[None].astype(jnp.int32)]
         )
-        return out, off + 3
+        return out, end
     # topn head-chunk scoring
     num_rows, n_shards, chunk = d[1], d[2], d[3]
     srcs, blocks, brow, bslot, bshard = flat[off : off + 5]

@@ -126,6 +126,8 @@ TOPN_PASS2_IDS = "topn.pass2_ids"
 TOPN_CHUNKS = "topn.chunks"
 # device launches made for a filter before the program that consumes it
 FILTER_LAUNCHES = "filter.launches"
+# filter nodes traced into the program that consumes them, with no launch
+FILTER_INLINED = "filter.inlined"
 # TopN rank/LRU caches
 CACHE_HITS = "cache.hits"
 CACHE_MISSES = "cache.misses"
@@ -454,9 +456,21 @@ METRICS: dict[str, tuple[str, str]] = {
     FILTER_LAUNCHES: (
         "counter",
         "device launches made for a call's filter, shard-batched, before "
-        "the program that consumes it (label: op = range, a BSI compare "
-        "or the copy of the existence plane that stands for one; and, "
-        "or, xor, andnot, an eager boolean op between two stacks)",
+        "the program that consumes it, where the consumer reads the filter "
+        "as one array: a TopN's source, a mesh kernel, a per-call GroupBy, "
+        "Distinct or Percentile (label: op = range, a BSI compare or the "
+        "copy of the existence plane that stands for one; and, or, xor, "
+        "andnot, an eager boolean op between two stacks)",
+    ),
+    FILTER_INLINED: (
+        "counter",
+        "nodes of a call's filter traced into the one-device program that "
+        "consumes it (Count, Sum, and a fused Distinct, Percentile or "
+        "GroupBy), so that they cost no launch of their own, counted at "
+        "lowering as the launches they replace would be (label: op = "
+        "range, a BSI compare or the existence plane that stands for one; "
+        "and, or, xor, andnot, a boolean node once for each child after "
+        "its first)",
     ),
     CACHE_HITS: ("counter", "TopN rank/LRU cache hits"),
     CACHE_MISSES: ("counter", "TopN rank/LRU cache misses"),

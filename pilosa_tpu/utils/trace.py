@@ -569,7 +569,7 @@ WATERFALL: dict = {
     WF_WAVE_MATES: "combined wave: the wave-mates' share of its measured legs, waited through",
     WF_GUARD_QUEUE: "device-guard pool: wait for a worker to pick the call up",
     WF_TOPN_CANDIDATES: "TopN ranked-cache snapshot and candidate chunk assembly",
-    WF_FILTER_EVAL: "a call's filter lowered to one shard stack: Range launches and eager boolean ops, on the host",
+    WF_FILTER_EVAL: "a call's filter lowered on the host: to structure and staged leaves for a program that traces it, or to one shard stack by Range launches and eager boolean ops",
     WF_DEVICE_COMPUTE: "host's wait on the device (launch → result ready)",
     WF_TRANSFER_DECODE: "device→host copy and result decode",
     WF_MESH_FETCH: "mesh: copy of the gathered TopN scores from one replica",

@@ -19,6 +19,8 @@ from benchmark import datagen, roofline, run, traffic
 from benchmark.kinds import ssb_lineorder
 from pilosa_tpu.core import Holder
 from pilosa_tpu.executor import Executor
+from pilosa_tpu.executor import executor as executor_mod
+from pilosa_tpu.executor import fusion
 from pilosa_tpu.utils import metrics, profiler, trace
 
 ROOT = run.ROOT
@@ -233,51 +235,72 @@ def test_the_program_answers_as_the_reference(built, executor, case, form):
 # -- (e) what one request counts and books ------------------------------------
 
 
-def test_one_request_counts_two_range_launches_and_books_its_filter(built, monkeypatch):
+def test_one_request_is_one_launch_with_its_filter_inlined(built, monkeypatch):
+    """A flight-1 request launches one program: the compares and the
+    folds of its filter are traced into the sum's (ISSUE 34); the
+    constants are traced values, so other dates and bands compile
+    nothing."""
     _, h, _ = built
     ex = Executor(h, device_policy="always")
-    q = traffic.pql(PAPER["Q1.1"])
+    ops_ = ("range", "and", "or", "xor", "andnot")
     read = lambda: (  # noqa: E731
-        {op: _counter(metrics.FILTER_LAUNCHES, op=op) for op in ("range", "and", "or", "xor", "andnot")},
+        {op: _counter(metrics.FILTER_LAUNCHES, op=op) for op in ops_},
+        {op: _counter(metrics.FILTER_INLINED, op=op) for op in ops_},
+        _counter(metrics.KERNEL_OPERAND_BYTES, kind="bsi_sum") + _counter(metrics.KERNEL_OPERAND_BYTES, kind="fused_query"),
         _counter(metrics.KERNEL_OPERAND_BYTES, kind="bsi_range"),
-        _counter(metrics.PROFILER_COMPILES, kind="bsi_range"),
     )
+    grown = lambda now, was, k: {op: now[k][op] - was[k][op] for op in ops_ if now[k][op] != was[k][op]}  # noqa: E731
+    launched = []
+    timed = executor_mod._timed_kernel
+
+    def counting(kind, fn, **kw):
+        run = timed(kind, fn, **kw)
+        return lambda *a: (launched.append(kind), run(*a))[1]
+
+    monkeypatch.setattr(executor_mod, "_timed_kernel", counting)
+    monkeypatch.setattr(fusion, "_timed_kernel", counting)
     try:
-        before = read()
-        ex.execute(SMALL["index"], q)
-        first = read()
-        grown = {op: first[0][op] - before[0][op] for op in first[0]}
-        assert grown == {"range": 2, "and": 2, "or": 0, "xor": 0, "andnot": 0}
-        # lo_discount 0..10: 4 value planes + existence; lo_quantity 1..50: 6 + 1
-        assert first[1] - before[1] == (5 + 7) * SMALL["shards"] * DENSE
-        assert first[2] - before[2] == 2
-        assert sorted(ex._range_jits) == [("<", 6), ("><", 4)]
-        jits = dict(ex._range_jits)
+        # Q1.1: a year's row, two Ranges: 2 range + 2 and; Q1.3 has a second row: 2 + 3
+        for call, ands, rows in ((PAPER["Q1.1"], 2, 1), (PAPER["Q1.3"], 3, 2)):
+            before = read()
+            del launched[:]
+            ex.execute(SMALL["index"], traffic.pql(call))
+            first = read()
+            assert launched == ["bsi_sum"]
+            assert grown(first, before, 0) == {}
+            assert grown(first, before, 1) == {"range": 2, "and": ands}
+            # the leaves' planes are operands of the one program, counted there once:
+            # lo_revenue_computed 27 + 1, lo_discount 4 + 1, lo_quantity 6 + 1, the rows,
+            # and the predicates' vector (3 or 4 u32)
+            preds = 3 if call is PAPER["Q1.1"] else 4
+            assert first[2] - before[2] == (28 + 5 + 7 + rows) * SMALL["shards"] * DENSE + 4 * preds
+            assert first[3] == before[3]
+        assert len(ex._tree_jits) == 2 and not ex._range_jits
         signatures = {r["signature"] for r in profiler.COMPILES.snapshot(top=256)["signatures"]}
-        assert {f"bsi_range:{k!r}" for k in jits} <= signatures
+        assert {f"bsi_sum:{k!r}" for k in ex._tree_jits} <= signatures
 
-        # the same request again: no new kernel, no compile, the same counts;
-        # its filter's host time is filter.eval's, not other's
-        slow = type(ex)._range_launch
-
-        def slowed(self, *a):
-            time.sleep(0.1)
-            return slow(self, *a)
-
-        monkeypatch.setattr(type(ex), "_range_launch", slowed)
+        # other constants, lone and as the wave of two: nothing new is compiled or kept
+        ex.execute(SMALL["index"], traffic.pql(PAPER["Q1.1"]) + traffic.pql(PAPER["Q1.3"]))
+        kept = set(ex._tree_jits), set(ex.fuser._programs)
+        assert len(kept[1]) == 1
+        compiled = _counter(metrics.PROFILER_COMPILES, kind="xla")
+        before = read()
+        del launched[:]
+        other = _q11(5, discount=("><", 2, 9), quantity=("<", 40)), _q13(11, 3)
         wf: dict = {}
         with trace.attrib_activate(wf):
-            t0 = time.monotonic()
-            ex.execute(SMALL["index"], q)
-            total = time.monotonic() - t0
-        second = read()
-        assert ex._range_jits == jits and second[2] == first[2]
-        assert second[0]["range"] - first[0]["range"] == 2 and second[1] - first[1] == first[1] - before[1]
-        assert wf[trace.WF_FILTER_EVAL] >= 0.2
-        assert wf.get(trace.WF_STAGER_LOOKUP, 0.0) > 0.0  # the staged rows' probes keep their own leg
-        summary = profiler.WATERFALL.summarize(wf, total)
-        assert summary["stages"][trace.WF_FILTER_EVAL] >= 200.0
-        assert summary["stages"].get(trace.WF_OTHER, 0.0) < 100.0, summary
+            answers = _answers(ex, [other[0]]) + _answers(ex, list(other))
+        assert launched == ["bsi_sum", "fused_query"]
+        assert (set(ex._tree_jits), set(ex.fuser._programs)) == kept
+        assert _counter(metrics.PROFILER_COMPILES, kind="xla") == compiled
+        after = read()
+        assert grown(after, before, 0) == {} and grown(after, before, 1) == {"range": 6, "and": 7}
+        ref = built[0]
+        assert answers == [ref.answer(other[0]), ref.answer(other[0]), ref.answer(other[1])]
+        assert answers[0] != ref.answer(PAPER["Q1.1"])
+        # the lowering's host time is filter.eval's, the staged leaves' probes keep their own leg
+        assert wf[trace.WF_FILTER_EVAL] > 0.0 and wf.get(trace.WF_STAGER_LOOKUP, 0.0) > 0.0
+        assert wf.get(trace.WF_DEVICE_COMPUTE, 0.0) > 0.0
         assert trace.WF_FILTER_EVAL not in profiler.WaterfallAggregator.DEVICE_STAGES
     finally:
         ex.close()
@@ -326,8 +349,14 @@ def test_the_manifest_names_the_cell_with_one_chip_and_its_four_metrics():
         "executor.range_launches_per_query": ("filter.launches", {"op": "range"}, "query_p50_ms"),
         "kernels.bsi_range_operand_mb_per_query": ("kernel.operand_bytes", {"kind": "bsi_range"}, "queries_per_s"),
     }
-    assert [m["name"] for m in manifest["per_layer"][-4:]] == list(new)  # appended, in the issue's order
-    for m in manifest["per_layer"][-4:]:
+    new["executor.filter_inlined_per_query"] = ("filter.inlined", {}, "query_p50_ms")  # ISSUE 34's
+    assert [m["name"] for m in manifest["per_layer"][-5:]] == list(new)  # appended, in the issues' order
+    assert manifest["per_layer"][-1] == {
+        "name": "executor.filter_inlined_per_query", "unit": "count/query", "better": "higher",
+        "source": "program_counter", "layer": "executor host side", "moves": "query_p50_ms",
+        "workloads": [CELL, "taxi96.dashboard"],
+    }
+    for m in manifest["per_layer"][-5:]:
         metric, labels, moves = new[m["name"]]
         assert m["workloads"] == [CELL, "taxi96.dashboard"] and m["moves"] == moves
         spec = run.layer_metrics.load(m["name"])
@@ -383,8 +412,10 @@ def test_the_cell_runs_correct_against_a_server_child(monkeypatch, tmp_path):
     assert out["correct"] and out["failed"] == 0
     assert out["attempted"] == out["checks"]["compared"]["value"] > 0  # every answer compared
     assert phases["warm_up"]["distinct_requests"] == 462 and phases["warm_up"]["clients"] == 2
-    # three Range kernels in all: >< at depth 4 and 6, < at depth 6
-    assert phases["warm_up"]["compiles_by_kind"].get("bsi_range") == 3
+    # no compare is launched on its own; a program a filter structure:
+    # three lone sums and the nine ordered pairs of a wave of two
+    by_kind = phases["warm_up"]["compiles_by_kind"]
+    assert "bsi_range" not in by_kind and (by_kind["bsi_sum"], by_kind["fused_query"]) == (3, 9)
     assert phases["warm_up"]["rounds_compiled"][-2:] == [(0, 0), (0, 0)]
     w = phases["window"]
     assert w["server_exit_code"] == 0 and w["fallbacks_in_window"] == {}

@@ -134,11 +134,32 @@ def _ssb_range_between(s):
     return fn.lower(s((SSB_S, 7, W)), s((), jnp.uint32), s((), jnp.uint32))
 
 
+# ssb10.flight1's filters as Executor._tree_leaves lowers them: the
+# Ranges are structure, their plane stacks leaves, their predicates one
+# traced vector after the leaves
+_SSB_Q11 = ("Intersect", (("leaf", 0), ("range", "><", 4, 1, (0, 1)), ("range", "<", 6, 2, (2,))))
+_SSB_Q13 = (
+    "Intersect",
+    (("leaf", 0), ("leaf", 1), ("range", "><", 4, 2, (0, 1)), ("range", "><", 6, 3, (2, 3))),
+)
+
+
+def _ssb_inputs(s, rows, predicates):
+    # Sum(..., field=lo_revenue_computed): 27 value planes + existence,
+    # then the filter's rows, lo_discount's 4 + 1 planes, lo_quantity's 6 + 1
+    return [s((SSB_S, 28, W))] + [s((SSB_S, W))] * rows + [s((SSB_S, 5, W)), s((SSB_S, 7, W)), s((predicates,))]
+
+
 def _ssb_sum(s):
-    # ssb10.flight1: Sum(..., field=lo_revenue_computed), 27 value planes + existence
-    return ops.bsi_plane_counts_batched.lower(
-        s((SSB_S, 28, W)), s((SSB_S, W)), bit_depth=27, has_filter=True
-    )
+    # a lone Q1.1: the compares and the folds inside the sum's program
+    fn = jax.jit(lambda planes, *ls: executor_mod._trace_bsi_sum(27, _SSB_Q11, planes, ls))
+    return fn.lower(*_ssb_inputs(s, 1, 3))
+
+
+def _ssb_wave_of_two(s):
+    # Q1.1 and Q1.3 met in one dispatch wave: one fused program
+    descs = (("sum", 27, _SSB_Q11, 4), ("sum", 27, _SSB_Q13, 5))
+    return jax.jit(fusion._build_program(descs)).lower(*_ssb_inputs(s, 1, 3), *_ssb_inputs(s, 2, 4))
 
 
 def _groupby_plane_counts(s):
@@ -154,7 +175,7 @@ def _fused_query(s):
         ("count", ("leaf", 0), 1),
         ("count", _CHAIN, 5),
         ("topn", S * 128, S, 128),
-        ("sum", DEPTH, True),
+        ("sum", DEPTH, ("leaf", 0), 1),
     )
     flat = (
         [s((S, W))] * 6
@@ -176,6 +197,7 @@ def _fused_query(s):
         _bsi_range,
         _ssb_range_between,
         _ssb_sum,
+        _ssb_wave_of_two,
         _groupby_plane_counts,
         _fused_query,
     ],
