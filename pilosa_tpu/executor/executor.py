@@ -446,6 +446,16 @@ def _fetch(arr) -> np.ndarray:
         return np.asarray(arr)
 
 
+def _mesh_fetch(arr) -> np.ndarray:
+    """The copy of a mesh kernel's replicated result (a chunk's gathered
+    scores, a Sum's or a Count's reduced counts) from one replica, once
+    ``_timed_kernel`` has fenced the program. What the mesh adds on the
+    host has a leg of its own; it takes ``transfer.decode``'s place on
+    a mesh and is 0 without one."""
+    with trace.leg(trace.WF_MESH_FETCH):
+        return np.asarray(arr)
+
+
 class Executor:
     def __init__(
         self,
@@ -1916,7 +1926,7 @@ class Executor:
         batch = self._shard_plan(shards)
         if self.mesh is not None:
             words = self._device_bitmap_stack(index, child, batch)
-            return int(self._spmd_kernel("count")(words))
+            return int(_mesh_fetch(self._spmd_kernel("count")(words)))
         # One fused program per query-tree structure: boolean
         # internal nodes trace into a single jit so the whole
         # chain is one XLA fusion + one dispatch, instead of an
@@ -2034,7 +2044,7 @@ class Executor:
                 filt = np.zeros((len(batch), _W32), dtype=np.uint32)
                 has_filter = False
             planes = self.stager.planes_stack(frags, depth)
-            counts = _fetch(
+            counts = _mesh_fetch(
                 self._spmd_kernel("plane_counts", depth, has_filter)(planes, filt)
             )
         else:
@@ -3246,10 +3256,8 @@ class _SpmdLazyScores(_ChunkedLazyScores):
         # the kernel's [S, k] is the chunk's shape already (frags is the
         # mesh-padded plan, k the chunk size), gathered to every device:
         # no trim, an eager launch over the whole mesh, and the copy
-        # reads one replica. What the mesh adds on the host once the
-        # kernel is fenced has a leg of its own.
-        with trace.leg(trace.WF_MESH_FETCH):
-            return np.asarray(dev)
+        # reads one replica
+        return _mesh_fetch(dev)
 
 
 class _LazyScores:

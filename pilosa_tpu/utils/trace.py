@@ -572,7 +572,7 @@ WATERFALL: dict = {
     WF_FILTER_EVAL: "a call's filter lowered on the host: to structure and staged leaves for a program that traces it, or to one shard stack by Range launches and eager boolean ops",
     WF_DEVICE_COMPUTE: "host's wait on the device (launch → result ready)",
     WF_TRANSFER_DECODE: "device→host copy and result decode",
-    WF_MESH_FETCH: "mesh: copy of the gathered TopN scores from one replica",
+    WF_MESH_FETCH: "mesh: copy of a mesh kernel's replicated result (a TopN chunk's gathered scores, a Sum's or Count's reduced counts) from one replica",
     WF_TOPN_WALK: "TopN ranked walk, cross-shard merge, sort, pass-2 trim",
     WF_REDUCE: "host-side shard-result reduction",
     WF_RESPOND: "results → JSON bytes → last write",

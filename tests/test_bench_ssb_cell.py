@@ -350,15 +350,18 @@ def test_the_manifest_names_the_cell_with_one_chip_and_its_four_metrics():
         "kernels.bsi_range_operand_mb_per_query": ("kernel.operand_bytes", {"kind": "bsi_range"}, "queries_per_s"),
     }
     new["executor.filter_inlined_per_query"] = ("filter.inlined", {}, "query_p50_ms")  # ISSUE 34's
-    assert [m["name"] for m in manifest["per_layer"][-5:]] == list(new)  # appended, in the issues' order
-    assert manifest["per_layer"][-1] == {
+    # appended in the issues' order; ISSUE 35's mesh metric came after them
+    # and its cell was appended to their lists (tests/test_bench_ssb_mesh_cell.py)
+    assert [m["name"] for m in manifest["per_layer"][-6:-1]] == list(new)
+    lists = [CELL, "taxi96.dashboard", "ssb20x4.flight1"]
+    assert manifest["per_layer"][-2] == {
         "name": "executor.filter_inlined_per_query", "unit": "count/query", "better": "higher",
         "source": "program_counter", "layer": "executor host side", "moves": "query_p50_ms",
-        "workloads": [CELL, "taxi96.dashboard"],
+        "workloads": lists,
     }
-    for m in manifest["per_layer"][-5:]:
+    for m in manifest["per_layer"][-6:-1]:
         metric, labels, moves = new[m["name"]]
-        assert m["workloads"] == [CELL, "taxi96.dashboard"] and m["moves"] == moves
+        assert m["workloads"] == lists and m["moves"] == moves
         spec = run.layer_metrics.load(m["name"])
         assert spec["numerator"] == [{"metric": metric, "labels": labels}] and spec["per"] == "request"
         assert metric in metrics.METRICS or metric.removesuffix("_sum") in metrics.METRICS
@@ -367,7 +370,7 @@ def test_the_manifest_names_the_cell_with_one_chip_and_its_four_metrics():
     assert set(new) | {"kernels.hbm_roofline", "executor.unattributed_ms"} <= here
     assert "kernels.hbm_roofline_per_chip" not in here
     assert not set(new) & {m["name"] for m in run.metrics_of(manifest, "per_layer", "tall64.topn")}
-    assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1 and len(manifest["workloads"]) == 4
+    assert [w["name"] for w in manifest["workloads"] if w["chips"] == 1] == ["tall64.topn", "taxi96.dashboard", CELL]
 
 
 def test_the_traffic_is_the_three_queries_over_462_requests():

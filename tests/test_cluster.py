@@ -897,10 +897,14 @@ class TestTranslateReplication:
                 )[0]
                 assert cid and rid
                 deadline = _t.monotonic() + 15
+                # the column store and the row store are pulled one after
+                # the other: wait for both, not for the first alone
                 while _t.monotonic() < deadline:
                     if (
                         s1.translate_store.translate_column_to_string("u", cid)
                         == "alice"
+                        and s1.translate_store.translate_row_to_string("u", "l", rid)
+                        == "pizza"
                     ):
                         break
                     _t.sleep(0.2)

@@ -235,26 +235,75 @@ def _bsi_sum_mesh(mesh, s):
     )
 
 
+MESH_S = 116  # shards of benchmark/configs/ssb20x4.json, 29 a chip
+
+
+def _tiled_quarter(shards: int) -> int:
+    """A device's quarter of the shards as the chip lays it out: the
+    (8, 128) tiling pads it to a multiple of 8, so 29 take 32."""
+    return -(-shards // 4 // 8) * 8
+
+
+def _ssb_mesh_compares(mesh, s):
+    # ssb20x4.flight1's Range leaves as Executor._range_launch launches
+    # them on a mesh: the kept jit of the vmapped kernel under GSPMD,
+    # planes split on the shard axis, the predicates the host's scalars
+    pred = jax.ShapeDtypeStruct((), jnp.uint32)
+
+    def both(discount, quantity, lo, hi, under):
+        between = jax.vmap(executor_mod._range_kernel("><", 4), in_axes=(0, None, None))
+        less = jax.vmap(executor_mod._range_kernel("<", 6), in_axes=(0, None))
+        return between(discount, lo, hi), less(quantity, under)
+
+    return jax.jit(both).lower(s((MESH_S, 5, W)), s((MESH_S, 7, W)), pred, pred, pred)
+
+
+def _ssb_mesh_and(mesh, s):
+    # the eager fold of two materialised stacks, as _bitmap_stack makes it
+    return jax.jit(ops.and_).lower(s((MESH_S, W)), s((MESH_S, W)))
+
+
+def _ssb_mesh_sum(mesh, s):
+    # Sum(..., field=lo_revenue_computed) behind the materialised filter
+    return spmd.bsi_sum_spmd(mesh, 27, True).lower(s((MESH_S, 28, W)), s((MESH_S, W)))
+
+
 @pytest.mark.parametrize(
     "lower",
-    [_count_fold, _count_stack, _topn, _topn_scores_sparse, _bsi_sum_mesh],
+    [_count_fold, _count_stack, _topn, _topn_scores_sparse, _bsi_sum_mesh,
+     _ssb_mesh_compares, _ssb_mesh_and, _ssb_mesh_sum],
     ids=lambda f: f.__name__.strip("_"),
 )
 def test_spmd_kernel_compiles_for_four_chips(mesh, lower):
     sharding = NamedSharding(mesh, P(spmd.SHARD_AXIS))
-    whole = []
+    held = []
 
     def shape(dims, dtype=jnp.uint32):
-        whole.append(int(np.prod(dims)) * jnp.dtype(dtype).itemsize)
+        held.append(_tiled_quarter(dims[0]) * int(np.prod(dims[1:])) * jnp.dtype(dtype).itemsize)
         return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
 
     compiled = lower(mesh, shape).compile()
     text = compiled.as_text()
-    # the cross-shard reduce is a collective inside the program (the
-    # compiler turns a small all_gather into an all-reduce)
-    assert "all-reduce" in text or "all-gather" in text
-    # each device holds a quarter of every shard-major operand
-    assert compiled.memory_analysis().argument_size_in_bytes == sum(whole) // 4
+    mem = compiled.memory_analysis()
+    # each device holds a quarter of every shard-major operand (and a
+    # tile for each of a compare's replicated predicates)
+    assert 0 <= mem.argument_size_in_bytes - sum(held) <= 3 * 512
+    outs = jax.tree_util.tree_leaves(compiled.output_shardings)
+    if lower in (_ssb_mesh_compares, _ssb_mesh_and):
+        # a filter built on the mesh stays split as its planes are: no
+        # collective, and each output a quarter a device
+        assert "all-gather" not in text and "all-reduce" not in text and "all-to-all" not in text
+        assert all(o.is_equivalent_to(sharding, 2) for o in outs)
+        assert 0 <= mem.output_size_in_bytes - len(outs) * _tiled_quarter(MESH_S) * W * 4 <= 512  # a tuple's table
+    else:
+        # the cross-shard reduce is a collective inside the program (the
+        # compiler turns a small all_gather into an all-reduce)
+        assert "all-reduce" in text or "all-gather" in text
+    if lower is _ssb_mesh_sum:
+        # one all-reduce, of the 28 plane counts; no [S, W] stack is gathered
+        assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 1
+        assert "all-gather" not in text
+        assert all(o.is_fully_replicated for o in outs)
 
 
 def _pallas_scores(s):

@@ -168,9 +168,7 @@ def test_carried_hands_span_attribution_and_wave_to_a_pool_thread():
 
 
 @pytest.fixture()
-def gated(tmp_path):
-    """An executor as the server builds it by default: reads run on the
-    device health gate's pool, another thread."""
+def gated_holder(tmp_path):
     h = Holder(str(tmp_path))
     h.open()
     idx = h.create_index("i")
@@ -190,11 +188,32 @@ def gated(tmp_path):
     f.import_bits(np.concatenate(rows), np.concatenate(cols))
     vcols = np.concatenate(vcols)
     v.import_values(vcols, rng.integers(0, 1000, size=vcols.size))
-    health = DeviceHealth(timeout_s=120.0)
-    ex = Executor(h, device_policy="always", health=health)
+    yield h
+    h.close()
+
+
+@pytest.fixture()
+def gated(gated_holder):
+    """An executor as the server builds it by default: reads run on the
+    device health gate's pool, another thread."""
+    ex = Executor(gated_holder, device_policy="always", health=DeviceHealth(timeout_s=120.0))
     yield ex
     ex.close()
-    h.close()
+
+
+@pytest.fixture()
+def gated_mesh(gated_holder):
+    """The same behind ``mesh-devices = 4``: 12 shards, 3 a device."""
+    import jax
+
+    from pilosa_tpu.executor.stager import DeviceStager
+    from pilosa_tpu.parallel.spmd import make_mesh
+
+    mesh = make_mesh(jax.devices()[:4])
+    ex = Executor(gated_holder, device_policy="always", health=DeviceHealth(timeout_s=120.0),
+                  mesh=mesh, stager=DeviceStager(budget_bytes=1 << 30, mesh=mesh))
+    yield ex
+    ex.close()
 
 
 @pytest.mark.parametrize("query", ["TopN(f, Row(f=1), n=3)", "Sum(Row(f=1), field=v)"])
@@ -230,6 +249,35 @@ def test_guarded_read_keeps_its_device_legs(gated, query):
     # wall clock's share under the suite's other workers is judged by
     # the best of the five, not by each
     assert min(shares) < 1 / 3, shares
+
+
+@pytest.mark.parametrize("query", [
+    "Sum(Intersect(Row(f=1), Range(v < 500)), field=v)",
+    "Count(Intersect(Row(f=1), Range(v >< [100, 900])))",
+])
+def test_guarded_read_on_a_mesh_books_the_replicated_results_copy_as_mesh_fetch(gated_mesh, query):
+    """A Sum's and a Count's mesh kernels end in a ``psum``: the copy of
+    the replicated result from one replica is the leg ``mesh.fetch``, as
+    a TopN chunk's gathered scores are (ISSUE 35; it was
+    ``transfer.decode`` inside ``_fetch``), and the legs still sum to no
+    more than the request."""
+    from pilosa_tpu.pql import parse
+
+    parsed = parse(query)
+    want = gated_mesh.execute("i", parsed)  # stage and compile
+    for _ in range(3):
+        wf: dict = {}
+        with trace.attrib_activate(wf):
+            t0 = time.monotonic()
+            res = gated_mesh.execute("i", parsed)
+            total = time.monotonic() - t0
+        assert res == want and res[0]
+        assert wf[trace.WF_MESH_FETCH] > 0.0 and trace.WF_TRANSFER_DECODE not in wf
+        assert wf[trace.WF_DEVICE_COMPUTE] > 0.0 and wf[trace.WF_FILTER_EVAL] > 0.0
+        assert wf.get(trace.WF_GUARD_QUEUE, 0.0) > 0.0
+        assert {k for k in wf if not k.startswith("_")} <= set(trace.WATERFALL_STAGES)
+        assert sum(v for k, v in wf.items() if not k.startswith("_")) <= total * 1.001
+        assert trace.WF_MESH_FETCH in profiler.WaterfallAggregator.DEVICE_STAGES
 
 
 # -- the capture --------------------------------------------------------------
