@@ -53,6 +53,10 @@ READ_QUERIES = [
     "TopN(f, n=4)",
     "Sum(field=val)",
     "Sum(Row(f=1), field=val)",
+    # a filter traced inside the mesh kernel, its predicates a host
+    # vector identical on every rank
+    "Sum(Intersect(Row(f=1), Range(val >< [100, 700])), field=val)",
+    "Count(Union(Row(f=2), Range(val < 250)))",
 ]
 
 

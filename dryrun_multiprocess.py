@@ -105,7 +105,7 @@ def worker() -> None:
 
     # BSI Sum — per-plane popcounts psum'd across processes
     plane_counts = np.asarray(
-        bsi_sum_spmd(mesh, D)(put(planes), put(filt)).addressable_shards[0].data
+        bsi_sum_spmd(mesh, D, ("leaf", 0))(put(planes), put(filt)).addressable_shards[0].data
     )
     want_planes = np.array(
         [

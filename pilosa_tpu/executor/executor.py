@@ -247,7 +247,7 @@ class _ScoreCarry:
         return list(zip(w[some].tolist(), sums[some].tolist()))
 
 
-def _eval_tree(t, leaves):
+def _eval_tree(t, leaves, shards=None):
     """Evaluate a lowered filter tree (``Executor._tree_leaves``) over
     its inputs. Traced inside the jit of the program that consumes the
     filter: boolean nodes, BSI compares and that program are one launch
@@ -255,7 +255,10 @@ def _eval_tree(t, leaves):
     u32[S, W] and, under a ``range`` or ``exists`` node, a field's plane
     stack u32[S, D+1, W]; a tree with ``range`` nodes takes their
     predicates, in base-value form, as one u32 vector after its leaves
-    (``leaves[-1]``), traced, so one program serves every constant."""
+    (``leaves[-1]``), traced, so one program serves every constant.
+    ``shards``: how many shards the program's arrays hold, as the
+    caller observes it, where that may not be the batch a ``zeros`` node
+    was lowered for (a mesh kernel sees a device's share)."""
     tag = t[0]
     if tag == "leaf":
         return leaves[t[1]]
@@ -270,11 +273,11 @@ def _eval_tree(t, leaves):
     if tag == "zeros":
         import jax.numpy as jnp
 
-        return jnp.zeros((t[1], _W32), dtype=jnp.uint32)
+        return jnp.zeros((t[1] if shards is None else shards, _W32), dtype=jnp.uint32)
     fold = _STACK_FOLDS[tag][1]
-    acc = _eval_tree(t[1][0], leaves)
+    acc = _eval_tree(t[1][0], leaves, shards)
     for sub in t[1][1:]:
-        acc = fold(acc, _eval_tree(sub, leaves))
+        acc = fold(acc, _eval_tree(sub, leaves, shards))
     return acc
 
 
@@ -285,12 +288,13 @@ def _eval_filter(tree, inputs, planes):
     kernels do not read."""
     if tree is None:
         return planes[:, -1, :], False
-    return _eval_tree(tree, inputs), True
+    return _eval_tree(tree, inputs, planes.shape[0]), True
 
 
 def _trace_bsi_sum(depth: int, tree, planes, inputs):
     """Plane counts of a Sum under its filter's structure: the body of
-    the lone program (``Executor._bsi_sum_jit``) and of a fused unit."""
+    the lone program (``Executor._bsi_sum_jit``), of a fused unit and,
+    before its psum, of a mesh kernel (``spmd.bsi_sum_spmd``)."""
     filt, has_filter = _eval_filter(tree, inputs, planes)
     return ops.bsi_plane_counts_batched(
         planes, filt, bit_depth=depth, has_filter=has_filter
@@ -640,7 +644,7 @@ class Executor:
                 from pilosa_tpu.parallel import spmd
 
                 if kind == "count":
-                    fn = spmd.count_stack_spmd(self.mesh)
+                    fn = spmd.count_stack_spmd(self.mesh, *statics)
                 elif kind == "plane_counts":
                     fn = spmd.bsi_sum_spmd(self.mesh, *statics)
                 elif kind == "topn_scores_sparse":
@@ -1602,8 +1606,9 @@ class Executor:
 
     def _tree_leaves(self, index, c: Call, batch):
         """Lower a filter to (inputs, structure) for a consumer that is
-        itself one jitted program on one device (``_eval_tree`` traces
-        the structure inside it). Boolean calls and BSI Ranges become
+        itself one jitted program, on one device or, a Sum or a Count,
+        a ``shard_map`` kernel over the mesh (``_eval_tree`` traces the
+        structure inside it). Boolean calls and BSI Ranges become
         structure: a Range is its field's staged plane stack, a leaf,
         and its predicates in base-value form, slots of one u32 vector
         that follows the leaves; where the field's bounds decide it, the
@@ -1699,7 +1704,8 @@ class Executor:
     def _device_bitmap_stack(self, index, c: Call, shards):
         """Lower a bitmap call subtree to one materialised u32[S, W]
         across shards, for a consumer that reads it as an array (a
-        TopN's source, a mesh kernel, a leaf of ``_tree_leaves``):
+        TopN's source, a per-call GroupBy, Distinct or Percentile, a
+        leaf of ``_tree_leaves``):
         boolean calls fold eagerly and a Range launches its compare,
         each counted to ``filter.launches``. The host's time in it is
         the request's ``filter.eval``; a staged row's probe inside
@@ -1924,15 +1930,14 @@ class Executor:
 
     def _count_device_batched(self, index, child, shards) -> int:
         batch = self._shard_plan(shards)
-        if self.mesh is not None:
-            words = self._device_bitmap_stack(index, child, batch)
-            return int(_mesh_fetch(self._spmd_kernel("count")(words)))
         # One fused program per query-tree structure: boolean
         # internal nodes trace into a single jit so the whole
         # chain is one XLA fusion + one dispatch, instead of an
         # eager op (one dispatch each) per tree node (SURVEY.md
-        # §7 step 4).
+        # §7 step 4). On a mesh it is a shard_map kernel.
         leaves, tree = self._tree_leaves(index, child, batch)
+        if self.mesh is not None:
+            return int(_mesh_fetch(self._spmd_kernel("count", tree)(*leaves)))
         res = self._tree_count_jit(tree)(*leaves)
         return int(_fetch(res).reshape(-1)[0])
 
@@ -2035,23 +2040,14 @@ class Executor:
 
     def _sum_device_batched(self, index, c: Call, batch, bsig, frags) -> ValCount:
         depth = bsig.bit_depth()
+        # one program a filter structure: the filter's compares and
+        # folds are traced into the sum's launch, as Count's are
+        inputs, tree = self._filter_tree(index, c, batch)
+        planes = self.stager.planes_stack(frags, depth)
         if self.mesh is not None:
-            # an SPMD kernel takes its filter as an array
-            if len(c.children) == 1:
-                filt = self._device_bitmap_stack(index, c.children[0], batch)
-                has_filter = True
-            else:
-                filt = np.zeros((len(batch), _W32), dtype=np.uint32)
-                has_filter = False
-            planes = self.stager.planes_stack(frags, depth)
-            counts = _mesh_fetch(
-                self._spmd_kernel("plane_counts", depth, has_filter)(planes, filt)
-            )
+            kernel = self._spmd_kernel("plane_counts", depth, tree)
+            counts = _mesh_fetch(kernel(planes, *inputs))
         else:
-            # one program a filter structure: the filter's compares and
-            # folds are traced into the sum's launch, as Count's are
-            inputs, tree = self._filter_tree(index, c, batch)
-            planes = self.stager.planes_stack(frags, depth)
             counts = _fetch(self._bsi_sum_jit(depth, tree)(planes, *inputs))
         vsum = sum(int(counts[i]) << i for i in range(depth))
         vcount = int(counts[depth])

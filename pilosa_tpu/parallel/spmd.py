@@ -177,24 +177,42 @@ def topn_batch_spmd(mesh: Mesh, k: int):
     )
 
 
-def count_stack_spmd(mesh: Mesh):
-    """Global popcount of a shard-sharded word stack in one program.
+def _filter_program(mesh: Mesh, kernel):
+    """``kernel(*arrays)`` as one program over the mesh with a small
+    replicated result. Every array of rank two or more is a shard stack
+    (its leading dim split over the mesh); a vector is a lowered
+    filter's predicates (``executor._eval_tree``), replicated."""
 
-    words: u32[S, W] (leading dim split over the mesh) -> i32 global
-    count. This is the serving executor's batched Count terminal: the
-    bitmap subtree has already folded elementwise (sharding-preserving),
-    so the only collective is the final psum — the reference's
-    uint64-sum reduceFn (executor.go:966-996) riding ICI.
+    @jax.jit
+    def program(*arrays):
+        specs = tuple(P(SHARD_AXIS) if a.ndim > 1 else P() for a in arrays)
+        return jax.shard_map(kernel, mesh=mesh, in_specs=specs, out_specs=P())(*arrays)
+
+    return program
+
+
+def count_stack_spmd(mesh: Mesh, tree):
+    """Global popcount of a filter over all shards in one program.
+
+    ``tree`` is the filter's structure and the arguments its inputs, as
+    ``Executor._tree_leaves`` lowers them: leaves u32[S, W] or a field's
+    planes u32[S, D+1, W], then the predicate vector where the tree has
+    ``range`` nodes -> i32 global count. This is the serving executor's
+    batched Count terminal: the tree is elementwise over the shard axis,
+    so each device evaluates its shards' share and the only collective
+    is the final psum — the reference's uint64-sum reduceFn
+    (executor.go:966-996) riding ICI. ``("leaf", 0)`` counts one stack.
     """
+    from pilosa_tpu.executor.executor import _eval_tree
 
     @jax.named_scope("count")
-    def kernel(block):  # u32[s_local, W]
-        local = jnp.sum(jax.lax.population_count(block).astype(jnp.int32))
+    def kernel(*inputs):  # leaves u32[s_local, ...]
+        # a filter with no input is all-zero nodes: no rows count 0
+        words = _eval_tree(tree, inputs, inputs[0].shape[0] if inputs else 0)
+        local = jnp.sum(jax.lax.population_count(words).astype(jnp.int32))
         return jax.lax.psum(local, SHARD_AXIS)
 
-    return jax.jit(
-        jax.shard_map(kernel, mesh=mesh, in_specs=(P(SHARD_AXIS),), out_specs=P())
-    )
+    return _filter_program(mesh, kernel)
 
 
 def topn_scores_sparse_spmd(mesh: Mesh, k: int):
@@ -244,34 +262,26 @@ def topn_scores_sparse_spmd(mesh: Mesh, k: int):
     )
 
 
-def bsi_sum_spmd(mesh: Mesh, bit_depth: int, has_filter: bool = True):
+def bsi_sum_spmd(mesh: Mesh, bit_depth: int, tree=None):
     """Sum(field) over all shards: per-plane popcounts psum'd over ICI.
 
-    planes: u32[S, D+1, W], filter: u32[S, W]. Returns i32[D+1] global
-    per-plane counts; host computes Σ counts[i]<<i in exact Python ints.
-    has_filter is static: an unfiltered Sum counts the planes directly
-    (the reference's fragment.sum with nil filter) rather than ANDing
-    with an all-ones mask.
+    planes: u32[S, D+1, W], then the inputs of the filter whose
+    structure ``tree`` is (``count_stack_spmd``; None: an unfiltered Sum
+    counts the planes directly, the reference's fragment.sum with nil
+    filter). Each device evaluates the filter over its own shards and
+    counts: the arithmetic of the one-device program
+    (``executor._trace_bsi_sum``), one launch a request. Returns
+    i32[D+1] global per-plane counts; host computes Σ counts[i]<<i in
+    exact Python ints.
     """
+    from pilosa_tpu.executor.executor import _trace_bsi_sum
 
     @jax.named_scope("plane_counts")
-    def kernel(planes, filt):
-        block = (
-            jnp.bitwise_and(planes, filt[:, None, :]) if has_filter else planes
-        )  # [s_local, D+1, W]
-        local = jnp.sum(
-            jax.lax.population_count(block).astype(jnp.int32), axis=(0, 2)
-        )  # [D+1]
+    def kernel(planes, *inputs):  # planes u32[s_local, D+1, W]
+        local = _trace_bsi_sum(bit_depth, tree, planes, inputs)
         return jax.lax.psum(local, SHARD_AXIS)
 
-    return jax.jit(
-        jax.shard_map(
-            kernel,
-            mesh=mesh,
-            in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-            out_specs=P(),
-        )
-    )
+    return _filter_program(mesh, kernel)
 
 
 def row_algebra_spmd(mesh: Mesh, op: str):

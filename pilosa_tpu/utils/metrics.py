@@ -457,17 +457,17 @@ METRICS: dict[str, tuple[str, str]] = {
         "counter",
         "device launches made for a call's filter, shard-batched, before "
         "the program that consumes it, where the consumer reads the filter "
-        "as one array: a TopN's source, a mesh kernel, a per-call GroupBy, "
-        "Distinct or Percentile (label: op = range, a BSI compare or the "
+        "as one array: a TopN's source, a per-call GroupBy, Distinct or "
+        "Percentile (label: op = range, a BSI compare or the "
         "copy of the existence plane that stands for one; and, or, xor, "
         "andnot, an eager boolean op between two stacks)",
     ),
     FILTER_INLINED: (
         "counter",
-        "nodes of a call's filter traced into the one-device program that "
-        "consumes it (Count, Sum, and a fused Distinct, Percentile or "
-        "GroupBy), so that they cost no launch of their own, counted at "
-        "lowering as the launches they replace would be (label: op = "
+        "nodes of a call's filter traced into the one program, on a device "
+        "or a mesh, that consumes it (Count, Sum, and a fused Distinct, "
+        "Percentile or GroupBy), so that they cost no launch of their own, "
+        "counted at lowering as the launches they replace would be (label: op = "
         "range, a BSI compare or the existence plane that stands for one; "
         "and, or, xor, andnot, a boolean node once for each child after "
         "its first)",

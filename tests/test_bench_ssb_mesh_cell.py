@@ -3,7 +3,8 @@
 devices. (a) the mesh executor, the one-device executor and the plain
 reference on the three queries; (b) where every staged stack and every
 materialised filter lies, and what a second pass stages; (c) what one
-request counts and books, on the guard thread; (d) a two-call query and
+request counts and books, on the guard thread: since ISSUE 36 one
+launch, its filter traced inside the mesh kernel; (d) a two-call query and
 the fuser's mesh bypass; (e) two callers at once; (f) the configuration
 and the manifest; (g) the cell through the harness against a server
 child started from the configuration's own TOML."""
@@ -137,10 +138,6 @@ def _launches() -> dict:
     return _by_op(metrics.FILTER_LAUNCHES)
 
 
-def _inlined() -> float:
-    return sum(_by_op(metrics.FILTER_INLINED).values())
-
-
 def _grown(now: dict, was: dict) -> dict:
     return {k: now[k] - was[k] for k in now if now[k] != was[k]}
 
@@ -176,9 +173,11 @@ def test_mesh_one_device_and_reference_agree_on_flight_1(build, mesh, seed):
         whole, band = ref.answer(EDGES["discount_0_to_10_is_the_existence_plane"]), ref.answer(_q11(1))
         assert whole["count"] > 20 * band["count"] / 11  # 11 discounts where 3 were
         assert sum(1 for a in answered if a["count"]) >= 12  # the drawn ones hold line items
-        # the mesh launched its compares; the one device traced them into the sum's program
-        assert set(four._range_jits) == {("><", 4), ("<", 6), ("><", 6)} and not one._range_jits
-        assert {k[0] for k in four._spmd_kernels} == {"plane_counts"} and not four._tree_jits
+        # both traced the compares into the sum's program: one a filter structure
+        # (Q1.1, Q1.2, Q1.3, and the two edges the bounds decide), a shard_map kernel on the mesh
+        assert not four._range_jits and not one._range_jits and not four._tree_jits
+        assert {k[0] for k in four._spmd_kernels} == {"plane_counts"} and {k[0] for k in one._tree_jits} == {"bsi_sum"}
+        assert {k[1:] for k in four._spmd_kernels} == {k[1:] for k in one._tree_jits} and len(one._tree_jits) == 5
     finally:
         one.close()
         four.close()
@@ -228,7 +227,8 @@ def test_a_second_pass_stages_nothing_and_every_stack_and_filter_lies_a_quarter_
         assert st._bytes == (5 + 7 + 28 + len(rows)) * SMALL["shards"] * DENSE
         for a in arrays:
             assert a.shape[0] == SMALL["shards"] and _a_quarter_a_device(a, mesh), (a.shape, _quarters(a))
-        # every materialised filter: compares, folds, the existence plane's copy
+        # every materialised filter (a TopN's source still reads one): compares, folds,
+        # the existence plane's copy
         from pilosa_tpu.pql import parse
 
         for call in calls:
@@ -246,16 +246,17 @@ def test_a_second_pass_stages_nothing_and_every_stack_and_filter_lies_a_quarter_
 # -- (c) what one request counts and books ------------------------------------
 
 
-def test_one_request_launches_its_filter_then_one_sum_and_one_fetch_on_the_guard_thread(built, mesh):
+def test_one_request_is_one_launch_with_its_filter_inlined_and_one_fetch_on_the_guard_thread(built, mesh):
     """The server's default executor runs a read on the device health
     gate's pool thread: every leg must land in the request's waterfall
-    from there, once."""
+    from there, once. The filter is structure inside the mesh kernel:
+    nothing is launched ahead of the sum."""
     from pilosa_tpu.pql import parse
 
     _, h = built
     ex = _mesh_executor(h, mesh, health=DeviceHealth(timeout_s=120.0))
     try:
-        for call, want in ((_q11(3), {"range": 2, "and": 2}), (_q13(5, 2), {"range": 2, "and": 3})):
+        for call, want, date_rows in ((_q11(3), {"range": 2, "and": 2}, 1), (_q13(5, 2), {"range": 2, "and": 3}, 2)):
             parsed = parse(traffic.pql(call))
             ex.execute(SMALL["index"], parsed)  # stage and compile
             legs = []
@@ -267,7 +268,7 @@ def test_one_request_launches_its_filter_then_one_sum_and_one_fetch_on_the_guard
                     legs.append(lg)
                 return lg
 
-            before = (_launches(), _inlined(), _executions("plane_counts"),
+            before = (_launches(), _by_op(metrics.FILTER_INLINED), _executions("plane_counts"),
                       _counter(metrics.KERNEL_OPERAND_BYTES, kind="bsi_range"),
                       _counter(metrics.KERNEL_OPERAND_BYTES, kind="plane_counts"))
             wf: dict = {}
@@ -277,13 +278,15 @@ def test_one_request_launches_its_filter_then_one_sum_and_one_fetch_on_the_guard
                     t0 = time.monotonic()
                     ex.execute(SMALL["index"], parsed)
                     total = time.monotonic() - t0
-            assert _grown(_launches(), before[0]) == want and sum(want.values()) in (4, 5)
-            assert _inlined() == before[1]
+            assert _grown(_by_op(metrics.FILTER_INLINED), before[1]) == want and sum(want.values()) in (4, 5)
+            assert _grown(_launches(), before[0]) == {}
             assert _executions("plane_counts") == before[2] + 1
-            # the compares' operands: lo_discount's 4 + 1 planes, lo_quantity's 6 + 1
-            assert _counter(metrics.KERNEL_OPERAND_BYTES, kind="bsi_range") - before[3] == 12 * SMALL["shards"] * DENSE
-            # the sum's: lo_revenue_computed's 27 + 1 planes and the filter
-            assert _counter(metrics.KERNEL_OPERAND_BYTES, kind="plane_counts") - before[4] == 29 * SMALL["shards"] * DENSE
+            assert _counter(metrics.KERNEL_OPERAND_BYTES, kind="bsi_range") == before[3]
+            # the one program's: lo_revenue_computed's 27 + 1 planes, lo_discount's 4 + 1,
+            # lo_quantity's 6 + 1, the date rows, and the predicates' u32 vector
+            preds = 3 if date_rows == 1 else 4
+            assert (_counter(metrics.KERNEL_OPERAND_BYTES, kind="plane_counts") - before[4]
+                    == (28 + 5 + 7 + date_rows) * SMALL["shards"] * DENSE + 4 * preds)
             assert len(legs) == 1
             assert wf[trace.WF_MESH_FETCH] == pytest.approx(legs[0].seconds) and legs[0].seconds > 0.0
             assert wf[trace.WF_FILTER_EVAL] > 0.0 and wf[trace.WF_DEVICE_COMPUTE] > 0.0
@@ -461,9 +464,9 @@ def test_the_cell_runs_correct_on_a_four_device_mesh(monkeypatch, tmp_path):
     assert phases["serve"]["build_info"]["device_count"] == str(DEVICES)
     warm = phases["warm_up"]
     assert warm["distinct_requests"] == 462 and warm["clients"] == 2
-    # three compares (>< at depth 4 and 6, < at 6) and one sum; nothing is fused on a mesh
+    # one mesh kernel a filter structure (Q1.1, Q1.2, Q1.3), the compares inside; nothing is fused on a mesh
     by_kind = warm["compiles_by_kind"]
-    assert (by_kind["bsi_range"], by_kind["plane_counts"]) == (3, 1)
+    assert by_kind["plane_counts"] == 3 and "bsi_range" not in by_kind
     assert "fused_query" not in by_kind and "bsi_sum" not in by_kind
     assert warm["rounds_compiled"][-2:] == [(0, 0), (0, 0)]
     w = phases["window"]

@@ -6,7 +6,10 @@ Distinct, Percentile or GroupBy that consumes them, and counts them to
 still has its filter evaluated before it, counted to
 ``filter.launches``. (a) inlined against evaluated: every answer equals
 the CPU path's; (c) a TopN's source is materialised as before. What one
-flight-1 request launches, (b), is in ``test_bench_ssb_cell.py``."""
+flight-1 request launches, (b), is in ``test_bench_ssb_cell.py``. A Sum
+or a Count on a mesh is such a consumer too (ISSUE 36): its filter is
+traced inside the ``shard_map`` kernel, on four virtual devices here,
+the module's three shards padded to four."""
 
 import numpy as np
 import pytest
@@ -16,6 +19,8 @@ from pilosa_tpu.core import Holder
 from pilosa_tpu.core.field import FieldOptions
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.executor.executor import _eval_tree
+from pilosa_tpu.executor.stager import DeviceStager
+from pilosa_tpu.parallel.spmd import make_mesh
 from pilosa_tpu.utils import metrics
 
 OPS = ("range", "and", "or", "xor", "andnot")
@@ -71,6 +76,8 @@ CONSUMERS = {
     "percentile": "Percentile({}, field=w, nth=50)",
     "groupby": "GroupBy(Rows(f), {})",
 }
+# the consumers that are one shard_map kernel on a mesh
+MESH_CONSUMERS = {"mesh_sum": CONSUMERS["sum"], "mesh_count": CONSUMERS["count"]}
 
 
 @pytest.fixture(scope="module")
@@ -100,6 +107,17 @@ def device(holder):
 
 
 @pytest.fixture(scope="module")
+def mesh(holder):
+    import jax
+
+    four = make_mesh(jax.devices()[:4])
+    ex = Executor(holder, device_policy="always", mesh=four,
+                  stager=DeviceStager(budget_bytes=1 << 30, mesh=four))
+    yield ex
+    ex.close()
+
+
+@pytest.fixture(scope="module")
 def cpu(holder):
     ex = Executor(holder, device_policy="never")
     yield ex
@@ -118,10 +136,13 @@ def _grown(name, before):
 # -- (a) inlined against evaluated --------------------------------------------
 
 
-@pytest.mark.parametrize("consumer", sorted(CONSUMERS))
+@pytest.mark.parametrize("consumer", sorted(CONSUMERS) + sorted(MESH_CONSUMERS))
 @pytest.mark.parametrize("case", sorted(FILTERS))
-def test_an_inlined_filter_answers_as_the_cpu_path(device, cpu, case, consumer):
-    q = CONSUMERS[consumer].format(FILTERS[case])
+def test_an_inlined_filter_answers_as_the_cpu_path(device, mesh, cpu, case, consumer):
+    if consumer in MESH_CONSUMERS:
+        device, q = mesh, MESH_CONSUMERS[consumer].format(FILTERS[case])
+    else:
+        q = CONSUMERS[consumer].format(FILTERS[case])
     launches = _counted(metrics.FILTER_LAUNCHES)
     got = device.execute("i", q)
     # nothing was launched for the filter ahead of its consumer
@@ -191,16 +212,24 @@ def _slots(tree):
             yield from _slots(sub)
 
 
-def test_one_program_serves_every_constant(device):
+@pytest.mark.parametrize("where", ["device", "mesh"])
+def test_one_program_serves_every_constant(request, cpu, where):
+    """On a mesh the program is a shard_map kernel kept in
+    ``_spmd_kernels``, and a two-call query runs call by call (the
+    fuser stands down), so it adds none either."""
+    ex = request.getfixturevalue(where)
+    kept = ex._spmd_kernels if where == "mesh" else ex._tree_jits
     q = "Sum(Intersect(Row(f={}), Range(v >< [{}, {}]), Range(w < {})), field=w)"
-    first = device.execute("i", q.format(1, 1, 300, 25))
-    programs = len(device._tree_jits), len(device.fuser._programs)
-    again = device.execute("i", q.format(2, 40, 90, 11))
-    assert first != again
-    device.execute("i", q.format(1, 1, 300, 25) + q.format(2, 40, 90, 11))
-    pair = len(device.fuser._programs)
-    device.execute("i", q.format(3, 2, 7, 50) + q.format(0, 500, 900, 3))
-    assert (len(device._tree_jits), len(device.fuser._programs)) == (programs[0], pair)
+    first = ex.execute("i", q.format(1, 1, 300, 25))
+    programs = len(kept)
+    again = ex.execute("i", q.format(2, 40, 90, 11))
+    assert first != again and again == cpu.execute("i", q.format(2, 40, 90, 11))
+    ex.execute("i", q.format(1, 1, 300, 25) + q.format(2, 40, 90, 11))
+    pair = len(ex.fuser._programs)
+    assert (pair == 0) == (where == "mesh")
+    other = q.format(3, 2, 7, 50) + q.format(0, 500, 900, 3)
+    assert ex.execute("i", other) == cpu.execute("i", other)
+    assert (len(kept), len(ex.fuser._programs)) == (programs, pair)
 
 
 # -- (c) a consumer that reads an array keeps its filter materialised ---------

@@ -98,7 +98,7 @@ def test_bsi_sum_spmd(mesh):
     S, D = 8, 6
     planes = rand_words(rng, S, D + 1, W)
     filt = rand_words(rng, S, W)
-    fn = bsi_sum_spmd(mesh, D)
+    fn = bsi_sum_spmd(mesh, D, ("leaf", 0))  # the filter is one staged stack
     counts = np.asarray(fn(put_sharded(mesh, planes), put_sharded(mesh, filt)))
     for i in range(D + 1):
         want = sum(popcount(planes[s, i] & filt[s]) for s in range(S))

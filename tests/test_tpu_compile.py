@@ -215,7 +215,7 @@ def _count_fold(mesh, s):
 
 
 def _count_stack(mesh, s):
-    return spmd.count_stack_spmd(mesh).lower(s((S, W)))
+    return spmd.count_stack_spmd(mesh, ("leaf", 0)).lower(s((S, W)))
 
 
 def _topn(mesh, s):
@@ -230,7 +230,7 @@ def _topn_scores_sparse(mesh, s):
 
 
 def _bsi_sum_mesh(mesh, s):
-    return spmd.bsi_sum_spmd(mesh, DEPTH, True).lower(
+    return spmd.bsi_sum_spmd(mesh, DEPTH, ("leaf", 0)).lower(
         s((S, DEPTH + 1, W)), s((S, W))
     )
 
@@ -245,8 +245,9 @@ def _tiled_quarter(shards: int) -> int:
 
 
 def _ssb_mesh_compares(mesh, s):
-    # ssb20x4.flight1's Range leaves as Executor._range_launch launches
-    # them on a mesh: the kept jit of the vmapped kernel under GSPMD,
+    # flight 1's Range leaves as Executor._range_launch launches them on
+    # a mesh for a consumer that reads an array (a TopN's source since
+    # ISSUE 36): the kept jit of the vmapped kernel under GSPMD,
     # planes split on the shard axis, the predicates the host's scalars
     pred = jax.ShapeDtypeStruct((), jnp.uint32)
 
@@ -264,14 +265,31 @@ def _ssb_mesh_and(mesh, s):
 
 
 def _ssb_mesh_sum(mesh, s):
-    # Sum(..., field=lo_revenue_computed) behind the materialised filter
-    return spmd.bsi_sum_spmd(mesh, 27, True).lower(s((MESH_S, 28, W)), s((MESH_S, W)))
+    # Sum(Row(...), field=lo_revenue_computed): the filter one staged stack
+    return spmd.bsi_sum_spmd(mesh, 27, ("leaf", 0)).lower(s((MESH_S, 28, W)), s((MESH_S, W)))
+
+
+def _ssb_mesh_inlined(mesh, s, tree, rows, predicates):
+    # an ssb20x4.flight1 request as it is launched since ISSUE 36: the
+    # compares and the folds inside the sum's shard_map kernel, the
+    # predicates one u32 vector on every device
+    preds = jax.ShapeDtypeStruct((predicates,), jnp.uint32, sharding=NamedSharding(mesh, P()))
+    stacks = [s((MESH_S, 28, W)), *(s((MESH_S, W)) for _ in range(rows)), s((MESH_S, 5, W)), s((MESH_S, 7, W))]
+    return spmd.bsi_sum_spmd(mesh, 27, tree).lower(*stacks, preds)
+
+
+def _ssb_mesh_sum_q11(mesh, s):
+    return _ssb_mesh_inlined(mesh, s, _SSB_Q11, 1, 3)
+
+
+def _ssb_mesh_sum_q13(mesh, s):
+    return _ssb_mesh_inlined(mesh, s, _SSB_Q13, 2, 4)
 
 
 @pytest.mark.parametrize(
     "lower",
     [_count_fold, _count_stack, _topn, _topn_scores_sparse, _bsi_sum_mesh,
-     _ssb_mesh_compares, _ssb_mesh_and, _ssb_mesh_sum],
+     _ssb_mesh_compares, _ssb_mesh_and, _ssb_mesh_sum, _ssb_mesh_sum_q11, _ssb_mesh_sum_q13],
     ids=lambda f: f.__name__.strip("_"),
 )
 def test_spmd_kernel_compiles_for_four_chips(mesh, lower):
@@ -299,10 +317,11 @@ def test_spmd_kernel_compiles_for_four_chips(mesh, lower):
         # the cross-shard reduce is a collective inside the program (the
         # compiler turns a small all_gather into an all-reduce)
         assert "all-reduce" in text or "all-gather" in text
-    if lower is _ssb_mesh_sum:
-        # one all-reduce, of the 28 plane counts; no [S, W] stack is gathered
+    if lower in (_ssb_mesh_sum, _ssb_mesh_sum_q11, _ssb_mesh_sum_q13):
+        # one all-reduce, of the 28 plane counts; no [S, W] stack is gathered:
+        # a device evaluates the filter over its own 29 shards
         assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 1
-        assert "all-gather" not in text
+        assert "s32[28]" in text and "all-gather" not in text and "all-to-all" not in text
         assert all(o.is_fully_replicated for o in outs)
 
 
