@@ -324,9 +324,8 @@ class BatchedScorer:
             metrics.observe(metrics.BATCHER_BATCH_SIZE, len(batch))
             if len(batch) == 1:
                 src, trim = batch[0].src, batch[0].trim
-                t0 = self._note_launch(src, mat)
-                dev = _trim_device(self._single_fn(src, mat), rows=trim)
-                launched.append((batch, dev, t0))
+                dev, t0 = self._timed_launch(self._single_fn, src, mat)
+                launched.append((batch, _trim_device(dev, rows=trim), t0))
                 return launched
             for start in range(0, len(batch), self.max_batch):
                 chunk = batch[start : start + self.max_batch]
@@ -346,8 +345,7 @@ class BatchedScorer:
                                 "batcher", int(getattr(zero, "nbytes", 0))
                             )
                     srcs = srcs + [zero] * (q - len(chunk))
-                t0 = self._note_launch(srcs, mat)
-                dev = self._batch_fn(srcs, mat)
+                dev, t0 = self._timed_launch(self._batch_fn, srcs, mat)
                 # transfer hygiene: pad query lanes never reach the
                 # host, and when every slot declared its read width the
                 # score columns trim device-side too (the fetch then
@@ -363,11 +361,18 @@ class BatchedScorer:
                     s.event.set()
             raise
 
-    def _note_launch(self, srcs, mat) -> float:
-        """One launch's operand bytes (padding included) under the
-        scorer's kernel name; returns the launch time for _finish."""
-        profiler.count_operands(self.kind, (srcs, mat))
-        return time.monotonic()
+    def _timed_launch(self, fn, srcs, mat) -> tuple:
+        """One launch under the scorer's kernel name: the jit call up to
+        its return of the not-yet-ready scores is ``device.launch``
+        (nested in the leader's ``device.compute``) and
+        ``spmd.launch_seconds``, the operand bytes (padding included)
+        counted in it; returns the scores and the launch time for
+        _finish."""
+        with trace.leg(trace.WF_DEVICE_LAUNCH) as launch:
+            profiler.count_operands(self.kind, (srcs, mat))
+            dev = fn(srcs, mat)
+        metrics.observe(metrics.SPMD_LAUNCH_SECONDS, launch.seconds, kind=self.kind)
+        return dev, launch.t0
 
     def _finish(self, launched: list[tuple]) -> None:
         """Fetch launched device results and wake the coalesced slots.

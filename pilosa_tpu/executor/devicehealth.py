@@ -128,11 +128,15 @@ class DeviceHealth:
         fn = trace.carried(fn)
 
         picked_up: list[float] = []
+        done: list[float] = []
 
         def run():
             picked_up.append(time.monotonic())
             started.set()
-            return fn()
+            try:
+                return fn()
+            finally:
+                done.append(time.monotonic())
 
         try:
             fut = pool.submit(run)
@@ -168,7 +172,8 @@ class DeviceHealth:
             raise DeviceDown("guard pool shut down mid-queue")
         while True:
             try:
-                return fut.result(timeout=timeout)
+                fut.exception(timeout=timeout)  # the wait; the outcome is read below
+                break
             except CancelledError:
                 raise DeviceDown("guard pool shut down mid-queue")
             except FutureTimeout:
@@ -180,6 +185,10 @@ class DeviceHealth:
                     continue
                 self._trip("device probe failed after call deadline")
                 raise DeviceDown("device call timed out and probe failed")
+        # the hand-back: the worker's last leg closed at its stamp, this
+        # thread runs again only now (it must take the interpreter back)
+        trace.book(trace.WF_HANDOFF_WAKE, time.monotonic() - done[0])
+        return fut.result()
 
     def trip(self, reason: str) -> None:
         """Gate the device off from outside the guard path. Used by the

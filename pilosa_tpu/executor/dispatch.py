@@ -93,6 +93,7 @@ class _Item:
         "value",
         "error",
         "t_enq",
+        "t_done",
         "wait_s",
         "trace_ctx",
         "attrib",
@@ -114,6 +115,7 @@ class _Item:
         self.value: Any = None
         self.error: Optional[BaseException] = None
         self.t_enq = 0.0
+        self.t_done = 0.0
         self.wait_s = 0.0
         # distributed trace context (utils/trace.py tuple): a deduped
         # item span-links the executed item it shared results with
@@ -130,6 +132,7 @@ class _Item:
     def finish(self, result=None, error=None) -> None:
         self.value = result
         self.error = error
+        self.t_done = time.monotonic()
         self.event.set()
 
     def result(self) -> Any:
@@ -149,11 +152,10 @@ class _Item:
         d = trace.attrib_current()
         if d is not None:
             # the waiter's waterfall: queue wait + this item's share of
-            # the wave's measured legs (+ the wave id for log joins)
-            if self.wait_s > 0.0:
-                d[trace.WF_DISPATCH_QUEUE] = (
-                    d.get(trace.WF_DISPATCH_QUEUE, 0.0) + self.wait_s
-                )
+            # the wave's measured legs (+ the wave id for log joins) +
+            # the hand-back, the wave's finishing stamp → running again
+            trace.book(trace.WF_HANDOFF_WAKE, time.monotonic() - self.t_done)
+            trace.book(trace.WF_DISPATCH_QUEUE, self.wait_s)
             if self.attrib:
                 for k, v in self.attrib.items():
                     if k != "_req":

@@ -357,8 +357,10 @@ def _timed_kernel(kind: str, fn, signature=None, recovery=None):
     This is also the device-leg fence (ISSUE 12): ``block_until_ready``
     on the outputs pins the measurement to real device completion
     instead of async-dispatch return, so the timing feeds the waterfall
-    as device.compute and the first call feeds the compile tracker
-    under ``signature`` (the canonical plan key of the cached jit).
+    (the jit call itself as device.launch and, warm,
+    spmd.launch_seconds; the wait after it as device.compute) and the
+    first call feeds the compile tracker under ``signature`` (the
+    canonical plan key of the cached jit).
 
     And it is the OOM-recovery boundary (ISSUE 14): with ``recovery``
     (an executor's OomRecovery) an allocation failure at dispatch or at
@@ -372,7 +374,14 @@ def _timed_kernel(kind: str, fn, signature=None, recovery=None):
         cf = chaos.FAULTS
         if cf is not None:
             cf.on_kernel(kind)
-        out = fn(*args, **kw)
+        # the jit call up to its return of the not-yet-ready result, the
+        # host's dispatch (to every device of a mesh), apart from the
+        # wait for it below
+        with trace.leg(trace.WF_DEVICE_LAUNCH) as launch:
+            profiler.count_operands(kind, args)
+            out = fn(*args, **kw)
+        if not state["first"]:
+            metrics.observe(metrics.SPMD_LAUNCH_SECONDS, launch.seconds, kind=kind)
         try:
             import jax  # lazy, matching this module's other jax uses
 
@@ -386,7 +395,6 @@ def _timed_kernel(kind: str, fn, signature=None, recovery=None):
         return out
 
     def run(*args, **kw):
-        profiler.count_operands(kind, args)
         with trace.leg(trace.WF_DEVICE_COMPUTE) as lg:
             if recovery is not None:
                 out = recovery.run(lambda: attempt(*args, **kw), kind=kind)
@@ -417,20 +425,24 @@ OOM_CPU_COOLDOWN_S = 30.0
 def _launch(kind: str, fn, *args, **kw):
     """Launch a module-level jitted kernel whose small result the caller
     reads at once, under its name, and return the result on the host:
-    launch → fetched is the request's device leg and
-    ``spmd.execute_seconds{kind}``, the operands count to
-    ``kernel.operand_bytes{kind}``. Wait and copy stay one step, as
+    launch → fetched is ``spmd.execute_seconds{kind}`` and the request's
+    device legs: the jit call ``device.launch``
+    (``spmd.launch_seconds{kind}``; the operands count to
+    ``kernel.operand_bytes{kind}`` in it), the rest ``device.compute``.
+    Wait and copy stay one step, as
     ``np.asarray`` makes them (a count vector is a few KB): waiting
     apart would hand the interpreter lock over once more per launch. A
     first call's compile is in the time (``profiler.compiles{kind=xla}``
     counts it)."""
-    profiler.count_operands(kind, args)
     with trace.leg(trace.WF_DEVICE_COMPUTE) as lg:
-        out = fn(*args, **kw)
+        with trace.leg(trace.WF_DEVICE_LAUNCH) as launch:
+            profiler.count_operands(kind, args)
+            out = fn(*args, **kw)
         if isinstance(out, tuple):
             out = tuple(np.asarray(o) for o in out)
         else:
             out = np.asarray(out)
+    metrics.observe(metrics.SPMD_LAUNCH_SECONDS, launch.seconds, kind=kind)
     metrics.observe(metrics.SPMD_EXECUTE_SECONDS, lg.seconds, kind=kind)
     return out
 

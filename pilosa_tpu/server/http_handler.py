@@ -427,16 +427,18 @@ class Handler:
     @staticmethod
     def _record_waterfall(cls: str, summary: dict, index: str) -> None:
         """Aggregate one query's waterfall. Under the HTTP transport the
-        request's ``admission`` joins the summary now (``profile=
-        waterfall`` shows it) and the record waits for ``respond``,
-        which ends with the last write (``_Req._run``); a caller with no
-        transport around it (``Handler.handle`` direct) records here."""
+        request's ``admission`` and the pipeline worker's hand-back
+        (``handoff.wake``, booked by ``pipeline.submit``'s waiter) join
+        the summary now (``profile=waterfall`` shows them) and the
+        record waits for ``respond``, which ends with the last write
+        (``_Req._run``); a caller with no transport around it
+        (``Handler.handle`` direct) records here."""
         transport = trace.attrib_current()
         if transport is None:
             profiler.WATERFALL.record_summary(cls, summary, tenant=index)
             return
-        admission = transport.pop(trace.WF_ADMISSION, 0.0)
-        profiler.WATERFALL.extend(summary, {trace.WF_ADMISSION: admission})
+        so_far = (trace.WF_ADMISSION, trace.WF_HANDOFF_WAKE)
+        profiler.WATERFALL.extend(summary, {s: transport.pop(s, 0.0) for s in so_far})
         transport["_record"] = (cls, summary, index)
 
     def get_index(self, req) -> dict:
@@ -869,6 +871,7 @@ class Handler:
         started = getattr(srv, "started_at", None)
         if started:
             metrics.gauge(metrics.UPTIME_SECONDS, round(time.time() - started, 3))
+        metrics.gauge(metrics.PROCESS_CPU_SECONDS, time.process_time())
         slo.MONITOR.tick()
         text = metrics.render_prometheus(
             extra_snapshots=[self._expvar_snapshot()]

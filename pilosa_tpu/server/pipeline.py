@@ -126,6 +126,7 @@ class _Entry:
         "result",
         "error",
         "t_enq",
+        "t_done",
         "trace_ctx",
         "index",
         "seq",
@@ -150,6 +151,7 @@ class _Entry:
         self.result: Any = None
         self.error: Optional[BaseException] = None
         self.t_enq = 0.0
+        self.t_done = 0.0
         # distributed trace context (utils/trace.py tuple): carried so
         # a coalesced follower can link the leader's trace
         self.trace_ctx = trace_ctx
@@ -479,6 +481,10 @@ class QueryPipeline:
                 if rem <= 0:
                     dl.check("admission")  # raises (and counts)
                 entry.event.wait(timeout=min(rem, 0.5))
+        # the last hand-back: the worker's finishing stamp → this thread
+        # running again. Outside api.query's total: it goes to the
+        # transport's waterfall and joins the summary as admission does
+        trace.book(trace.WF_HANDOFF_WAKE, time.monotonic() - entry.t_done)
         if entry.error is not None:
             raise entry.error
         return entry.result
@@ -540,6 +546,7 @@ class QueryPipeline:
             with self._mu:
                 if self._inflight.get(e.signature) is e:
                     del self._inflight[e.signature]
+        e.t_done = time.monotonic()
         e.event.set()
 
     # -- lifecycle -----------------------------------------------------------

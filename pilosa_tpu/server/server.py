@@ -149,8 +149,7 @@ class Server:
         if self.exporter is not None:
             events.JOURNAL.on_record = self.exporter.tap_event
             trace.TRACER.on_export = self.exporter.tap_span
-        # only hook gc.callbacks when someone consumes the counter
-        self.gc_notifier = GCNotifier() if self.stats is not NOP_STATS else None
+        self.gc_notifier: Optional[GCNotifier] = None  # hooked while open
         self.holder = Holder(
             data_dir,
             new_attr_store=new_attr_store,
@@ -607,6 +606,8 @@ class Server:
         self.started_at = time.time()
         metrics.gauge(metrics.PROCESS_START_TIME_SECONDS, round(self.started_at, 3))
         metrics.gauge(metrics.UPTIME_SECONDS, 0.0)
+        # the collector's pauses, timed into the metric registry
+        self.gc_notifier = GCNotifier()
         slo.MONITOR.configure(
             objectives=slo.parse_objectives(self.config.slo_objectives),
             burn_threshold=self.config.slo_burn_threshold,
@@ -884,6 +885,22 @@ class Server:
         except (ImportError, ValueError, OSError) as e:
             self.logger.printf("could not raise file limit: %s", e)
 
+    def flush_caches(self) -> None:
+        """One pass of the cache-flush loop. It runs under the
+        interpreter's lock beside the requests, so its seconds
+        (``holder.cache_flush_seconds``) say what a slow minute held."""
+        t0 = time.monotonic()
+        try:
+            for idx in list(self.holder.indexes.values()):
+                for fld in list(idx.fields.values()):
+                    for view in list(fld.views.values()):
+                        for frag in list(view.fragments.values()):
+                            if frag._open:
+                                frag.flush_cache()
+        except Exception as e:
+            self.logger.printf("cache flush error: %s", e)
+        metrics.observe(metrics.CACHE_FLUSH_SECONDS, time.monotonic() - t0)
+
     def _start_background_loops(self) -> None:
         """reference server.go: monitorAntiEntropy:400, monitorRuntime:683,
         monitorDiagnostics:633."""
@@ -897,15 +914,7 @@ class Server:
             if interval <= 0:
                 return
             while not self._closed.wait(interval):
-                try:
-                    for idx in list(self.holder.indexes.values()):
-                        for fld in list(idx.fields.values()):
-                            for view in list(fld.views.values()):
-                                for frag in list(view.fragments.values()):
-                                    if frag._open:
-                                        frag.flush_cache()
-                except Exception as e:
-                    self.logger.printf("cache flush error: %s", e)
+                self.flush_caches()
 
         def anti_entropy_loop():
             interval = self.config.anti_entropy_interval
@@ -951,12 +960,6 @@ class Server:
                     self.stats.gauge(metrics.THREADS, threading.active_count())
                     counts = gc.get_count()
                     self.stats.gauge(metrics.GC_GEN0, counts[0])
-                    cycles = (
-                        self.gc_notifier.poll() if self.gc_notifier else 0
-                    )
-                    if cycles:
-                        # reference server.go:702-704 via gcnotify
-                        self.stats.count(metrics.GARBAGE_COLLECTION, cycles)
                     self.stats.gauge(metrics.OPEN_FRAGMENTS, self._count_fragments())
                 except Exception:
                     pass

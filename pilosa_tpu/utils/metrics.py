@@ -85,6 +85,7 @@ EXECUTOR_DEVICE_DOWN_FALLBACK = "executor.device_down_fallback"
 EXECUTOR_NOT_DEVICEABLE = "executor.not_deviceable"
 SPMD_COMPILE_SECONDS = "spmd.compile_seconds"
 SPMD_EXECUTE_SECONDS = "spmd.execute_seconds"
+SPMD_LAUNCH_SECONDS = "spmd.launch_seconds"
 # batched scorers
 BATCHER_DISPATCHES = "batcher.dispatches"
 BATCHER_BATCH_SIZE = "batcher.batch_size"
@@ -276,6 +277,11 @@ SLO_BUDGET_REMAINING = "slo.budget_remaining"
 SLO_BURNS = "slo.burns"
 UPTIME_SECONDS = "uptime_seconds"
 PROCESS_START_TIME_SECONDS = "process_start_time_seconds"
+# the process, on no request's path
+PROCESS_CPU_SECONDS = "process.cpu_seconds"
+GC_PAUSE_SECONDS = "runtime.gc_pause_seconds"
+GARBAGE_COLLECTION = "garbage_collection"
+CACHE_FLUSH_SECONDS = "holder.cache_flush_seconds"
 # server-level (emitted through the server's expvar/statsd stats client;
 # merged into /metrics from the expvar snapshot)
 QUERY_TIME = "query_time"
@@ -283,7 +289,6 @@ SLOW_QUERY = "slow_query"
 MAX_RSS_KB = "maxRSSKB"
 THREADS = "threads"
 GC_GEN0 = "gcGen0"
-GARBAGE_COLLECTION = "garbage_collection"
 OPEN_FRAGMENTS = "openFragments"
 ANTI_ENTROPY_SECONDS = "antiEntropyDurationSeconds"
 ANTI_ENTROPY_ERRORS = "antiEntropyErrors"
@@ -316,6 +321,12 @@ METRICS: dict[str, tuple[str, str]] = {
         "summary",
         "launch → result ready of a warm compiled kernel, by kernel name; "
         "a batched scorer's launches count to the fetch (label: kind)",
+    ),
+    SPMD_LAUNCH_SECONDS: (
+        "summary",
+        "the launch alone of a warm compiled kernel: the jit call up to its "
+        "return of the not-yet-ready result, operand bytes counted "
+        "(waterfall stage device.launch; label: kind)",
     ),
     BATCHER_DISPATCHES: (
         "counter",
@@ -1004,12 +1015,27 @@ METRICS: dict[str, tuple[str, str]] = {
         "gauge",
         "unix timestamp at which this process's server opened",
     ),
+    PROCESS_CPU_SECONDS: (
+        "gauge",
+        "CPU seconds of this process, user and system, every thread "
+        "(time.process_time(); refreshed at scrape time)",
+    ),
+    GC_PAUSE_SECONDS: (
+        "summary",
+        "one collection of the interpreter's cyclic collector, start → stop, "
+        "which holds every thread of the process (label: generation)",
+    ),
+    GARBAGE_COLLECTION: ("counter", "completed gc collection cycles"),
+    CACHE_FLUSH_SECONDS: (
+        "summary",
+        "one pass of the ranked-cache flush loop over every open fragment "
+        "(cache-flush-interval), on its own thread under the interpreter's lock",
+    ),
     QUERY_TIME: ("summary", "whole-query wall time, server-level (label: index)"),
     SLOW_QUERY: ("counter", "queries slower than cluster.long-query-time"),
     MAX_RSS_KB: ("gauge", "process max RSS in KB"),
     THREADS: ("gauge", "live Python threads"),
     GC_GEN0: ("gauge", "gc generation-0 object count"),
-    GARBAGE_COLLECTION: ("counter", "completed gc collection cycles"),
     OPEN_FRAGMENTS: ("gauge", "fragments currently open in the holder"),
     ANTI_ENTROPY_SECONDS: ("summary", "anti-entropy sweep duration"),
     ANTI_ENTROPY_ERRORS: (
@@ -1108,6 +1134,18 @@ class Registry:
             if h is None:
                 h = self._hists[k] = LogHistogram()
             h.observe(value)
+
+    def try_observe(self, name: str, value: float, **labels) -> bool:
+        """``observe`` for a gc callback. A collection starts between any
+        two bytecodes, also on a thread that is inside this lock, where
+        waiting for it would never end: False, and nothing recorded,
+        where the lock is taken."""
+        if not self._mu.acquire(blocking=False):
+            return False
+        self._mu.release()
+        # free a moment ago, so not held by this thread: waiting is safe
+        self.observe(name, value, **labels)
+        return True
 
     def snapshot(self) -> dict:
         """JSON-safe flat snapshot: ``name[;k:v,...]`` -> number or

@@ -58,6 +58,7 @@ class WaterfallAggregator:
 
     # buckets that count as device-side for rtt_fraction
     DEVICE_STAGES = (
+        trace.WF_DEVICE_LAUNCH,
         trace.WF_DEVICE_COMPUTE,
         trace.WF_TRANSFER_DECODE,
         trace.WF_MESH_FETCH,

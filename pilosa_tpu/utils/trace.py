@@ -530,11 +530,13 @@ WF_WAVE_MATES = "dispatch.wave_mates"
 WF_GUARD_QUEUE = "guard.queue"
 WF_TOPN_CANDIDATES = "topn.candidates"
 WF_FILTER_EVAL = "filter.eval"
+WF_DEVICE_LAUNCH = "device.launch"
 WF_DEVICE_COMPUTE = "device.compute"
 WF_TRANSFER_DECODE = "transfer.decode"
 WF_MESH_FETCH = "mesh.fetch"
 WF_TOPN_WALK = "topn.walk"
 WF_REDUCE = "reduce"
+WF_HANDOFF_WAKE = "handoff.wake"
 WF_RESPOND = "respond"
 WF_OTHER = "other"
 
@@ -550,11 +552,13 @@ WATERFALL_STAGES: tuple = (
     WF_GUARD_QUEUE,
     WF_TOPN_CANDIDATES,
     WF_FILTER_EVAL,
+    WF_DEVICE_LAUNCH,
     WF_DEVICE_COMPUTE,
     WF_TRANSFER_DECODE,
     WF_MESH_FETCH,
     WF_TOPN_WALK,
     WF_REDUCE,
+    WF_HANDOFF_WAKE,
     WF_RESPOND,
     WF_OTHER,
 )
@@ -570,39 +574,16 @@ WATERFALL: dict = {
     WF_GUARD_QUEUE: "device-guard pool: wait for a worker to pick the call up",
     WF_TOPN_CANDIDATES: "TopN ranked-cache snapshot and candidate chunk assembly",
     WF_FILTER_EVAL: "a call's filter lowered on the host: to structure and staged leaves for a program that traces it, or to one shard stack by Range launches and eager boolean ops",
-    WF_DEVICE_COMPUTE: "host's wait on the device (launch → result ready)",
+    WF_DEVICE_LAUNCH: "the call of a compiled program up to its return of the not-yet-ready result: operands flattened and handed to every device, their bytes counted (a first call's compile too)",
+    WF_DEVICE_COMPUTE: "host's wait for a launched program's result, the launch apart",
     WF_TRANSFER_DECODE: "device→host copy and result decode",
     WF_MESH_FETCH: "mesh: copy of a mesh kernel's replicated result (a TopN chunk's gathered scores, a Sum's or Count's reduced counts) from one replica",
     WF_TOPN_WALK: "TopN ranked walk, cross-shard merge, sort, pass-2 trim",
     WF_REDUCE: "host-side shard-result reduction",
+    WF_HANDOFF_WAKE: "hand-backs: a worker thread's finishing stamp → the thread that waited for it running again (guard pool → wave, wave → pipeline worker, pipeline worker → handler)",
     WF_RESPOND: "results → JSON bytes → last write",
     WF_OTHER: "unattributed host time (total − measured legs)",
 }
-
-# span-stage → waterfall-bucket mapping. Every key of metrics.STAGES
-# must appear here (tests/test_profiling.py enforces completeness both
-# ways), so a new span stage can't silently fall outside the taxonomy.
-WATERFALL_OF: dict = {
-    "query": WF_OTHER,
-    "pipeline.wait": WF_PIPELINE_QUEUE,
-    "pipeline.coalesce": WF_PIPELINE_QUEUE,
-    "plan.canon": WF_PLAN_CANON,
-    "executor": WF_OTHER,
-    "executor.call": WF_OTHER,
-    "executor.map_shard": WF_OTHER,
-    "executor.route": WF_OTHER,
-    "executor.device_batch": WF_DEVICE_COMPUTE,
-    "spmd.kernel": WF_DEVICE_COMPUTE,
-    "batcher.score": WF_DEVICE_COMPUTE,
-    "stager.stage": WF_STAGER,
-    "stager.delta_apply": WF_STAGER,
-    "dispatch.dedup": WF_DISPATCH_QUEUE,
-    "cluster.map_remote": WF_OTHER,
-    "cluster.map_local": WF_OTHER,
-    "multihost.gang": WF_DEVICE_COMPUTE,
-    "multihost.replay": WF_OTHER,
-}
-
 
 # Per-request attribution accumulator: a plain ``{bucket: seconds}``
 # dict in a contextvar. Always-on for served queries (api.query installs
@@ -659,6 +640,8 @@ def attrib_activate(d: Optional[dict]) -> _AttribActivation:
 # req=<request id>)`` in the profiler's host plane, on the thread that
 # did the work: legs and device ops then share one clock. The request
 # id rides in the attribution dict (``_req``, like ``_wave``).
+# ``trace.book(stage, seconds)`` credits an interval that no ``with``
+# on the crediting thread spans (it began at another thread's stamp).
 #
 # Cost with no capture running: two clock reads, one contextvar get, one
 # global test, one thread-local read and write; jax is never imported.
@@ -728,6 +711,22 @@ class leg:
         if d is not None:
             d[self.stage] = d.get(self.stage, 0.0) + max(0.0, dt - self._inner)
         return False
+
+
+def book(stage: str, seconds: float) -> None:
+    """Credit to ``stage`` an interval that ended now on this thread and
+    began at another thread's stamp, where no ``with`` could have opened
+    it: a queue's own stamps, a worker's finishing stamp → the waiter
+    running again. A leg open on this thread gives the seconds up, as to
+    a nested leg; no annotation (nothing of the request ran in them)."""
+    if seconds <= 0.0:
+        return
+    parent = getattr(_open_leg, "leg", None)
+    if parent is not None:
+        parent._inner += seconds
+    d = _attrib.get()
+    if d is not None:
+        d[stage] = d.get(stage, 0.0) + seconds
 
 
 # -- dispatch wave id ---------------------------------------------------------

@@ -5,6 +5,7 @@ enterprise/b)."""
 import gc
 import random
 import socket
+import time
 
 import numpy as np
 import pytest
@@ -22,6 +23,7 @@ from pilosa_tpu.roaring import (
     get_default_container_store,
     set_default_container_store,
 )
+from pilosa_tpu.utils import metrics
 from pilosa_tpu.utils.gcnotify import GCNotifier
 from pilosa_tpu.utils.stats import StatsDClient
 
@@ -167,17 +169,62 @@ def test_multi_stats_snapshot_keeps_expvar_lit():
 # -- gcnotify --------------------------------------------------------------
 
 
-def test_gcnotifier_counts_cycles():
+def _gc_pauses(generation: int) -> tuple:
+    snap = metrics.snapshot()
+    h = snap.get(f"{metrics.GC_PAUSE_SECONDS}.hist;generation:{generation}")
+    return (h["count"], h["sum"]) if h else (0, 0.0)
+
+
+@pytest.mark.parametrize("generation", [0, 1, 2])
+def test_gcnotifier_books_pauses_by_generation_and_unhooks(generation):
+    """Each collection is one observation of start → stop under its
+    generation and one ``garbage_collection``, in the metric registry;
+    ``close()`` takes the callback out."""
+    was = gc.isenabled()
+    gc.disable()  # only the collections asked for below
     n = GCNotifier()
     try:
-        gc.collect()
-        gc.collect()
-        assert n.poll() >= 2
-        assert n.poll() == 0  # poll resets
+        count, seconds = _gc_pauses(generation)
+        cycles = metrics.snapshot().get(metrics.GARBAGE_COLLECTION, 0)
+        t0 = time.monotonic()
+        gc.collect(generation)
+        gc.collect(generation)
+        spent = time.monotonic() - t0
+        count2, seconds2 = _gc_pauses(generation)
+        assert count2 == count + 2
+        assert 0.0 < seconds2 - seconds <= spent
+        assert metrics.snapshot()[metrics.GARBAGE_COLLECTION] == cycles + 2
+        assert n._on_gc in gc.callbacks
     finally:
         n.close()
-    gc.collect()
-    assert n.poll() == 0  # closed → no longer counting
+        n.close()  # idempotent
+        if was:
+            gc.enable()
+    assert n._on_gc not in gc.callbacks
+    gc.collect(generation)
+    assert _gc_pauses(generation)[0] == count + 2  # closed: no longer timing
+
+
+def test_gcnotifier_never_waits_for_the_registry_lock_it_may_be_inside():
+    """A collection starts between any two bytecodes, also on a thread
+    that holds the registry's lock: the callback must not wait for it
+    (that would never end). The pause is kept and booked with the next
+    collection."""
+    was = gc.isenabled()
+    gc.disable()
+    n = GCNotifier()
+    try:
+        count, _ = _gc_pauses(2)
+        with metrics.REGISTRY._mu:
+            assert not metrics.REGISTRY.try_observe(metrics.GC_PAUSE_SECONDS, 1.0, generation=2)
+            gc.collect()  # returns: the callback found the lock taken
+        assert _gc_pauses(2)[0] == count
+        gc.collect()
+        assert _gc_pauses(2)[0] == count + 2
+    finally:
+        n.close()
+        if was:
+            gc.enable()
 
 
 # -- iterators (reference iterator.go) -------------------------------------

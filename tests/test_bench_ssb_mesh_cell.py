@@ -409,7 +409,11 @@ def test_the_manifest_names_the_cell_with_four_chips_and_the_lists():
     assert all(w in entry["source"] for w in ("configs[4]", "SF=100", "Star Schema Benchmark", "flight 1", "SF=20"))
     assert manifest["workloads"][-1] is cell and manifest["configs"][-1] is entry
     assert len(manifest["workloads"]) == 5 and sum(w["chips"] == 4 for w in manifest["workloads"]) == 2
-    new = manifest["per_layer"][-1]
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    new = by_name["executor.fusion_mesh_bypasses_per_query"]
+    # appended behind ISSUE 34's, which is the last of the five that list this cell
+    at = manifest["per_layer"].index(new)
+    assert manifest["per_layer"][at - 1]["name"] == "executor.filter_inlined_per_query"
     assert new == {
         "name": "executor.fusion_mesh_bypasses_per_query", "unit": "count/query", "better": "lower",
         "source": "program_counter", "layer": "executor routing", "moves": "query_p50_ms", "workloads": [CELL],
@@ -417,7 +421,6 @@ def test_the_manifest_names_the_cell_with_four_chips_and_the_lists():
     spec = run.layer_metrics.load(new["name"])
     assert spec["numerator"] == [{"metric": "fusion.bypasses", "labels": {"reason": "mesh"}}]
     assert (spec["per"], spec["scale"]) == ("request", 1) and "fusion.bypasses" in metrics.METRICS
-    by_name = {m["name"]: m for m in manifest["per_layer"]}
     assert by_name["executor.fallbacks"]["layer"] == new["layer"]
     accepted = ["tall64.topn", "taxi96.dashboard", "tall128x4.topn", "ssb10.flight1"]
     # one chip's peak whatever ran: it would read four times the share here

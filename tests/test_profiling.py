@@ -93,17 +93,34 @@ def _seed(server, index="pf"):
 
 
 def test_waterfall_taxonomy_covers_every_span_stage():
-    """Every span stage the tracer can record maps into a waterfall
-    bucket, and the mapping names only real buckets — a new stage can't
-    silently fall outside the attribution taxonomy (and the mapping
-    can't rot to stages that no longer exist)."""
-    span_stages = set(metrics.STAGES)
-    mapped = set(trace.WATERFALL_OF)
-    assert span_stages - mapped == set(), "span stages missing a bucket"
-    assert mapped - span_stages == set(), "mapping names unknown span stages"
-    assert set(trace.WATERFALL_OF.values()) <= set(trace.WATERFALL_STAGES)
-    # every bucket is documented for /debug/latency
-    assert set(trace.WATERFALL) == set(trace.WATERFALL_STAGES)
+    """The taxonomy's tables say the same thing: every stage has a
+    ``WF_`` name of its own, is listed once in display order (the
+    synthetic ``other`` last) and is documented for /debug/latency; the
+    device-side stages of ``rtt_fraction`` are stages, the launch and
+    the wait both (one leg became two: the gauge must not move), no
+    host stage among them. (Until ISSUE 37 it held a span-stage →
+    bucket mapping, which nothing read once the timers were folded into
+    ``leg``, to the span stages; the mapping went.)"""
+    stages = trace.WATERFALL_STAGES
+    assert len(set(stages)) == len(stages) and stages[-1] == trace.WF_OTHER
+    named = {v for k, v in vars(trace).items() if k.startswith("WF_")}
+    assert named == set(stages), named ^ set(stages)
+    assert set(trace.WATERFALL) == set(stages)
+    assert all(isinstance(d, str) and d for d in trace.WATERFALL.values())
+    device = WaterfallAggregator.DEVICE_STAGES
+    assert len(set(device)) == len(device) and set(device) < set(stages)
+    assert set(device) == {
+        trace.WF_DEVICE_LAUNCH,
+        trace.WF_DEVICE_COMPUTE,
+        trace.WF_TRANSFER_DECODE,
+        trace.WF_MESH_FETCH,
+    }
+    # launch + wait read what the one leg read
+    both = WaterfallAggregator.summarize(
+        {trace.WF_DEVICE_LAUNCH: 0.002, trace.WF_DEVICE_COMPUTE: 0.003}, 0.010
+    )
+    one = WaterfallAggregator.summarize({trace.WF_DEVICE_COMPUTE: 0.005}, 0.010)
+    assert both["rtt_fraction"] == one["rtt_fraction"] == 0.5
 
 
 # -- attribution layer --------------------------------------------------------
@@ -424,6 +441,42 @@ def test_query_profile_waterfall_param(server):
     assert st == 200 and "profile" not in body and "_waterfall" not in body
 
 
+def test_served_query_splits_into_stages_with_the_launch_and_the_wake_ups(server):
+    """A device read over HTTP: ``device.launch`` (the jit call) apart
+    from ``device.compute`` (the wait), ``handoff.wake`` (the three
+    hand-backs, the handler's joined from outside ``api.query``'s
+    total), and the stages with ``other`` still partition the total, in
+    the response and in the ring."""
+    from pilosa_tpu import SHARD_WIDTH
+
+    _seed(server, index="hb")
+    for sh in range(3):
+        req(server, "POST", "/index/hb/query", b"Set(%d, f=1)" % (sh * SHARD_WIDTH + 5))
+    q = b"Count(Row(f=1))"
+    req(server, "POST", "/index/hb/query?cache=false", q)  # stage and compile
+    st, body = req(server, "POST", "/index/hb/query?profile=waterfall", q)
+    assert st == 200 and body["results"] == [4]
+    wf = body["profile"]["waterfall"]
+    for stage in (trace.WF_DEVICE_LAUNCH, trace.WF_DEVICE_COMPUTE, trace.WF_HANDOFF_WAKE):
+        assert wf["stages"].get(stage, 0.0) > 0.0, (stage, wf)
+    assert set(wf["stages"]) <= set(trace.WATERFALL_STAGES)
+    assert abs(sum(wf["stages"].values()) - wf["total_ms"]) < 0.001 * (len(wf["stages"]) + 1)
+    st, lat = req(server, "GET", "/debug/latency?limit=1")
+    recent = lat["recent"][-1]
+    assert recent["stages"][trace.WF_HANDOFF_WAKE] >= wf["stages"][trace.WF_HANDOFF_WAKE]
+    assert trace.WF_RESPOND in recent["stages"]
+    assert abs(sum(recent["stages"].values()) - recent["total_ms"]) < 0.001 * (
+        len(recent["stages"]) + 1
+    )
+    # the kinds' launches, beside launch → ready
+    ms = metrics.snapshot()
+    launched = {k for k in ms if k.startswith(metrics.SPMD_LAUNCH_SECONDS + ".hist")}
+    assert launched and all(
+        ms[k]["sum"] <= ms[k.replace(metrics.SPMD_LAUNCH_SECONDS, metrics.SPMD_EXECUTE_SECONDS)]["sum"]
+        for k in launched
+    )
+
+
 def test_debug_latency_endpoint(server):
     _seed(server, index="lat")
     for _ in range(3):
@@ -510,6 +563,43 @@ def test_uptime_and_start_time_gauges(server):
     }
     assert lines["pilosa_uptime_seconds"] >= 0.0
     assert abs(lines["pilosa_process_start_time_seconds"] - time.time()) < 600
+
+
+def _sample(server, name: str) -> float:
+    st, raw = req(server, "GET", "/metrics", raw=True)
+    assert st == 200
+    values = [
+        float(l.rsplit(" ", 1)[1])
+        for l in raw.decode().splitlines()
+        if l.startswith(name + " ") or l.startswith(name + "{")
+    ]
+    assert values, f"{name} missing from /metrics"
+    return sum(values)
+
+
+def test_process_cpu_seconds_gauge_grows_with_the_cpu_spent(server):
+    """``process.cpu_seconds`` is ``time.process_time()`` at the scrape:
+    what the process burned between two scrapes is their difference."""
+    before = _sample(server, "pilosa_process_cpu_seconds")
+    assert 0.0 < before <= time.process_time()
+    t0 = time.process_time()
+    while time.process_time() - t0 < 0.05:  # burn, on the CPU's own clock
+        sum(range(1000))
+    after = _sample(server, "pilosa_process_cpu_seconds")
+    assert after - before >= 0.05
+    assert after <= time.process_time()
+
+
+def test_one_cache_flush_pass_is_one_observation(server):
+    _seed(server, index="fl")
+    key = metrics.CACHE_FLUSH_SECONDS + ".hist"
+    before = metrics.snapshot().get(key, {"count": 0, "sum": 0.0})
+    server.flush_caches()
+    server.flush_caches()
+    after = metrics.snapshot()[key]
+    assert after["count"] == before["count"] + 2
+    assert after["sum"] > before["sum"]
+    assert _sample(server, "pilosa_holder_cache_flush_seconds_count") == after["count"]
 
 
 def test_fleet_scrape_carries_profile_and_slo_samples(server):

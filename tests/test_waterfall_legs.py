@@ -2,23 +2,28 @@
 across the device-guard thread, the capture's options, compiles counted
 wherever they happen, the batched scorers' kernel accounting, and the
 benchmark's per-layer metric files against the names the program
-publishes."""
+publishes. ISSUE 37: the launch apart from the wait at the three sites
+that launch, and the three hand-backs booked from the worker's stamp."""
 
 import glob
+import itertools
 import json
 import os
 import sys
 import threading
 import time
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from pilosa_tpu import SHARD_WIDTH
 from pilosa_tpu.core import Holder
-from pilosa_tpu.executor import Executor
+from pilosa_tpu.executor import Executor, batcher, devicehealth, dispatch
+from pilosa_tpu.executor import executor as executor_mod
 from pilosa_tpu.executor.batcher import BatchedScorer
 from pilosa_tpu.executor.devicehealth import DeviceHealth
+from pilosa_tpu.server import pipeline
 from pilosa_tpu.utils import metrics, profiler, trace
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
@@ -32,6 +37,14 @@ def _metric(name: str, **labels) -> float:
         return snap[key]
     hist = snap.get(metrics._flat_key(name + ".hist", metrics._labels_key(labels)))
     return hist["count"] if hist else 0
+
+
+def _seconds(name: str, **labels) -> float:
+    """A summary's sum from the registry."""
+    hist = metrics.snapshot().get(
+        metrics._flat_key(name + ".hist", metrics._labels_key(labels))
+    )
+    return hist["sum"] if hist else 0.0
 
 
 # -- the primitive ------------------------------------------------------------
@@ -93,6 +106,24 @@ def test_guard_queue_ends_when_the_worker_picks_the_call_up():
         assert wf[trace.WF_GUARD_QUEUE] + wf[trace.WF_TOPN_WALK] <= total
     finally:
         health.close()
+
+
+def test_book_credits_an_interval_that_began_on_another_threads_clock():
+    """``trace.book``: the seconds join the stage, an open leg of this
+    thread gives them up as to a nested leg, nothing is booked without
+    a request or for a stamp that lies ahead."""
+    wf: dict = {}
+    with trace.attrib_activate(wf):
+        trace.book(trace.WF_HANDOFF_WAKE, 0.004)
+        trace.book(trace.WF_HANDOFF_WAKE, 0.001)
+        trace.book(trace.WF_HANDOFF_WAKE, -0.5)  # the clocks' jitter, not a wake-up
+        with trace.leg(trace.WF_REDUCE) as outer:
+            trace.book(trace.WF_DISPATCH_QUEUE, 0.25)
+    assert wf[trace.WF_HANDOFF_WAKE] == pytest.approx(0.005)
+    assert wf[trace.WF_DISPATCH_QUEUE] == 0.25
+    assert wf[trace.WF_REDUCE] == max(0.0, outer.seconds - 0.25)
+    trace.book(trace.WF_HANDOFF_WAKE, 1.0)  # no request: nothing to credit
+    assert trace.attrib_current() is None and wf[trace.WF_HANDOFF_WAKE] == pytest.approx(0.005)
 
 
 def test_leg_without_attribution_is_a_timer_only():
@@ -280,6 +311,192 @@ def test_guarded_read_on_a_mesh_books_the_replicated_results_copy_as_mesh_fetch(
         assert trace.WF_MESH_FETCH in profiler.WaterfallAggregator.DEVICE_STAGES
 
 
+# -- the launch apart from the wait (ISSUE 37) --------------------------------
+
+
+@pytest.mark.parametrize("site", ["timed_kernel", "launch", "batched_scorer"])
+def test_launch_leg_nests_in_device_compute_and_leaves_launch_to_ready_whole(monkeypatch, site):
+    """At each of the three sites that launch, the jit call is
+    ``device.launch`` inside ``device.compute``: a second is credited
+    once (the two stages sum to the outer leg), the outer leg's
+    ``seconds`` still feed ``spmd.execute_seconds`` whole, the inner
+    one's feed ``spmd.launch_seconds``, the operands are counted inside
+    the launch, and while a capture runs the launch is an annotation
+    with the request's id like every other leg. On a clock that ticks
+    once a reading, so every interval is a whole number."""
+    ticks = itertools.count(1)
+    clock = SimpleNamespace(monotonic=lambda: float(next(ticks)))
+    monkeypatch.setattr(trace, "time", clock)
+    monkeypatch.setattr(batcher, "time", clock)
+    kind = f"probe_{site}"
+    operand = np.arange(16, dtype=np.uint32)
+    counted = []
+    monkeypatch.setattr(
+        profiler, "count_operands",
+        lambda k, ops_: counted.append((k, getattr(trace._open_leg, "leg", None).stage)),
+    )
+    if site == "timed_kernel":
+        kernel = executor_mod._timed_kernel(kind, lambda a: a + 1)
+        kernel(operand)  # the first call is the compile's: spmd.compile_seconds
+        assert _metric(metrics.SPMD_LAUNCH_SECONDS, kind=kind) == 0
+
+        def call():
+            return kernel(operand)
+    elif site == "launch":
+        def call():
+            return executor_mod._launch(kind, lambda a: a + 1, operand)
+    else:
+        scorer = BatchedScorer(single_fn=lambda src, mat: mat + src, kind=kind)
+
+        def call():
+            return scorer.score(("k", id(operand)), operand, np.uint32(1))
+
+    made = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            made.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace, "_annotation", Annotation)
+    counted.clear()
+    ready0 = _seconds(metrics.SPMD_EXECUTE_SECONDS, kind=kind)
+    launch0 = _seconds(metrics.SPMD_LAUNCH_SECONDS, kind=kind)
+    slot0 = _seconds(metrics.BATCHER_SLOT_WAIT_SECONDS)
+    wf: dict = {"_req": 41}
+    with trace.attrib_activate(wf):
+        out = call()
+    np.testing.assert_array_equal(out, operand + 1)
+    launch = _seconds(metrics.SPMD_LAUNCH_SECONDS, kind=kind) - launch0
+    ready = _seconds(metrics.SPMD_EXECUTE_SECONDS, kind=kind) - ready0
+    assert wf[trace.WF_DEVICE_LAUNCH] == launch == 1.0
+    assert ready > launch  # launch to ready holds the launch, whole
+    if site == "batched_scorer":
+        # the outer leg is the slot's (enqueue → result); launch → fetched lies in it
+        whole = _seconds(metrics.BATCHER_SLOT_WAIT_SECONDS) - slot0
+        assert ready < whole
+    else:
+        whole = ready  # the outer leg's own seconds
+    assert wf[trace.WF_DEVICE_LAUNCH] + wf[trace.WF_DEVICE_COMPUTE] == whole
+    assert set(wf) == {"_req", trace.WF_DEVICE_LAUNCH, trace.WF_DEVICE_COMPUTE}
+    assert counted == [(kind, trace.WF_DEVICE_LAUNCH)]
+    assert made == [
+        (trace.WF_DEVICE_COMPUTE, {"req": 41}),
+        (trace.WF_DEVICE_LAUNCH, {"req": 41}),
+    ]
+
+
+# -- the hand-backs (ISSUE 37) ------------------------------------------------
+
+
+class _Stamps:
+    """The real clock, every reading kept with the thread that took it."""
+
+    def __init__(self):
+        self.by_thread: dict = {}
+
+    def monotonic(self) -> float:
+        t = time.monotonic()
+        self.by_thread.setdefault(threading.get_ident(), []).append(t)
+        return t
+
+    def last(self, ident: int) -> float:
+        return self.by_thread[ident][-1]
+
+
+@pytest.mark.parametrize("hop", ["guard", "dispatch", "pipeline"])
+def test_handoff_wake_runs_from_the_workers_finishing_stamp_to_the_waiter(monkeypatch, hop):
+    """Each of the three hops a result comes back over books
+    ``handoff.wake``: the last reading of the clock the worker took
+    before it handed over (its finishing stamp) → the waiter's reading
+    once it runs again, to the request's waterfall on the waiter's
+    thread, whatever the call's outcome."""
+    stamps = _Stamps()
+    worker: list[int] = []
+    me = threading.get_ident()
+
+    def work():
+        worker.append(threading.get_ident())
+        return "answer"
+
+    wf: dict = {}
+    if hop == "guard":
+        monkeypatch.setattr(devicehealth, "time", stamps)
+        health = DeviceHealth(timeout_s=30.0)
+        try:
+            with trace.attrib_activate(wf):
+                assert health.guard(work) == "answer"
+                first = wf[trace.WF_HANDOFF_WAKE]
+                assert first == stamps.last(me) - stamps.last(worker[0])
+
+                def broken():
+                    work()
+                    raise KeyError("the call's own error")
+
+                with pytest.raises(KeyError):
+                    health.guard(broken)
+            assert wf[trace.WF_HANDOFF_WAKE] == first + (
+                stamps.last(me) - stamps.last(worker[-1])
+            )
+        finally:
+            health.close()
+    elif hop == "dispatch":
+        monkeypatch.setattr(dispatch, "time", stamps)
+        from pilosa_tpu.pql import parse
+
+        item = dispatch._Item("i", parse("Count(Row(f=1))"), None, None, None, "sig")
+        t = threading.Thread(target=lambda: item.finish(result=[work()]))
+        with trace.attrib_activate(wf):
+            t.start()
+            assert item.result() == ["answer"]
+        t.join(timeout=10)
+        assert not t.is_alive()
+        assert item.t_done == stamps.last(worker[0])
+        assert wf[trace.WF_HANDOFF_WAKE] == stamps.last(me) - item.t_done
+    else:
+        monkeypatch.setattr(pipeline, "time", stamps)
+        pl = pipeline.QueryPipeline()
+        try:
+            with trace.attrib_activate(wf):  # the transport's waterfall
+                assert pl.submit(pipeline.CLASS_INTERACTIVE, work) == "answer"
+            woke = stamps.last(me) - stamps.last(worker[0])
+        finally:
+            assert pl.close()
+        assert wf[trace.WF_HANDOFF_WAKE] == woke
+    assert wf[trace.WF_HANDOFF_WAKE] > 0.0
+
+
+def test_the_handlers_wake_up_joins_the_summary_as_admission_does():
+    """The third hand-back lies outside ``api.query``'s total: the
+    transport's waterfall holds it and ``_record_waterfall`` adds it to
+    the summary, stage and total, with ``admission`` (so
+    ``profile=waterfall`` shows both); ``respond`` follows at the last
+    write; ``other`` is untouched."""
+    from pilosa_tpu.server.http_handler import Handler
+
+    summary = profiler.WATERFALL.summarize(
+        {trace.WF_REDUCE: 0.002, trace.WF_HANDOFF_WAKE: 0.001, "_req": 5}, 0.010
+    )
+    other = summary["stages"][trace.WF_OTHER]
+    transport = {"_req": 5, trace.WF_ADMISSION: 0.0005, trace.WF_HANDOFF_WAKE: 0.0015}
+    with trace.attrib_activate(transport):
+        Handler._record_waterfall("interactive", summary, "i")
+    assert transport == {"_req": 5, "_record": ("interactive", summary, "i")}
+    assert summary["stages"][trace.WF_HANDOFF_WAKE] == pytest.approx(2.5)  # both hops' sum
+    assert summary["stages"][trace.WF_ADMISSION] == pytest.approx(0.5)
+    assert summary["total_ms"] == pytest.approx(12.0)
+    assert summary["stages"][trace.WF_OTHER] == other
+    assert sum(summary["stages"].values()) == pytest.approx(summary["total_ms"])
+    assert list(summary["stages"]) == [
+        n for n in trace.WATERFALL_STAGES if n in summary["stages"]
+    ]
+
+
 # -- the capture --------------------------------------------------------------
 
 
@@ -413,3 +630,41 @@ def test_layer_metric_files_name_published_metrics_and_stages():
             if stage is not None:
                 assert base == metrics.LATENCY_STAGE_SECONDS
                 assert stage in trace.WATERFALL_STAGES, f"{spec['name']}: no stage {stage!r}"
+
+
+def test_the_manifest_ends_with_issue_37s_seven_metrics_and_each_is_a_data_file():
+    """Appended in the issue's order, every cell (no ``workloads``
+    list), each read from ``/metrics`` by a data file alone; the layer
+    ``process`` is new: the collector, the flush and the CPU belong to
+    no one layer of a request."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        manifest = json.load(fh)
+    stage = metrics.LATENCY_STAGE_SECONDS + "_sum"
+    want = [
+        ("executor.device_launch_ms", "ms", "program_span", "executor host side", "query_p50_ms",
+         stage, {"stage": trace.WF_DEVICE_LAUNCH}, 1000, "request"),
+        ("dispatch.handoff_wake_ms", "ms", "program_span", "dispatch", "query_p50_ms",
+         stage, {"stage": trace.WF_HANDOFF_WAKE}, 1000, "request"),
+        ("dispatch.wave_mates_ms", "ms", "program_span", "dispatch", "query_p50_ms",
+         stage, {"stage": trace.WF_WAVE_MATES}, 1000, "request"),
+        ("dispatch.guard_queue_ms", "ms", "program_span", "dispatch", "query_p95_ms",
+         stage, {"stage": trace.WF_GUARD_QUEUE}, 1000, "request"),
+        ("process.gc_pause_ms_per_query", "ms", "program_counter", "process", "query_p95_ms",
+         metrics.GC_PAUSE_SECONDS + "_sum", {}, 1000, "request"),
+        ("process.cpu_ms_per_query", "ms", "program_counter", "process", "queries_per_s",
+         metrics.PROCESS_CPU_SECONDS, {}, 1000, "request"),
+        ("holder.cache_flush_s_in_window", "s", "program_counter", "process", "query_p95_ms",
+         metrics.CACHE_FLUSH_SECONDS + "_sum", {}, 1, "window"),
+    ]
+    layers = {m["layer"] for m in manifest["per_layer"][:-7]}
+    for entry, (name, unit, source, layer, moves, metric, labels, scale, per) in zip(
+        manifest["per_layer"][-7:], want
+    ):
+        assert entry == {"name": name, "unit": unit, "better": "lower", "source": source,
+                         "layer": layer, "moves": moves}
+        assert layer in layers or layer == "process"
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as fh:
+            spec = json.load(fh)
+        assert spec == {"name": name, "source": "server_metrics", "scale": scale, "per": per,
+                        "numerator": [{"metric": metric, "labels": labels}]}
+    assert "process" not in layers
