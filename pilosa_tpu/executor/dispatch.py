@@ -11,15 +11,27 @@ scheduling): one persistent dispatch loop owns the device and admits
 whatever is queued into the next wave, so the device never idles
 between launches. This module is that loop for bitmap queries:
 
-* **Submit, don't block.** ``Executor.execute`` hands eligible local
-  reads to ``submit()`` and gets a future back; the calling thread
-  waits on the future instead of occupying the executor. Ineligible
-  work (writes, gang/multihost, cluster fan-out, remote legs, traced
-  queries, ``serial``) keeps the old inline path — the PR 5/6 gang
-  determinism contract holds because gang execution is ``serial`` and
-  never reaches the engine.
+* **Submit; lead when nobody is queued.** ``Executor.execute`` hands
+  eligible local reads to ``submit()`` and gets a future back. Who
+  runs the wave follows from two things the engine sees under its own
+  lock. With the queue empty and a runner slot free the submitter
+  *leads*: it takes the slot and runs a wave of its one item on its
+  own thread, so the future is resolved when ``submit()`` returns and
+  the request crossed no thread (the loop's wake-up, a wave thread's
+  start and the hand-back were a millisecond of every request that no
+  other request waited beside). With every slot taken, or somebody
+  already queued (FIFO: nobody overtakes), the item queues and the
+  loop *hands* the backlog to a wave thread; the caller waits on the
+  future. Both enter one code path (``_begin_wave_locked`` →
+  ``_run_wave_slot``); ``dispatch.waves{how=led|handed}`` counts
+  which. Ineligible work (writes, gang/multihost, cluster fan-out,
+  remote legs, traced queries, ``serial``) keeps the old inline path —
+  the PR 5/6 gang determinism contract holds because gang execution
+  is ``serial`` and never reaches the engine.
 * **Heterogeneous waves.** The loop drains up to ``max_wave`` queued
-  items per wave. Within a wave, items group by execution context
+  items per wave: backlog alone widens a wave, and backlog forms only
+  while every slot computes (a led wave holds a slot like any other).
+  Within a wave, items group by execution context
   (index, shard set, exec-opt bits) and dedup by canonical plan
   signature (plan/canon.py) — wave-level singleflight, so duplicate
   plans (including argument-order permutations) execute once and share
@@ -30,26 +42,33 @@ between launches. This module is that loop for bitmap queries:
   batched launches — generalizing both the pipeline's identical-query
   gangs and the scorer's homogeneous micro-batches.
 * **Overlap.** ``max_inflight`` waves execute concurrently (double /
-  triple buffering at the serving layer): while wave N computes, the
-  loop is already building wave N+1 and firing advisory stage-ahead
-  warms (``stager.stage_ahead``) so operand uploads overlap kernel
-  execution, and wave N−1's waiters consume results as each runner
-  finishes.
+  triple buffering at the serving layer), led on their submitters'
+  threads or handed to wave threads: while the slots compute, the
+  loop is already building the next handed wave out of what queued
+  meanwhile and firing advisory stage-ahead warms
+  (``stager.stage_ahead``) so operand uploads overlap kernel
+  execution, and earlier waves' waiters consume results as each
+  runner finishes.
 * **Deadlines.** Items whose deadline expired while queued are
   cancelled at wave build — before any parse/translate/kernel work —
-  and their wave-mates are unaffected; a combined execution that fails
-  (one bad member, a deadline, anything) falls back to per-item
+  and their wave-mates are unaffected (a led item is checked there
+  too, and from then on at ``_execute``'s own stage boundaries, like
+  an inline execution: only the waiter of a handed wave can give up
+  on its own clock while the wave still runs); a combined execution
+  that fails (one bad member, a deadline, anything) falls back to per-item
   execution so each member gets ITS OWN outcome, mirroring the
   pipeline's gang fallback.
 * **Shutdown by construction.** ``close()`` flips ``_closing`` under
   the queue lock; from then on ``submit()`` returns ``None`` and the
   caller executes inline — there is no submit/close race to lose. The
   loop drains what was already queued within the ``drain`` budget and
-  fails the rest.
+  fails the rest; a led wave counts in flight like a handed one, so
+  ``close()`` waits for it too.
 
-Observability: ``dispatch.wave_size``, ``dispatch.inflight_depth``,
-``dispatch.device_idle_fraction`` (1 − fraction of wall time with at
-least one wave executing, since first submit), and
+Observability: ``dispatch.waves{how}``, ``dispatch.wave_size``,
+``dispatch.inflight_depth``, ``dispatch.device_idle_fraction`` (1 −
+fraction of wall time with at least one wave executing, since first
+submit), and
 ``dispatch.queue_wait_seconds``; snapshot at ``/debug/dispatch``.
 """
 
@@ -169,8 +188,8 @@ class _Item:
 
 class DispatchEngine:
     """The persistent per-device dispatch loop. One per Executor; the
-    loop thread starts lazily on first submit, so idle executors (and
-    every bare test executor that never routes through it) pay
+    loop thread starts lazily with the first item that has to queue, so
+    idle executors (and every executor whose submitters all lead) pay
     nothing."""
 
     def __init__(
@@ -192,7 +211,8 @@ class DispatchEngine:
         # wave runner slots: the loop blocks here BEFORE dequeuing, so
         # while all slots compute the queue keeps accumulating and the
         # next wave comes out wider — backlog IS the batching window,
-        # exactly like the pipeline's gang dequeue
+        # exactly like the pipeline's gang dequeue. A submitter that
+        # leads takes one without blocking, under _mu
         self._slots = threading.Semaphore(self.max_inflight)
         self._inflight = 0
         self._in_wave = threading.local()
@@ -205,6 +225,7 @@ class DispatchEngine:
         self._busy_since: Optional[float] = None
         # counters (ints under _mu; snapshot is consistent)
         self.waves = 0
+        self.led = 0  # of them, run by their submitter (the rest: handed)
         self.items = 0
         self.dedup_hits = 0
         self.combined_items = 0
@@ -237,10 +258,12 @@ class DispatchEngine:
         text: Optional[str] = None,
         trace_ctx=None,
     ) -> Optional[_Item]:
-        """Enqueue a read-only query for the next wave and return its
-        future — or ``None`` when the engine is closing, in which case
-        the caller executes inline (shutdown can never strand a
-        submit)."""
+        """Admit a read-only query and return its future — or ``None``
+        when the engine is closing, in which case the caller executes
+        inline (shutdown can never strand a submit). With nobody queued
+        and a runner slot free the caller leads its own wave here, and
+        the future it gets back is resolved; else the item queues for
+        the loop's next wave."""
         sig = None
         if text is not None:
             from pilosa_tpu.plan import canon
@@ -250,18 +273,28 @@ class DispatchEngine:
         with self._mu:
             if self._closing:
                 return None
-            if self._loop_thread is None:
-                self._t_start = time.monotonic()
-                t = threading.Thread(
-                    target=self._loop, name="dispatch-loop", daemon=True
-                )
-                self._loop_thread = t
-                t.start()
             item.t_enq = time.monotonic()
-            self._q.append(item)
+            if self._t_start is None:
+                self._t_start = item.t_enq
             self.items += 1
             self._tenant_row_locked(index)["items"] += 1
-            self._cond.notify_all()
+            # FIFO holds: somebody queued is served first, by the loop
+            lead = not self._q and self._slots.acquire(blocking=False)
+            if lead:
+                wave_no = self._begin_wave_locked(led=True)
+            else:
+                if self._loop_thread is None:
+                    t = threading.Thread(
+                        target=self._loop, name="dispatch-loop", daemon=True
+                    )
+                    self._loop_thread = t
+                    t.start()
+                self._q.append(item)
+                self._cond.notify_all()
+        if lead:
+            # nothing is queued behind a led wave, so there is nothing
+            # for _stage_ahead_peek to warm
+            self._run_wave_slot([item], wave_no)
         return item
 
     def in_wave(self) -> bool:
@@ -288,12 +321,7 @@ class DispatchEngine:
                 if not wave:
                     self._slots.release()
                     continue
-                self.waves += 1
-                wave_no = self.waves
-                self._inflight += 1
-                if self._inflight == 1:
-                    self._busy_since = time.monotonic()
-                metrics.gauge(metrics.DISPATCH_INFLIGHT_DEPTH, self._inflight)
+                wave_no = self._begin_wave_locked(led=False)
             # overlap: operand staging for what is STILL queued runs on
             # the stager's side thread while this wave computes
             self._stage_ahead_peek()
@@ -303,6 +331,21 @@ class DispatchEngine:
                 name="dispatch-wave",
                 daemon=True,
             ).start()
+
+    def _begin_wave_locked(self, led: bool) -> int:
+        """A wave starts, its slot already taken: the wave number, the
+        in-flight count, the busy clock and the gauges. ``led``: by its
+        submitter, on the submitter's thread; else handed by the loop to
+        a wave thread. Whoever runs it releases through
+        ``_run_wave_slot``."""
+        self.waves += 1
+        self.led += led
+        metrics.count(metrics.DISPATCH_WAVES, how="led" if led else "handed")
+        self._inflight += 1
+        if self._inflight == 1:
+            self._busy_since = time.monotonic()
+        metrics.gauge(metrics.DISPATCH_INFLIGHT_DEPTH, self._inflight)
+        return self.waves
 
     def _run_wave_slot(self, wave: list[_Item], wave_no: int = 0) -> None:
         try:
@@ -318,7 +361,8 @@ class DispatchEngine:
                     metrics.DISPATCH_DEVICE_IDLE_FRACTION,
                     self._idle_fraction_locked(),
                 )
-                self._cond.notify_all()  # close() waits on inflight==0
+                if self._closing:
+                    self._cond.notify_all()  # close() waits on inflight==0
             self._slots.release()
 
     def _idle_fraction_locked(self) -> float:
@@ -570,6 +614,8 @@ class DispatchEngine:
                 "queued": len(self._q),
                 "inflight_waves": self._inflight,
                 "waves": self.waves,
+                "led": self.led,
+                "handed": self.waves - self.led,
                 "items": self.items,
                 "dedup_hits": self.dedup_hits,
                 "combined_items": self.combined_items,

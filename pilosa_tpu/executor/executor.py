@@ -724,6 +724,8 @@ class Executor:
                     text=query if isinstance(query, str) else None,
                     trace_ctx=trace.current_ctx(),
                 )
+                # resolved already where this thread led its own wave
+                # (nobody queued, a slot free); else wait for the loop's
                 if fut is not None:  # None: engine closing -> inline
                     return fut.result()
             query = parsed  # already parsed; don't redo it below

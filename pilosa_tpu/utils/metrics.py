@@ -205,6 +205,7 @@ BACKUP_ARCHIVES = "backup.archives"
 RESTORE_APPLIED = "restore.applied"
 RESTORE_REFUSED = "restore.refused"
 # async continuous-batching dispatch engine (executor/dispatch.py)
+DISPATCH_WAVES = "dispatch.waves"
 DISPATCH_WAVE_SIZE = "dispatch.wave_size"
 DISPATCH_INFLIGHT_DEPTH = "dispatch.inflight_depth"
 DISPATCH_DEVICE_IDLE_FRACTION = "dispatch.device_idle_fraction"
@@ -749,6 +750,10 @@ METRICS: dict[str, tuple[str, str]] = {
         "counter",
         "restores refused: archive failed checksum/manifest verification "
         "before any byte was applied",
+    ),
+    DISPATCH_WAVES: (
+        "counter",
+        "dispatch waves started (label: how — led: by their submitter on its own thread, nobody queued and a runner slot free; handed: by the dispatch loop to a wave thread, out of the backlog)",
     ),
     DISPATCH_WAVE_SIZE: (
         "summary",

@@ -569,7 +569,7 @@ WATERFALL: dict = {
     WF_PLAN_CANON: "query parse, canonicalization, CSE planning",
     WF_STAGER_LOOKUP: "stager cache probe: content-key hashing + LRU touch",
     WF_STAGER: "HBM stage miss: building + uploading shard planes",
-    WF_DISPATCH_QUEUE: "dispatch-engine queue wait before a wave",
+    WF_DISPATCH_QUEUE: "dispatch-engine queue wait before a wave (a led wave's: the microseconds from admission to the wave's start on the same thread)",
     WF_WAVE_MATES: "combined wave: the wave-mates' share of its measured legs, waited through",
     WF_GUARD_QUEUE: "device-guard pool: wait for a worker to pick the call up",
     WF_TOPN_CANDIDATES: "TopN ranked-cache snapshot and candidate chunk assembly",
@@ -580,7 +580,7 @@ WATERFALL: dict = {
     WF_MESH_FETCH: "mesh: copy of a mesh kernel's replicated result (a TopN chunk's gathered scores, a Sum's or Count's reduced counts) from one replica",
     WF_TOPN_WALK: "TopN ranked walk, cross-shard merge, sort, pass-2 trim",
     WF_REDUCE: "host-side shard-result reduction",
-    WF_HANDOFF_WAKE: "hand-backs: a worker thread's finishing stamp → the thread that waited for it running again (guard pool → wave, wave → pipeline worker, pipeline worker → handler)",
+    WF_HANDOFF_WAKE: "hand-backs: a worker thread's finishing stamp → the thread that waited for it running again (guard pool → the thread that runs the wave, a handed wave's thread → pipeline worker, pipeline worker → handler)",
     WF_RESPOND: "results → JSON bytes → last write",
     WF_OTHER: "unattributed host time (total − measured legs)",
 }

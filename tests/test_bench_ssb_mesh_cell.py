@@ -328,9 +328,11 @@ def test_a_two_call_query_on_the_mesh_counts_the_bypass_and_answers_as_two_singl
 
 def test_two_threads_through_one_mesh_executor_finish_and_agree_with_the_reference(built, mesh):
     """Two clients of the cell: each request's launches span the four
-    devices and end in a collective, waves of two form in the dispatch
-    engine and run call by call after the bypass. Nothing may hang and
-    every answer is the reference's."""
+    devices and end in a collective. With two runner slots each request
+    leads its own wave (ISSUE 38), so two ``shard_map`` launches run
+    side by side on the clients' threads; a wave of two forms only
+    while both slots compute, and runs call by call after the bypass.
+    Nothing may hang and every answer is the reference's."""
     ref, h = built
     ex = _mesh_executor(h, mesh, health=DeviceHealth(timeout_s=120.0))
     calls = _drawn(SEEDS[0] + 1, 30)
@@ -364,11 +366,35 @@ def test_two_threads_through_one_mesh_executor_finish_and_agree_with_the_referen
         assert [len(g) for g in got] == [per_thread, per_thread]
         for q, answer in got[0] + got[1]:
             assert answer == want[q], q
-        # every wave of two that formed was bypassed, none fused
-        after = ex.dispatch_engine.stats()
-        combined = after["combined_items"] - waves["combined_items"]
-        assert _counter(metrics.FUSION_BYPASSES, reason="mesh") - bypasses >= combined // 2
-        assert after["fallbacks"] == waves["fallbacks"] and not ex.fuser._programs
+        # two clients, two slots: nobody queued, every request led
+        engine = ex.dispatch_engine
+        after = engine.stats()
+        assert after["led"] - waves["led"] == 2 * per_thread and after["handed"] == waves["handed"]
+        assert after["combined_items"] == waves["combined_items"]
+        assert _counter(metrics.FUSION_BYPASSES, reason="mesh") == bypasses
+        # both slots computing: the two queue, one wave of two, bypassed and not fused
+        pair = [traffic.pql(calls[0]), traffic.pql(calls[-1])]
+        held: dict = {}
+        for _ in range(engine.max_inflight):
+            assert engine._slots.acquire(timeout=10)
+        threads = [threading.Thread(target=lambda q=q: held.update({q: ex.execute(SMALL["index"], q)}), daemon=True)
+                   for q in pair]
+        for t in threads:
+            t.start()
+        while engine.stats()["queued"] < 2:
+            time.sleep(0.002)
+        for _ in range(engine.max_inflight):
+            engine._slots.release()
+        for t in threads:
+            t.join(timeout=240)
+        assert not any(t.is_alive() for t in threads), "a wave of two hangs on the mesh"
+        for q in pair:
+            (r,) = held[q]
+            assert {"value": r.val, "count": r.count} == want[q], q
+        last = engine.stats()
+        assert last["handed"] == after["handed"] + 1 and last["combined_items"] == after["combined_items"] + 2
+        assert _counter(metrics.FUSION_BYPASSES, reason="mesh") == bypasses + 1
+        assert last["fallbacks"] == waves["fallbacks"] and not ex.fuser._programs
     finally:
         ex.close()
 

@@ -722,3 +722,282 @@ class TestStageAhead:
         finally:
             gate.set()
             ex.close()
+
+
+# -- who runs a wave (ISSUE 38) ------------------------------------------------
+
+
+def _waves(how: str) -> float:
+    return metrics.snapshot().get(f"dispatch.waves;how:{how}", 0)
+
+
+class _held_slots:
+    """Every runner slot taken, as by waves that compute: whatever is
+    submitted meanwhile queues, and leaving hands the backlog over."""
+
+    def __init__(self, engine):
+        self.engine = engine
+
+    def __enter__(self):
+        for _ in range(self.engine.max_inflight):
+            assert self.engine._slots.acquire(timeout=10)
+        return self
+
+    def __exit__(self, *exc):
+        for _ in range(self.engine.max_inflight):
+            self.engine._slots.release()
+        return False
+
+
+def _recording_executor(h, **kw):
+    """Device executor that notes every ``_execute``: the thread, the
+    number of calls, the engine's re-entry flag."""
+    ex = Executor(h, device_policy="always", dispatch_enabled=True, **kw)
+    orig = ex._execute
+    seen = []
+
+    def recording(index, query, shards=None, opt=None):
+        seen.append(
+            (threading.get_ident(), len(query.calls), ex.dispatch_engine.in_wave())
+        )
+        return orig(index, query, shards, opt)
+
+    ex._execute = recording
+    return ex, seen
+
+
+class TestLedWave:
+    def test_idle_engine_runs_the_wave_on_the_callers_thread(
+        self, holder, monkeypatch
+    ):
+        from pilosa_tpu.executor import dispatch
+
+        seed_mixed(holder)
+        oracle = Executor(holder, device_policy="never", dispatch_enabled=False)
+        ex, seen = _recording_executor(holder)
+        started = []
+
+        class Recorded(threading.Thread):
+            def start(self):
+                started.append(self.name)
+                super().start()
+
+        monkeypatch.setattr(dispatch.threading, "Thread", Recorded)
+        try:
+            led, handed = _waves("led"), _waves("handed")
+            for k, q in enumerate(MIXED_QUERIES[:4]):
+                assert ex.execute("i", q) == oracle.execute("i", q)
+                assert seen[k][0] == threading.get_ident()
+                assert seen[k][2] is True  # the re-entry guard, on this thread
+                assert not ex.dispatch_engine.in_wave()
+            assert _waves("led") == led + 4 and _waves("handed") == handed
+            st = ex.dispatch_engine.stats()
+            assert (st["waves"], st["led"], st["handed"]) == (4, 4, 0)
+            assert st["items"] == 4 and st["inflight_waves"] == 0
+            assert 0.0 <= st["device_idle_fraction"] < 1.0
+            # neither the loop nor a wave thread was ever needed
+            assert started == [] and ex.dispatch_engine._loop_thread is None
+            assert ex.dispatch_engine.close(drain=1.0) is True
+        finally:
+            monkeypatch.undo()
+            ex.close()
+            oracle.close()
+
+    def test_held_slots_queue_the_submitters_into_one_handed_wave(self, holder):
+        """Both slots taken: N submitters queue and come out as ONE
+        handed wave of N, deduped and combined as ever."""
+        seed_mixed(holder)
+        oracle = Executor(holder, device_policy="never", dispatch_enabled=False)
+        queries = ["Count(Row(f=1))"] * 3 + MIXED_QUERIES[1:5]
+        want = [oracle.execute("i", q) for q in queries]
+        ex, seen = _recording_executor(
+            holder, dispatch_max_inflight=2, dispatch_max_wave=32
+        )
+        engine = ex.dispatch_engine
+        try:
+            led, handed = _waves("led"), _waves("handed")
+            res = {}
+            with _held_slots(engine):
+                ts = [
+                    threading.Thread(
+                        target=lambda k=k, q=q: res.update({k: ex.execute("i", q)})
+                    )
+                    for k, q in enumerate(queries)
+                ]
+                for t in ts:
+                    t.start()
+                _wait_queued(engine, len(queries))
+                assert seen == []  # nobody runs without a slot
+            for t in ts:
+                t.join(10)
+            assert [res[k] for k in range(len(queries))] == want
+            st = engine.stats()
+            assert (st["waves"], st["led"], st["handed"]) == (1, 0, 1)
+            assert _waves("led") == led and _waves("handed") == handed + 1
+            assert st["dedup_hits"] == 2
+            assert st["combined_items"] == len(queries) - 2
+            # one combined execution, on a wave thread
+            (ident, n_calls, in_wave), = seen
+            assert ident != threading.get_ident() and in_wave
+            assert n_calls == len(queries) - 2
+            # the slots came back: the next request leads
+            assert ex.execute("i", queries[0]) == want[0]
+            assert engine.stats()["led"] == 1
+        finally:
+            ex.close()
+            oracle.close()
+
+    def test_a_submitter_does_not_overtake_one_that_is_queued(self, holder):
+        """A free slot is not enough to lead: with somebody queued the
+        newcomer queues behind it, and the loop serves both in order."""
+        seed_mixed(holder)
+        ex = Executor(
+            holder, device_policy="always", dispatch_enabled=True,
+            dispatch_max_inflight=1, dispatch_max_wave=32,
+        )
+        engine = ex.dispatch_engine
+        orig = ex._execute
+        order = []
+
+        def recording(index, query, shards=None, opt=None):
+            order.append([str(c) for c in query.calls])
+            return orig(index, query, shards, opt)
+
+        ex._execute = recording
+
+        class LoopHeld:
+            """The engine's semaphore; the loop's blocking acquire also
+            waits for the test, a submitter's try does not."""
+
+            def __init__(self, sem):
+                self.sem, self.go = sem, threading.Event()
+
+            def acquire(self, blocking=True, timeout=None):
+                if blocking:
+                    assert self.go.wait(10), "the loop was never let go"
+                return self.sem.acquire(blocking, timeout)
+
+            def release(self):
+                self.sem.release()
+
+        held = engine._slots = LoopHeld(engine._slots)
+        try:
+            res = {}
+
+            def client(name, q):
+                res[name] = ex.execute("i", q)
+
+            assert held.sem.acquire(timeout=10)  # the one slot computes
+            first = threading.Thread(target=client, args=("first", "Count(Row(f=1))"))
+            first.start()
+            _wait_queued(engine, 1)
+            held.sem.release()  # a slot is free, the loop not yet there
+            second = threading.Thread(target=client, args=("second", "Count(Row(f=2))"))
+            second.start()
+            _wait_queued(engine, 2)
+            assert order == [] and engine.stats()["led"] == 0
+            held.go.set()
+            first.join(10)
+            second.join(10)
+            oracle = Executor(holder, device_policy="never", dispatch_enabled=False)
+            assert res["first"] == oracle.execute("i", "Count(Row(f=1))")
+            assert res["second"] == oracle.execute("i", "Count(Row(f=2))")
+            # one handed wave, the earlier submitter's call first
+            (calls,) = order
+            assert calls == ["Count(Row(f=1))", "Count(Row(f=2))"]
+            st = engine.stats()
+            assert (st["led"], st["handed"], st["combined_items"]) == (0, 1, 2)
+        finally:
+            held.go.set()
+            ex.close()
+
+    def test_led_item_with_a_lapsed_deadline_is_cancelled_before_execute(
+        self, holder
+    ):
+        seed_mixed(holder)
+        ex, seen = _recording_executor(holder)
+        try:
+            base = metrics.snapshot().get("pipeline.deadline_expired;stage:dispatch", 0)
+            with dl_mod.activate(Deadline(time.monotonic() - 1.0)):
+                with pytest.raises(DeadlineExceeded):
+                    ex.execute("i", "Count(Row(f=1))")
+            assert seen == []  # no parse, translate or kernel work
+            st = ex.dispatch_engine.stats()
+            assert st["deadline_expired"] == 1 and st["led"] == 1
+            assert st["tenants"]["i"] == {"items": 1, "dedup_hits": 0, "expired": 1}
+            assert (
+                metrics.snapshot().get("pipeline.deadline_expired;stage:dispatch", 0)
+                == base + 1
+            )
+            assert st["inflight_waves"] == 0 and not ex.dispatch_engine.in_wave()
+            assert ex.execute("i", "Count(Row(f=1))")  # the slot came back
+        finally:
+            ex.close()
+
+    def test_an_error_in_a_led_wave_reaches_its_caller_and_frees_the_slot(
+        self, holder
+    ):
+        seed_mixed(holder)
+        ex = Executor(
+            holder, device_policy="always", dispatch_enabled=True,
+            dispatch_max_inflight=2,
+        )
+        engine = ex.dispatch_engine
+        orig = ex._execute
+        where = []
+
+        def broken(index, query, shards=None, opt=None):
+            where.append((threading.get_ident(), engine.in_wave()))
+            raise RuntimeError("injected: the wave's own error")
+
+        ex._execute = broken
+        try:
+            for _ in range(3):  # more often than there are slots
+                with pytest.raises(RuntimeError, match="the wave's own error"):
+                    ex.execute("i", "Count(Row(f=1))")
+                assert not engine.in_wave()
+            assert where == [(threading.get_ident(), True)] * 3
+            st = engine.stats()
+            assert (st["led"], st["handed"], st["inflight_waves"]) == (3, 0, 0)
+            # both slots are back
+            with _held_slots(engine):
+                pass
+            ex._execute = orig
+            assert ex.execute("i", "Count(Row(f=1))")
+            assert engine.stats()["led"] == 4
+        finally:
+            ex.close()
+
+    def test_close_waits_for_a_led_wave_and_later_submits_run_inline(self, holder):
+        seed_mixed(holder)
+        oracle = Executor(holder, device_policy="never", dispatch_enabled=False)
+        want = oracle.execute("i", "Count(Row(f=0))")
+        ex, gate, first = _gated_executor(holder)
+        engine = ex.dispatch_engine
+        try:
+            res = {}
+            leader = threading.Thread(
+                target=lambda: res.update(r=ex.execute("i", "Count(Row(f=0))"))
+            )
+            leader.start()
+            assert first.wait(10)
+            assert engine.stats()["inflight_waves"] == 1 and engine.stats()["led"] == 1
+            closed = {}
+            closer = threading.Thread(
+                target=lambda: closed.update(clean=engine.close(drain=10.0))
+            )
+            closer.start()
+            closer.join(0.3)
+            assert closer.is_alive()  # the led wave counts in flight
+            gate.set()
+            closer.join(10)
+            leader.join(10)
+            assert closed == {"clean": True} and res["r"] == want
+            # closed: submit returns None, the caller runs inline
+            assert ex.execute("i", "Count(Row(f=0))") == want
+            st = engine.stats()
+            assert st["closing"] and (st["items"], st["waves"]) == (1, 1)
+        finally:
+            gate.set()
+            ex.close()
+            oracle.close()

@@ -3,7 +3,10 @@ across the device-guard thread, the capture's options, compiles counted
 wherever they happen, the batched scorers' kernel accounting, and the
 benchmark's per-layer metric files against the names the program
 publishes. ISSUE 37: the launch apart from the wait at the three sites
-that launch, and the three hand-backs booked from the worker's stamp."""
+that launch, and the three hand-backs booked from the worker's stamp.
+ISSUE 38: a request that leads its own dispatch wave: its legs on the
+submitter's thread, its queue wait and its hand-back read off one
+thread's clock."""
 
 import glob
 import itertools
@@ -497,6 +500,142 @@ def test_the_handlers_wake_up_joins_the_summary_as_admission_does():
     ]
 
 
+# -- a request that leads its own wave (ISSUE 38) ------------------------------
+
+
+def _stub_engine(execute):
+    """A dispatch engine over a stand-in executor, two slots."""
+    ex = SimpleNamespace(_execute=execute, stager=SimpleNamespace())
+    return dispatch.DispatchEngine(ex, max_inflight=2)
+
+
+def _submit(engine, query="Count(Row(f=1))"):
+    from pilosa_tpu.pql import parse
+
+    opt = SimpleNamespace(remote=False, exclude_row_attrs=False, exclude_columns=False, cache=True)
+    return engine.submit("i", parse(query), None, opt, text=query)
+
+
+@pytest.mark.parametrize("how", ["led", "handed"])
+def test_a_requests_stages_sum_to_its_total_whoever_runs_its_wave(gated, how):
+    """Behind the device guard, as served: the wave's legs reach the
+    request's waterfall once and sum, with ``dispatch.queue`` and the
+    hand-backs, to no more than the request; a led wave's are opened on
+    the submitter's own thread."""
+    from pilosa_tpu.pql import parse
+
+    parsed = parse("TopN(f, Row(f=1), n=3)")
+    want = gated.execute("i", parsed)  # compile
+    engine = gated.dispatch_engine
+    stages = set(trace.WATERFALL_STAGES)
+    for _ in range(3):
+        before = engine.stats()
+        wf: dict = {}
+        out = {}
+
+        def request():
+            with trace.attrib_activate(wf):
+                t0 = time.monotonic()
+                out["res"] = gated.execute("i", parsed)
+                out["total"] = time.monotonic() - t0
+
+        if how == "led":
+            request()
+        else:  # every slot computes: the request queues, the loop hands it on
+            for _ in range(engine.max_inflight):
+                assert engine._slots.acquire(timeout=10)
+            t = threading.Thread(target=request)
+            t.start()
+            while engine.stats()["queued"] < 1:
+                time.sleep(0.002)
+            for _ in range(engine.max_inflight):
+                engine._slots.release()
+            t.join(timeout=60)
+            assert not t.is_alive()
+        after = engine.stats()
+        assert after[how] == before[how] + 1 and after["waves"] == before["waves"] + 1
+        assert out["res"] == want
+        assert wf["_wave"] == after["waves"]
+        legs = {k: v for k, v in wf.items() if not k.startswith("_")}
+        assert set(legs) <= stages
+        for stage in (trace.WF_DISPATCH_QUEUE, trace.WF_HANDOFF_WAKE, trace.WF_GUARD_QUEUE,
+                      trace.WF_DEVICE_COMPUTE, trace.WF_TOPN_WALK, trace.WF_TOPN_CANDIDATES):
+            assert legs.get(stage, 0.0) > 0.0, stage
+        assert trace.WF_WAVE_MATES not in legs
+        assert sum(legs.values()) <= out["total"] * 1.001
+        summary = profiler.WATERFALL.summarize(wf, out["total"])
+        assert sum(summary["stages"].values()) == pytest.approx(summary["total_ms"], rel=1e-3)
+        assert summary["wave"] == after["waves"]
+
+
+def test_a_led_waves_queue_wait_and_hand_back_are_read_off_the_submitters_own_clock(monkeypatch):
+    """No thread is crossed: ``dispatch.queue`` runs from the admission
+    stamp to the wave's start and hand-back (b) from the wave's
+    finishing stamp to ``result()``, all four readings of this thread's
+    clock, microseconds apart; ``handoff.wake`` is left with the guard's
+    hand-back (a) and the pipeline's (c)."""
+    stamps = _Stamps()
+    monkeypatch.setattr(dispatch, "time", stamps)
+    ran = []
+    engine = _stub_engine(lambda index, q, shards, opt: ran.append(threading.get_ident()) or [7])
+    me = threading.get_ident()
+    wf: dict = {"_req": 9}
+    try:
+        with trace.attrib_activate(wf):
+            item = _submit(engine)
+            assert item.event.is_set() and item.req == 9  # resolved before submit returned
+            assert item.result() == [7]
+            woke = stamps.last(me)
+        assert ran == [me] and set(stamps.by_thread) == {me}
+        mine = stamps.by_thread[me]
+        assert item.t_enq in mine and item.t_done in mine and item.t_enq < item.t_done
+        assert wf[trace.WF_DISPATCH_QUEUE] == item.wait_s
+        assert item.t_enq + item.wait_s in mine  # the wave's starting stamp
+        assert wf[trace.WF_HANDOFF_WAKE] == woke - item.t_done
+        assert wf["_wave"] == 1 and engine.stats()["led"] == 1
+        # nothing of the wave's own scope is left on this thread
+        assert trace.attrib_current() is None and trace.current_wave() == 0
+        assert not engine.in_wave()
+    finally:
+        assert engine.close(drain=1.0)
+
+
+def test_a_leg_inside_a_led_wave_is_credited_once_under_the_submitters_open_leg():
+    """The wave's legs open on a thread that has a leg and a scope of
+    its own open: they are credited to the wave's scope, which
+    ``result()`` merges into the request's once, the outer leg gives
+    their seconds up as to any nested leg, and both the scope and the
+    innermost-leg pointer are the submitter's again afterwards."""
+    inner_legs = []
+
+    def execute(index, q, shards, opt):
+        assert trace.attrib_current() == {"_req": 9}  # the wave's scope, not the request's
+        with trace.leg(trace.WF_DEVICE_COMPUTE) as lg:
+            time.sleep(0.02)
+        inner_legs.append(lg)
+        time.sleep(0.01)  # the wave's glue: no leg of the wave's covers it
+        return [7]
+
+    engine = _stub_engine(execute)
+    wf: dict = {"_req": 9}
+    try:
+        with trace.attrib_activate(wf):
+            with trace.leg(trace.WF_REDUCE) as outer:
+                time.sleep(0.01)
+                assert _submit(engine).result() == [7]
+                assert trace.attrib_current() is wf
+                assert trace._open_leg.leg is outer
+            assert trace._open_leg.leg is None
+        (inner,) = inner_legs
+        assert inner.seconds >= 0.02 and outer.seconds >= 0.04
+        assert wf[trace.WF_DEVICE_COMPUTE] == pytest.approx(inner.seconds)  # once
+        booked = wf[trace.WF_DISPATCH_QUEUE] + wf.get(trace.WF_HANDOFF_WAKE, 0.0)
+        assert wf[trace.WF_REDUCE] == pytest.approx(outer.seconds - inner.seconds - booked)
+        assert sum(v for k, v in wf.items() if not k.startswith("_")) == pytest.approx(outer.seconds)
+    finally:
+        assert engine.close(drain=1.0)
+
+
 # -- the capture --------------------------------------------------------------
 
 
@@ -632,7 +771,7 @@ def test_layer_metric_files_name_published_metrics_and_stages():
                 assert stage in trace.WATERFALL_STAGES, f"{spec['name']}: no stage {stage!r}"
 
 
-def test_the_manifest_ends_with_issue_37s_seven_metrics_and_each_is_a_data_file():
+def test_the_manifest_holds_issue_37s_seven_metrics_and_each_is_a_data_file():
     """Appended in the issue's order, every cell (no ``workloads``
     list), each read from ``/metrics`` by a data file alone; the layer
     ``process`` is new: the collector, the flush and the CPU belong to
@@ -656,9 +795,10 @@ def test_the_manifest_ends_with_issue_37s_seven_metrics_and_each_is_a_data_file(
         ("holder.cache_flush_s_in_window", "s", "program_counter", "process", "query_p95_ms",
          metrics.CACHE_FLUSH_SECONDS + "_sum", {}, 1, "window"),
     ]
-    layers = {m["layer"] for m in manifest["per_layer"][:-7]}
+    at = [m["name"] for m in manifest["per_layer"]].index("executor.device_launch_ms")
+    layers = {m["layer"] for m in manifest["per_layer"][:at]}
     for entry, (name, unit, source, layer, moves, metric, labels, scale, per) in zip(
-        manifest["per_layer"][-7:], want
+        manifest["per_layer"][at : at + 7], want, strict=True
     ):
         assert entry == {"name": name, "unit": unit, "better": "lower", "source": source,
                          "layer": layer, "moves": moves}
@@ -668,3 +808,21 @@ def test_the_manifest_ends_with_issue_37s_seven_metrics_and_each_is_a_data_file(
         assert spec == {"name": name, "source": "server_metrics", "scale": scale, "per": per,
                         "numerator": [{"metric": metric, "labels": labels}]}
     assert "process" not in layers
+
+
+def test_the_manifest_ends_with_issue_38s_metric_and_it_is_a_data_file():
+    """``dispatch.waves{how=led}`` a request, every cell: the share of
+    requests that crossed no thread into their wave. Nothing the
+    benchmark had is edited: one entry appended, one data file."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        manifest = json.load(fh)
+    name = "dispatch.waves_led_per_query"
+    assert manifest["per_layer"][-1] == {
+        "name": name, "unit": "count/query", "better": "higher", "source": "program_counter",
+        "layer": "dispatch", "moves": "query_p50_ms"}
+    assert manifest["per_layer"][-2]["name"] == "holder.cache_flush_s_in_window"
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as fh:
+        spec = json.load(fh)
+    assert spec == {"name": name, "source": "server_metrics", "scale": 1, "per": "request",
+                    "numerator": [{"metric": metrics.DISPATCH_WAVES, "labels": {"how": "led"}}]}
+    assert metrics.METRICS[metrics.DISPATCH_WAVES][0] == "counter"
