@@ -6,7 +6,10 @@ names: ``["Row", field, r]``, ``["Range", field, op, x]`` (``op`` one of
 ``> >= < <= == !=``) or ``["Range", field, "><", lo, hi]``, and
 ``["Intersect" | "Union" | "Difference" | "Xor", e1, e2, ...]``. Calls
 are ``["Count", e]``, ``["Sum", field, e | null]``,
-``["TopN", field, e | null, {"n": k}]`` and a bare bitmap expression.
+``["TopN", field, e | null, {"n": k}]``,
+``["GroupBy", [dim, ...], e | null, {"sum": field?, "limit": k?}]`` with
+``dim`` ``["Rows", field]`` or ``["Rows", field, [ids...]]``, and a bare
+bitmap expression.
 
 A bitmap is ``u64[S, WORDS64]``, packed little-endian as the program's
 shards are. Each field's data sits in one of three small classes, which
@@ -15,12 +18,17 @@ a data ``kind`` (``benchmark/kinds/``) fills from what it generated.
 
 from __future__ import annotations
 
+import itertools
 import operator
 
 import numpy as np
 
 SHARD_WIDTH = 1 << 20
 WORDS64 = SHARD_WIDTH // 64
+# shards a GroupBy over code fields reads at a time: whole-array
+# temporaries of many shards in the comparison's eight threads ran the
+# chip's machine out of memory once (ssb_lineorder.ShardwiseCodes)
+GROUP_SPAN = 8
 
 BITMAP_OPS = ("Intersect", "Union", "Difference", "Xor")
 _COMPARE = {
@@ -146,6 +154,8 @@ class Reference:
             return self.fields[call[1]].sum(src)
         if tag == "TopN":
             return self.topn(call[1], call[2], call[3].get("n", 0))
+        if tag == "GroupBy":
+            return self.groupby(call[1], call[2], call[3])
         mask = unpack(self.words(call))
         shard, col = np.nonzero(mask)
         cols = (shard.astype(np.int64) * SHARD_WIDTH + col).tolist()
@@ -166,11 +176,74 @@ class Reference:
             raise Undecidable(f"TopN({field}, n={n}): the cut does not clear the tail rows")
         return [{"id": int(r), "count": int(counts[r])} for r in order]
 
+    def groupby(self, dims: list, e, opts: dict) -> list[dict]:
+        """GroupBy as ``executor/analytics.py`` defines it (its module
+        docstring, ``finalize_groups``, ``emit_device_groups``): groups in
+        cross-product order, the first ``Rows`` slowest; a dimension's
+        rows are its ``ids`` in their order, or else every row of the
+        field that holds a bit, ascending; a group's ``count`` is its
+        columns, those with no value of the ``sum`` field among them, and
+        its ``sum`` the total of the values it holds; groups of count 0
+        are dropped, then the first ``limit`` kept."""
+        fields = [self.fields[d[1]] for d in dims]
+        for d, f in zip(dims, fields):
+            if isinstance(f, PackedRows):  # no cell groups by one yet: add nested ANDs with the first
+                raise Undecidable(f"GroupBy: Rows({d[1]}) is packed rows, which the reference does not group")
+            if not isinstance(f, Codes):
+                raise ValueError(f"GroupBy: Rows({d[1]}) is not a set field")
+        rows = [list(d[2]) if len(d) > 2 else np.flatnonzero(f.counts(None)).tolist() for d, f in zip(dims, fields)]
+        src = None if e is None else self.words(e)
+        vals = self.fields[opts["sum"]] if opts.get("sum") else None
+        counts, sums = _codes_groups(fields, rows, src, vals)
+        out = []
+        for k, key in enumerate(itertools.product(*rows)):
+            if counts[k]:
+                group = {"group": [{"field": d[1], "rowID": int(r)} for d, r in zip(dims, key)],
+                         "count": int(counts[k])}
+                if vals is not None:
+                    group["sum"] = int(sums[k])
+                out.append(group)
+        return out[: opts["limit"]] if opts.get("limit") else out
+
+
+def _codes_groups(fields: list, rows: list, src, vals) -> tuple[np.ndarray, np.ndarray]:
+    """Every dimension a ``Codes`` field: each column's group is one
+    number, ``(p0 * n1 + p1) * n2 + p2`` of its rows' places in the
+    dimensions' lists, and the counts and sums one ``bincount`` and one
+    ``add.at`` over the filter's columns, ``GROUP_SPAN`` shards a step."""
+    sizes = [len(r) for r in rows]
+    k = int(np.prod(sizes))
+    luts = []
+    for f, ids in zip(fields, rows):
+        lut = np.full(f.n_rows, -1, np.int64)  # a row the field does not have holds no column
+        for i, r in enumerate(ids):
+            if r < f.n_rows:
+                lut[r] = i
+        luts.append(lut)
+    counts, sums = np.zeros(k, np.int64), np.zeros(k, np.int64)
+    for a in range(0, len(fields[0].codes), GROUP_SPAN):
+        at = slice(a, a + GROUP_SPAN)
+        m = None if src is None else unpack(src[at])
+
+        def cols(x):
+            return x[at].ravel() if m is None else x[at][m]
+
+        key, ok = 0, True
+        for f, lut, n in zip(fields, luts, sizes):
+            pos = lut[cols(f.codes)]
+            key, ok = key * n + pos, ok & (pos >= 0)
+        counts += np.bincount(key[ok], minlength=k)
+        if vals is not None:
+            held = ok if vals.exists is None else ok & cols(vals.exists)
+            np.add.at(sums, key[held], cols(vals.vals)[held].astype(np.int64))
+    return counts, sums
+
 
 def same_answer(call, got, want) -> bool:
-    """Exact equality, but for a TopN: there the pairs must be the
-    reference's as a set wherever counts are equal (order among equal
-    counts is not the answer's to fix), and the counts non-increasing."""
+    """Exact equality (a GroupBy's order is defined), but for a TopN:
+    there the pairs must be the reference's as a set wherever counts are
+    equal (order among equal counts is not the answer's to fix), and the
+    counts non-increasing."""
     if call[0] != "TopN":
         return got == want
     if not isinstance(got, list) or len(got) != len(want):

@@ -4,7 +4,10 @@
 row a call must read costs shards x min(131,072 B dense, 4 B x the row's
 bits in a shard). The count does not change when an implementation
 stages less or more, so a share of the roofline stays a share; a share
-over 100 % is a fault in this count.
+over 100 % is a fault in this count. A GroupBy's group masks are
+computed, not read: its bytes are its dimensions' rows, its filter and
+its ``sum`` field, and its kernel, bound by arithmetic, reads low here
+by nature.
 """
 
 from __future__ import annotations
@@ -73,4 +76,11 @@ def bytes_needed(config: dict, call) -> float:
             # a row with under one bit a shard sits in that share of the shards
             return COUNT_BYTES * sum(n * shards * min(1.0, bits) for n, bits in classes)
         return _all_rows(config, call[1]) + _bitmap_bytes(config, call[2])
+    if tag == "GroupBy":
+        dims = sum(
+            sum(_one_row(config, d[1], r) for r in d[2]) if len(d) > 2 else _all_rows(config, d[1])
+            for d in call[1]
+        )
+        agg = call[3].get("sum")
+        return dims + _bitmap_bytes(config, call[2]) + (_all_rows(config, agg) if agg else 0.0)
     return _bitmap_bytes(config, call)

@@ -13,7 +13,10 @@ import pytest
 from benchmark import run
 
 ROOT = run.ROOT
-CELLS = ["tall64.topn", "taxi96.dashboard"]
+CELLS = ["tall64.topn", "taxi96.dashboard", "taxi96.groupby"]
+# the GroupBy mix, which no cell of BENCHMARK.json sends yet: rehearsed
+# as the cell it waits to be (PERF.md, Open questions)
+GROUPBY = {"name": "taxi96.groupby", "config": "taxi96", "traffic": "groupby", "chips": 1}
 
 
 @pytest.fixture(autouse=True)
@@ -26,13 +29,18 @@ def rehearse(cell, trace=0, seconds=4.0, **hooks):
         "--workload", cell, "--seed", str(2**31 + 11), "--seconds", str(seconds),
         "--trace", str(trace), "--allow-cpu", "--shards", "2",
     ])
-    return run.run_cell(args, **hooks)
+    manifest = run.read_json("BENCHMARK.json")
+    if cell == GROUPBY["name"]:
+        manifest["workloads"].append(GROUPBY)
+    return run.run_cell(args, manifest=manifest, **hooks)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_the_reference_agrees_with_the_servers_cpu_path(cell):
     """A second witness: the roaring CPU path (``--device-policy
-    never``) gives what the plain reference gives on every template."""
+    never``) gives what the plain reference gives on every template
+    (``taxi96.groupby``: the four GroupBy panels, the map-reduce of
+    ``analytics.groupby_shard``)."""
     out = rehearse(cell, server_flags=["--device-policy", "never"])
     assert out["correct"] and out["failed"] == 0 and out["attempted"] > 0
     assert out["checks"]["compared"]["value"] > 0

@@ -108,7 +108,7 @@ def test_same_answer_is_exact_but_for_order_among_equal_counts():
     assert same_answer(["Count", []], 7, 7) and not same_answer(["Count", []], 8, 7)
 
 
-@pytest.mark.parametrize("cell_config,cell_mix", [("tall64", "topn"), ("taxi96", "dashboard")])
+@pytest.mark.parametrize("cell_config,cell_mix", [("tall64", "topn"), ("taxi96", "dashboard"), ("taxi96", "groupby")])
 def test_the_control_comes_out_as_not_correct(cell_config, cell_mix):
     """A replica stale by one shard fails every answer of the mix, at a
     size a test run can hold (3 shards, a short tail)."""

@@ -148,7 +148,15 @@ def _bitmap(e) -> str:
     return f"{tag}({', '.join(_bitmap(c) for c in e[1:])})"
 
 
+def _rows(dim) -> str:
+    if len(dim) > 2:
+        return f"Rows({dim[1]}, ids=[{', '.join(str(r) for r in dim[2])}])"
+    return f"Rows({dim[1]})"
+
+
 def pql(call) -> str:
+    """A call in PQL, children first and named arguments last
+    (``docs/query-language.md``)."""
     tag = call[0]
     if tag == "Count":
         return f"Count({_bitmap(call[1])})"
@@ -163,4 +171,13 @@ def pql(call) -> str:
         if call[3].get("n"):
             parts.append(f"n={call[3]['n']}")
         return f"TopN({', '.join(parts)})"
+    if tag == "GroupBy":
+        parts = [_rows(d) for d in call[1]]
+        if call[2] is not None:
+            parts.append(_bitmap(call[2]))
+        if call[3].get("sum"):
+            parts.append(f"Sum(field={call[3]['sum']})")
+        if call[3].get("limit"):
+            parts.append(f"limit={call[3]['limit']}")
+        return f"GroupBy({', '.join(parts)})"
     return _bitmap(call)
