@@ -301,6 +301,21 @@ def _trace_bsi_sum(depth: int, tree, planes, inputs):
     )
 
 
+def _score_stacked(src, staged):
+    """One launch of the stacked scorer, counted by how it reads the
+    bundle (``topn.scorer_launches``; the fused head counts its own)."""
+    metrics.count(
+        metrics.TOPN_SCORER_LAUNCHES, how=ops.stacked_scorer_how(src.shape[0])
+    )
+    return ops.sparse_intersection_counts_stacked(src, *staged)
+
+
+def _score_stacked_batch(srcs, staged):
+    """One launch over coalesced sources: the batch form gathers."""
+    metrics.count(metrics.TOPN_SCORER_LAUNCHES, how="gather")
+    return ops.sparse_intersection_counts_stacked_batch_list(srcs, *staged)
+
+
 def _make_stacked_scorer() -> BatchedScorer:
     """Coalescing scorer for the cross-shard stacked-sparse TopN path.
     max_batch bounds the lax.map sweep (32, a value not measured on
@@ -309,10 +324,8 @@ def _make_stacked_scorer() -> BatchedScorer:
     may be held by abandoned workers)."""
     return BatchedScorer(
         max_batch=32,
-        single_fn=lambda src, st: ops.sparse_intersection_counts_stacked(src, *st),
-        batch_fn=lambda srcs, st: ops.sparse_intersection_counts_stacked_batch_list(
-            srcs, *st
-        ),
+        single_fn=_score_stacked,
+        batch_fn=_score_stacked_batch,
         kind="topn_score_stacked",
     )
 

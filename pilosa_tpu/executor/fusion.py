@@ -327,6 +327,10 @@ class QueryFuser:
         metrics.observe(metrics.FUSION_FUSED_CALLS_PER_LAUNCH, len(launch))
         metrics.count(metrics.FUSION_BYTES_RETURNED, nbytes)
         for d in descs:
+            if d[0] == "topn":
+                metrics.count(
+                    metrics.TOPN_SCORER_LAUNCHES, how=ops.stacked_scorer_how(d[2])
+                )
             if d[0] in ("groupby_count", "groupby_sum"):
                 metrics.count(metrics.FUSION_GROUPBY_LAUNCHES)
                 k = 1

@@ -972,8 +972,10 @@ class DeviceStager:
         peek: bool = False,
     ):
         """Merged block-sparse candidate staging for ALL shards: one
-        (blocks u32[B, 2048], global_row i32[B], slot i32[B],
-        shard i32[B], num_rows) bundle, where global_row = shard_index
+        (blocks u32[B, 16, 128], global_row i32[B], slot i32[B],
+        shard i32[B], num_rows) bundle (a 2048-word container block as
+        two (8, 128) tiles, so the one-pass scorer reads a block by one
+        leading index), where global_row = shard_index
         * chunk + local candidate index. One kernel dispatch then
         scores the whole index's chunk (ops.sparse_intersection_counts_
         stacked). Returns None when no shard has candidates. No delta
@@ -1013,7 +1015,9 @@ class DeviceStager:
                 brow = np.pad(brow, (0, b_pad - b))
                 bslot = np.pad(bslot, (0, b_pad - b))
                 bshard = np.pad(bshard, (0, b_pad - b))
-            w32 = np.ascontiguousarray(blocks).view("<u4")
+            w32 = np.ascontiguousarray(blocks).view("<u4").reshape(
+                b_pad, *ops.STACKED_BLOCK_SHAPE
+            )
             dev = (
                 jax.device_put(w32, self.device),
                 jax.device_put(brow, self.device),

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pilosa_tpu.ops import pallas_kernels
+
 # Words per shard-row on device: 2^20 bits / 32.
 SHARD_WIDTH = 1 << 20
 WORDS_PER_ROW = SHARD_WIDTH // 32
@@ -127,6 +129,36 @@ def sparse_intersection_counts(src, blocks, block_row, block_slot, num_rows: int
     return jax.ops.segment_sum(per_block, block_row, num_segments=num_rows)
 
 
+def stacked_block_counts_gather(srcs, blocks, block_slot, block_shard):
+    """popcount(block & its source block) per block, in XLA: the source
+    blocks gathered into a [B, 16, 128] temporary, ANDed, popcounted
+    and summed. srcs u32[S, W]; blocks u32[B, 16, 128] -> i32[B]."""
+    per_shard = srcs.reshape(srcs.shape[0], CONTAINERS_PER_ROW, *blocks.shape[1:])
+    src_blk = per_shard[block_shard, block_slot]
+    pc = jax.lax.population_count(jnp.bitwise_and(blocks, src_blk))
+    return jnp.sum(pc.astype(jnp.int32), axis=(1, 2))
+
+
+def stacked_block_counts(srcs, blocks, block_slot, block_shard):
+    """Per-block popcounts of the stacked scorer. Lowered for a TPU with
+    a source stack that fits its VMEM budget, one Pallas kernel reads
+    the bundle once (pallas_kernels.stacked_block_counts_onepass);
+    otherwise, and on every other platform, XLA's gather
+    (stacked_block_counts_gather), which writes the gathered sources
+    and reads them back. pallas_kernels.stacked_scorer_how states the
+    same rule for the host."""
+    if not pallas_kernels.onepass_fits(srcs.shape[0]):
+        return stacked_block_counts_gather(srcs, blocks, block_slot, block_shard)
+    return jax.lax.platform_dependent(
+        srcs,
+        blocks,
+        block_slot,
+        block_shard,
+        tpu=pallas_kernels.stacked_block_counts_onepass,
+        default=stacked_block_counts_gather,
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("num_rows",))
 @jax.named_scope("topn_score_stacked")
 def sparse_intersection_counts_stacked(
@@ -138,17 +170,16 @@ def sparse_intersection_counts_stacked(
     shard. Here every
     shard's candidate blocks are concatenated (block_shard says which
     shard a block belongs to, block_row is a GLOBAL segment id =
-    shard_index * chunk + local candidate index) and one gather +
-    popcount + segment-sum serves the whole index — the single-device
-    analog of the reference's per-node scatter-gather collapsing into
-    one program (reference executor.go:1444-1593).
+    shard_index * chunk + local candidate index) and one program of
+    block popcounts (stacked_block_counts) + segment-sum serves the
+    whole index — the single-device analog of the reference's per-node
+    scatter-gather collapsing into one program (reference
+    executor.go:1444-1593).
 
-    srcs: u32[S, W]; blocks: u32[B, 2048]; returns i32[num_rows].
+    srcs: u32[S, W]; blocks: u32[B, 16, 128] (a 2048-word container
+    block as two (8, 128) tiles); returns i32[num_rows].
     """
-    per_shard = srcs.reshape(srcs.shape[0], -1, CONTAINER_WORDS)
-    src_blk = per_shard[block_shard, block_slot]
-    pc = jax.lax.population_count(jnp.bitwise_and(blocks, src_blk))
-    per_block = jnp.sum(pc.astype(jnp.int32), axis=-1)
+    per_block = stacked_block_counts(srcs, blocks, block_slot, block_shard)
     return jax.ops.segment_sum(per_block, block_row, num_segments=num_rows)
 
 
@@ -195,7 +226,10 @@ def sparse_intersection_counts_stacked_batch(
     1B/64-shard config; vectorizing groups of 8 inside the map keeps
     the peak gather footprint bounded while amortizing the stream.
 
-    srcs_q: u32[Q, S, W]; blocks: u32[B, 2048]; returns i32[Q, num_rows].
+    The gather stays here: no cell sends concurrent TopNs over one
+    bundle, so the one-pass kernel serves the single form alone.
+
+    srcs_q: u32[Q, S, W]; blocks: u32[B, 16, 128]; returns i32[Q, num_rows].
     """
     q = srcs_q.shape[0]
     group = min(_BATCH_GROUP, q)
@@ -203,17 +237,21 @@ def sparse_intersection_counts_stacked_batch(
         # q is pow2-padded by the batcher; any stray remainder falls
         # back to the per-query sweep rather than a mid-shape compile
         return jax.lax.map(
-            lambda s: sparse_intersection_counts_stacked(
-                s, blocks, block_row, block_slot, block_shard, num_rows
+            lambda s: jax.ops.segment_sum(
+                stacked_block_counts_gather(s, blocks, block_slot, block_shard),
+                block_row,
+                num_segments=num_rows,
             ),
             srcs_q,
         )
-    per_shard = srcs_q.reshape(q, srcs_q.shape[1], -1, CONTAINER_WORDS)
+    per_shard = srcs_q.reshape(
+        q, srcs_q.shape[1], CONTAINERS_PER_ROW, *blocks.shape[1:]
+    )
 
     def one_group(g):
-        src_blk = g[:, block_shard, block_slot]  # [G, B, W]
+        src_blk = g[:, block_shard, block_slot]  # [G, B, 16, 128]
         pc = jax.lax.population_count(jnp.bitwise_and(blocks[None], src_blk))
-        per_block = jnp.sum(pc.astype(jnp.int32), axis=-1)  # [G, B]
+        per_block = jnp.sum(pc.astype(jnp.int32), axis=(2, 3))  # [G, B]
         return jax.vmap(
             lambda pb: jax.ops.segment_sum(pb, block_row, num_segments=num_rows)
         )(per_block)

@@ -5,11 +5,16 @@ reduce; the Pallas versions add explicit tiling so the fragment matrix
 streams HBM→VMEM in (TILE_R, TILE_W) blocks with the src row pinned in
 VMEM, accumulating per-row partial popcounts across word tiles.
 
-No kernel here is called by the served path today: the executor and the
-stager use the XLA forms in ops/packed.py, and only the tests call
-these (ROADMAP D4). Every kernel compiles for a described
-v5e (tests/test_tpu_compile.py); ``interpret=True`` runs them on the CPU
-so their semantics are tested there.
+One kernel here is on the served path: ``stacked_block_counts_onepass``,
+the per-block popcounts of the one-chip stacked TopN scorer
+(ops.packed.sparse_intersection_counts_stacked, launched by
+BatchedScorer or traced into a fused program as a TopN head). It runs
+where that program is lowered for a TPU and the source stack fits
+``ONEPASS_VMEM_BUDGET`` (``onepass_fits``); elsewhere the scorer keeps
+its XLA gather, and the batch and mesh scorers never call it. The
+others are called only by the tests (ROADMAP D4). Every kernel compiles
+for a described v5e (tests/test_tpu_compile.py); ``interpret=True`` runs
+them on the CPU so their semantics are tested there.
 """
 
 from __future__ import annotations
@@ -247,3 +252,122 @@ def pad_for_pallas(mat):
     if rp or wp:
         mat = np.pad(mat, ((0, rp), (0, wp)))
     return mat, r
+
+
+# -- the one-chip stacked TopN scorer's block popcounts ----------------------
+
+# A 2^16-bit container block as the stager lays it out: u32[16, 128], two
+# (8, 128) tiles, so a block is one leading index of a [B, 16, 128] bundle.
+BLOCK_SUBLANES = 16
+BLOCK_LANES = 128
+STACKED_BLOCK_SHAPE = (BLOCK_SUBLANES, BLOCK_LANES)
+# blocks streamed a grid step (8 MiB); the coordinate arrays' rank-1 SMEM
+# blocks must match XLA's T(1024) tiling of an s32 vector, so never fewer
+ONEPASS_TILE = 1024
+# VMEM the resident source stack may take: 256 shards of 128 KiB, a
+# quarter of v5e's 128 MiB, beside the two streamed tiles
+ONEPASS_VMEM_BUDGET = 32 << 20
+_ROW_BYTES = 16 * BLOCK_SUBLANES * BLOCK_LANES * 4  # one shard row, 128 KiB
+
+
+def onepass_fits(n_shards: int) -> bool:
+    """Whether a source stack of ``n_shards`` rows stays resident in
+    VMEM for the one-pass kernel."""
+    return n_shards * _ROW_BYTES <= ONEPASS_VMEM_BUDGET
+
+
+def stacked_scorer_how(n_shards: int) -> str:
+    """How a launch of the one-chip stacked scorer over ``n_shards``
+    reads its bundle on the default backend: ``onepass`` (this module's
+    kernel) or ``gather`` (XLA's gather, AND and popcount). The rule
+    ``ops.packed`` applies when it lowers the scorer, for the host's
+    ``topn.scorer_launches`` counter."""
+    tpu = jax.default_backend() == "tpu"
+    return "onepass" if tpu and onepass_fits(n_shards) else "gather"
+
+
+def _onepass_kernel(shard_ref, slot_ref, srcs_ref, blocks_ref, out_ref, rows_ref):
+    # Grid over tiles of blocks; the source stack [S * 16, 16, 128] is the
+    # same block at every step, so it is fetched once and stays. Each
+    # block's source is one leading index of it, (shard, slot) read from
+    # the tile's SMEM coordinates: AND, popcount and a sum over sublanes
+    # make one row of lane partials, 128 rows a group; the group's
+    # transpose summed over sublanes is its 128 counts, lane-dense.
+    group = BLOCK_LANES
+
+    def body(g, carry):
+        base = pl.multiple_of(g * group, group)
+        for j in range(group):
+            b = base + j
+            src = srcs_ref[shard_ref[b] * BLOCK_SUBLANES + slot_ref[b]]
+            pc = jax.lax.population_count(jnp.bitwise_and(blocks_ref[b], src))
+            rows_ref[j : j + 1, :] = jnp.sum(
+                pc.astype(jnp.int32), axis=0, keepdims=True
+            )
+        out_ref[:, pl.ds(base, group)] = jnp.sum(
+            rows_ref[...].T, axis=0, keepdims=True
+        )
+        return carry
+
+    jax.lax.fori_loop(0, blocks_ref.shape[0] // group, body, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def stacked_block_counts_onepass(
+    srcs, blocks, block_slot, block_shard, *, interpret: bool = False
+):
+    """popcount(block & its source block) per block, reading the bundle
+    once: srcs u32[S, W], blocks u32[B, 16, 128], block_slot and
+    block_shard i32[B] -> i32[B].
+
+    The source stack is held in VMEM for the whole launch (single
+    buffered: its block never changes), so S must pass onepass_fits;
+    the blocks stream HBM→VMEM in tiles of ONEPASS_TILE, and only the
+    counts go back. B that is not a multiple of the tile is padded with
+    zero blocks aimed at (shard 0, slot 0), which count 0.
+    """
+    s = srcs.shape[0]
+    b = blocks.shape[0]
+    pad = (-b) % ONEPASS_TILE
+    if pad:
+        blocks = jnp.pad(blocks, ((0, pad), (0, 0), (0, 0)))
+        block_slot = jnp.pad(block_slot, (0, pad))
+        block_shard = jnp.pad(block_shard, (0, pad))
+    n = b + pad
+    tile = ONEPASS_TILE
+    resident = s * _ROW_BYTES
+    streamed = 2 * tile * BLOCK_SUBLANES * BLOCK_LANES * 4
+    out = pl.pallas_call(
+        _onepass_kernel,
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
+        grid=(n // tile,),
+        in_specs=[
+            pl.BlockSpec((tile,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((tile,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec(
+                (s * BLOCK_SUBLANES, BLOCK_SUBLANES, BLOCK_LANES),
+                lambda i: (0, 0, 0),
+                memory_space=pltpu.VMEM,
+                pipeline_mode=pl.Buffered(1),
+            ),
+            pl.BlockSpec(
+                (tile, BLOCK_SUBLANES, BLOCK_LANES),
+                lambda i: (i, 0, 0),
+                memory_space=pltpu.VMEM,
+            ),
+        ],
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((BLOCK_LANES, BLOCK_LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=resident + streamed + (8 << 20),
+        ),
+        interpret=interpret,
+        name="stacked_block_counts_onepass",
+    )(
+        block_shard,
+        block_slot,
+        srcs.reshape(s * BLOCK_SUBLANES, BLOCK_SUBLANES, BLOCK_LANES),
+        blocks,
+    )
+    return out[0, :b]

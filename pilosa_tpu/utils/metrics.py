@@ -125,6 +125,8 @@ TOPN_PREFETCH_STARTS = "topn.prefetch_starts"
 TOPN_PASS2_IDS = "topn.pass2_ids"
 # candidate chunks a cross-shard TopN scored, by what set their size
 TOPN_CHUNKS = "topn.chunks"
+# launches of the one-chip stacked TopN scorer, by how they read the bundle
+TOPN_SCORER_LAUNCHES = "topn.scorer_launches"
 # device launches made for a filter before the program that consumes it
 FILTER_LAUNCHES = "filter.launches"
 # filter nodes traced into the program that consumes them, with no launch
@@ -464,6 +466,16 @@ METRICS: dict[str, tuple[str, str]] = {
         "shard; bounded, a later chunk that the walk's fixed thresholds "
         "and the cached counts ended short of the ladder's size, the "
         "walk's last; ladder, a later chunk of the ladder's size)",
+    ),
+    TOPN_SCORER_LAUNCHES: (
+        "counter",
+        "launches that score a stacked block-sparse TopN bundle on one "
+        "device, counted on the host once a launch, the fused head's "
+        "included (label: how = onepass, one kernel reads the bundle once, "
+        "the source stack resident in VMEM: a TPU and at most 256 shards; "
+        "gather, XLA gathers the source blocks, writes them and reads them "
+        "back: any other backend, a larger stack, a batch of coalesced "
+        "sources)",
     ),
     FILTER_LAUNCHES: (
         "counter",

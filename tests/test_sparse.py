@@ -205,7 +205,8 @@ class TestSparseTopN:
         frag = h.fragment("i", "f", "standard", 0)
         ids = tuple(frag.row_ids()[:32])
         blocks, brow, bslot = frag.sparse_row_blocks(list(ids))
-        blocks32 = np.ascontiguousarray(blocks).view("<u4")
+        # laid out as the stager stages a bundle: a block is [16, 128] words
+        blocks32 = np.ascontiguousarray(blocks).view("<u4").reshape(-1, 16, 128)
         bshard = np.zeros(len(brow), dtype=brow.dtype)  # single shard
         staged = (blocks32, brow, bslot, bshard, len(ids))
 

@@ -79,23 +79,29 @@ def _matrix_batch(s):
 
 def _sparse_stacked_mat(s):
     # chip_smoke.py's TopN head chunk: 128 candidates x 64 shards, every
-    # one a hot row holding all 16 container blocks
+    # one a hot row holding all 16 container blocks (tall64.topn's head)
     b = S * 128 * 16
     i32 = jnp.int32
     return ops.sparse_intersection_counts_stacked_mat.lower(
-        s((S, W)), s((b, 2048)), s((b,), i32), s((b,), i32), s((b,), i32),
+        s((S, W)), s((b, 16, 128)), s((b,), i32), s((b,), i32), s((b,), i32),
         num_rows=S * 128, n_shards=S, chunk=128,
     )
 
 
 def _sparse_stacked_second_chunk(s):
-    # the largest program chip_smoke.py launches: 4096 candidates x 64
-    # shards, 385,024 blocks padded to 2^19 — 4 GiB in, 4 GiB of scratch
+    # the largest program chip_smoke.py launches, in the XLA form the
+    # scorer keeps for a stack over the one-pass kernel's VMEM budget:
+    # 4096 candidates x 64 shards, 385,024 blocks padded to 2^19 — 4 GiB
+    # in, 4 GiB of gathered sources
     b = 1 << 19
     i32 = jnp.int32
-    return ops.sparse_intersection_counts_stacked.lower(
-        s((S, W)), s((b, 2048)), s((b,), i32), s((b,), i32), s((b,), i32),
-        num_rows=S * 4096,
+
+    def gather_scores(srcs, blocks, brow, bslot, bshard):
+        per_block = ops.stacked_block_counts_gather(srcs, blocks, bslot, bshard)
+        return jax.ops.segment_sum(per_block, brow, num_segments=S * 4096)
+
+    return jax.jit(gather_scores).lower(
+        s((S, W)), s((b, 16, 128)), s((b,), i32), s((b,), i32), s((b,), i32)
     )
 
 
@@ -179,7 +185,7 @@ def _fused_query(s):
     )
     flat = (
         [s((S, W))] * 6
-        + [s((S, W)), s((b, 2048)), s((b,), i32), s((b,), i32), s((b,), i32)]
+        + [s((S, W)), s((b, 16, 128)), s((b,), i32), s((b,), i32), s((b,), i32)]
         + [s((S, DEPTH + 1, W)), s((S, W))]
     )
     return jax.jit(fusion._build_program(descs)).lower(*flat)
@@ -208,6 +214,60 @@ def test_served_kernel_compiles_for_v5e(one_chip, lower):
     mem = compiled.memory_analysis()
     # 16 GB of HBM: arguments plus scratch of one program must fit
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 << 30
+
+
+TAXI_S = 96  # shards of benchmark/configs/taxi96.json
+
+
+def _onepass_tall64_head(s):
+    # tall64.topn's head (and its bounded second chunk): 2^17 blocks,
+    # 1 GiB, through the matrix form a fused program traces
+    return _sparse_stacked_mat(s)
+
+
+def _onepass_tall64_alone(s):
+    b = 1 << 17
+    i32 = jnp.int32
+    return pallas_kernels.stacked_block_counts_onepass.lower(
+        s((S, W)), s((b, 16, 128)), s((b,), i32), s((b,), i32)
+    )
+
+
+def _onepass_taxi96(s):
+    # taxi96.dashboard's filtered TopN: 96 shards, 2^16 blocks
+    b = 1 << 16
+    i32 = jnp.int32
+    return ops.sparse_intersection_counts_stacked.lower(
+        s((TAXI_S, W)), s((b, 16, 128)), s((b,), i32), s((b,), i32), s((b,), i32),
+        num_rows=TAXI_S * 128,
+    )
+
+
+@pytest.mark.parametrize(
+    "lower",
+    [_onepass_tall64_head, _onepass_tall64_alone, _onepass_taxi96],
+    ids=lambda f: f.__name__.lstrip("_"),
+)
+def test_onepass_scorer_compiles_for_v5e(one_chip, lower):
+    """Lowered for a TPU, the stacked scorer is the one-pass kernel: a
+    Mosaic custom call and no gathered [B, 16, 128] temporary (the
+    gather form's is the bundle's size, 1 GiB here)."""
+    compiled = lower(one_chip).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_gather_form_writes_a_temporary_the_size_of_the_bundle(one_chip):
+    b = 1 << 17
+    i32 = jnp.int32
+    s = one_chip
+    compiled = (
+        jax.jit(ops.stacked_block_counts_gather)
+        .lower(s((S, W)), s((b, 16, 128)), s((b,), i32), s((b,), i32))
+        .compile()
+    )
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes >= b * 2048 * 4
 
 
 def _count_fold(mesh, s):
