@@ -2156,7 +2156,6 @@ class Executor:
         dims = analytics.resolve_dims(
             self.holder, index, plan, shards, self.analytics_max_groups
         )
-        merged = None
         if (
             self._local_batchable(opt)
             and shards
@@ -2166,91 +2165,106 @@ class Executor:
         ):
             try:
                 with trace.child(metrics.STAGE_DEVICE_BATCH, call="GroupBy"):
-                    merged = self._groupby_device_batched(
-                        index, plan, dims, shards
+                    groups = self._groupby_device_batched(
+                        index, plan, dims, shards, finalize=not opt.remote
                     )
                 fields = [f for f, _ in dims] + (
                     [plan.agg_field] if plan.agg_field else []
                 )
                 self._analytics_heat_legs(index, fields, shards)
+                return groups
             except _NotDeviceable:
-                merged = None
+                pass
             except FragmentQuarantinedError:
                 metrics.count(metrics.ANALYTICS_DEGRADED_LEGS, call="GroupBy")
-                merged = None
-        if merged is None:
 
-            def map_fn(shard):
-                return analytics.groupby_shard(self, index, plan, dims, shard)
+        def map_fn(shard):
+            return analytics.groupby_shard(self, index, plan, dims, shard)
 
-            merged = self._map_reduce(
-                index,
-                shards,
-                c,
-                opt,
-                map_fn,
-                analytics.merge_group_lists,
-                zero_factory=list,
-            )
+        merged = self._map_reduce(
+            index,
+            shards,
+            c,
+            opt,
+            map_fn,
+            analytics.merge_group_lists,
+            zero_factory=list,
+        )
         if opt.remote:
             # un-finalized wire list: the coordinator merges remote legs
             # first, then orders + applies limit exactly once
             return merged or []
         return analytics.finalize_groups(plan, merged or [])
 
-    def _groupby_device_batched(self, index, plan, dims, shards) -> list[dict]:
+    def _groupby_device_batched(
+        self, index, plan, dims, shards, finalize: bool
+    ) -> list[dict]:
         """One segmented-reduction launch for the whole panel: stack each
         dimension's rows, cross-product AND on device, popcount the K
-        group bitmaps (and their BSI plane intersections for Sum)."""
+        group bitmaps (and their BSI plane intersections for Sum). The
+        wire list, ordered and limited where ``finalize`` (a remote leg's
+        is left to the coordinator). The host's lowering is the request's
+        ``groupby.lower``, the answer's assembly its
+        ``groupby.finalize``."""
         import jax.numpy as jnp
 
-        wf = len(shards) * _W32
-        dim_stacks = []
-        for field, ids in dims:
-            frags = tuple(
-                self.holder.fragment(index, field, VIEW_STANDARD, s)
-                for s in shards
-            )
-            rows = [self.stager.row_stack(frags, rid) for rid in ids]
-            dim_stacks.append(jnp.stack(rows).reshape(len(ids), wf))
-        if plan.filter is not None:
-            filt = jnp.asarray(
-                self._device_bitmap_stack(index, plan.filter, shards)
-            ).reshape(wf)
-        else:
-            filt = None
+        with trace.leg(trace.WF_GROUPBY_LOWER):
+            wf = len(shards) * _W32
+            dim_stacks = []
+            for field, ids in dims:
+                frags = tuple(
+                    self.holder.fragment(index, field, VIEW_STANDARD, s)
+                    for s in shards
+                )
+                rows = [self.stager.row_stack(frags, rid) for rid in ids]
+                dim_stacks.append(jnp.stack(rows).reshape(len(ids), wf))
+            if plan.filter is not None:
+                filt = jnp.asarray(
+                    self._device_bitmap_stack(index, plan.filter, shards)
+                ).reshape(wf)
+            else:
+                filt = None
+            planes = depth = bsig = None
+            if plan.agg_field is not None:
+                f = self.holder.field(index, plan.agg_field)
+                bsig = f.bsi_group(plan.agg_field) if f is not None else None
+                if bsig is None:
+                    raise NotFoundError(f"bsiGroup not found: {plan.agg_field}")
+                depth = bsig.bit_depth()
+                afrags = tuple(
+                    self.holder.fragment(
+                        index,
+                        plan.agg_field,
+                        VIEW_BSI_GROUP_PREFIX + plan.agg_field,
+                        s,
+                    )
+                    for s in shards
+                )
+                if any(afrags):
+                    planes = jnp.transpose(
+                        self.stager.planes_stack(afrags, depth), (1, 0, 2)
+                    ).reshape(depth + 1, wf)
         k = 1
         for _, ids in dims:
             k *= len(ids)
         metrics.count(metrics.FUSION_GROUPBY_LAUNCHES)
         metrics.observe(metrics.FUSION_GROUPBY_GROUPS, k)
-        if plan.agg_field is None:
+        if planes is None:
             counts = _launch("groupby_counts", ops.groupby_counts, tuple(dim_stacks), filt)
-            return analytics.emit_device_groups(dims, counts)
-        f = self.holder.field(index, plan.agg_field)
-        bsig = f.bsi_group(plan.agg_field) if f is not None else None
-        if bsig is None:
-            raise NotFoundError(f"bsiGroup not found: {plan.agg_field}")
-        depth = bsig.bit_depth()
-        afrags = tuple(
-            self.holder.fragment(
-                index, plan.agg_field, VIEW_BSI_GROUP_PREFIX + plan.agg_field, s
+            plane_counts = None
+        else:
+            counts, plane_counts = _launch(
+                "groupby_sum", ops.groupby_sum_reduce, tuple(dim_stacks), filt, planes
             )
-            for s in shards
-        )
-        if not any(afrags):
-            counts = _launch("groupby_counts", ops.groupby_counts, tuple(dim_stacks), filt)
-            return analytics.emit_device_groups(
-                dims, counts, sums=[0] * int(counts.shape[0])
-            )
-        planes = jnp.transpose(
-            self.stager.planes_stack(afrags, depth), (1, 0, 2)
-        ).reshape(depth + 1, wf)
-        counts, plane_counts = _launch(
-            "groupby_sum", ops.groupby_sum_reduce, tuple(dim_stacks), filt, planes
-        )
-        sums = analytics.assemble_sums(plane_counts, depth, bsig.min)
-        return analytics.emit_device_groups(dims, counts, sums=sums)
+        with trace.leg(trace.WF_GROUPBY_FINALIZE):
+            if plane_counts is not None:
+                sums = analytics.assemble_sums(plane_counts, depth, bsig.min)
+            elif bsig is not None:
+                sums = [0] * int(counts.shape[0])  # no value fragments: every sum 0
+            else:
+                sums = None
+            groups = analytics.emit_device_groups(dims, counts, sums=sums)
+            return analytics.finalize_groups(plan, groups) if finalize else groups
 
     def _execute_distinct(self, index, c: Call, shards, opt) -> list[int]:
         field, ok = c.string_arg("field")

@@ -239,7 +239,8 @@ class QueryFuser:
                 elif c.name == "Sum":
                     u = self._lower_sum(index, i, c, shards)
                 elif c.name == "GroupBy":
-                    u = self._lower_groupby(index, i, c, shards)
+                    with trace.leg(trace.WF_GROUPBY_LOWER):
+                        u = self._lower_groupby(index, i, c, shards)
                 elif c.name == "Distinct":
                     u = self._lower_distinct(index, i, c, shards)
                 elif c.name == "Percentile":
@@ -393,7 +394,9 @@ class QueryFuser:
         dimension's rows stack once, the cross-product AND + popcount
         (and BSI plane intersections for a Sum aggregate) trace into the
         fused program, and only the K-vector (or [K, depth+1] counts
-        matrix) crosses back to host."""
+        matrix) crosses back to host. The caller times this lowering as
+        the request's ``groupby.lower``; the finisher's assembly is its
+        ``groupby.finalize``."""
         import jax.numpy as jnp
 
         ex = self.ex
@@ -428,9 +431,10 @@ class QueryFuser:
 
             def finish(counts):
                 metrics.count(metrics.ANALYTICS_QUERIES, call="GroupBy")
-                return analytics.finalize_groups(
-                    plan, analytics.emit_device_groups(dims, counts)
-                )
+                with trace.leg(trace.WF_GROUPBY_FINALIZE):
+                    return analytics.finalize_groups(
+                        plan, analytics.emit_device_groups(dims, counts)
+                    )
 
             return _Unit(
                 i,
@@ -460,11 +464,12 @@ class QueryFuser:
 
         def finish(out):
             metrics.count(metrics.ANALYTICS_QUERIES, call="GroupBy")
-            sums = analytics.assemble_sums(out[:, 1:], depth, bsig.min)
-            return analytics.finalize_groups(
-                plan,
-                analytics.emit_device_groups(dims, out[:, 0], sums=sums),
-            )
+            with trace.leg(trace.WF_GROUPBY_FINALIZE):
+                sums = analytics.assemble_sums(out[:, 1:], depth, bsig.min)
+                return analytics.finalize_groups(
+                    plan,
+                    analytics.emit_device_groups(dims, out[:, 0], sums=sums),
+                )
 
         return _Unit(
             i,

@@ -530,11 +530,13 @@ WF_WAVE_MATES = "dispatch.wave_mates"
 WF_GUARD_QUEUE = "guard.queue"
 WF_TOPN_CANDIDATES = "topn.candidates"
 WF_FILTER_EVAL = "filter.eval"
+WF_GROUPBY_LOWER = "groupby.lower"
 WF_DEVICE_LAUNCH = "device.launch"
 WF_DEVICE_COMPUTE = "device.compute"
 WF_TRANSFER_DECODE = "transfer.decode"
 WF_MESH_FETCH = "mesh.fetch"
 WF_TOPN_WALK = "topn.walk"
+WF_GROUPBY_FINALIZE = "groupby.finalize"
 WF_REDUCE = "reduce"
 WF_HANDOFF_WAKE = "handoff.wake"
 WF_RESPOND = "respond"
@@ -552,11 +554,13 @@ WATERFALL_STAGES: tuple = (
     WF_GUARD_QUEUE,
     WF_TOPN_CANDIDATES,
     WF_FILTER_EVAL,
+    WF_GROUPBY_LOWER,
     WF_DEVICE_LAUNCH,
     WF_DEVICE_COMPUTE,
     WF_TRANSFER_DECODE,
     WF_MESH_FETCH,
     WF_TOPN_WALK,
+    WF_GROUPBY_FINALIZE,
     WF_REDUCE,
     WF_HANDOFF_WAKE,
     WF_RESPOND,
@@ -574,11 +578,13 @@ WATERFALL: dict = {
     WF_GUARD_QUEUE: "device-guard pool: wait for a worker to pick the call up",
     WF_TOPN_CANDIDATES: "TopN ranked-cache snapshot and candidate chunk assembly",
     WF_FILTER_EVAL: "a call's filter lowered on the host: to structure and staged leaves for a program that traces it, or to one shard stack by Range launches and eager boolean ops",
+    WF_GROUPBY_LOWER: "a GroupBy panel lowered on the host for its one launch: its dimensions' row ids resolved, their staged rows looked up and stacked, its Sum field's planes laid out",
     WF_DEVICE_LAUNCH: "the call of a compiled program up to its return of the not-yet-ready result: operands flattened and handed to every device, their bytes counted (a first call's compile too)",
     WF_DEVICE_COMPUTE: "host's wait for a launched program's result, the launch apart",
     WF_TRANSFER_DECODE: "device→host copy and result decode",
     WF_MESH_FETCH: "mesh: copy of a mesh kernel's replicated result (a TopN chunk's gathered scores, a Sum's or Count's reduced counts) from one replica",
     WF_TOPN_WALK: "TopN ranked walk, cross-shard merge, sort, pass-2 trim",
+    WF_GROUPBY_FINALIZE: "a GroupBy panel's fetched counts turned into its answer: the groups' sums assembled from the plane counts, the cross product walked, empty groups dropped, ordered, limited",
     WF_REDUCE: "host-side shard-result reduction",
     WF_HANDOFF_WAKE: "hand-backs: a worker thread's finishing stamp → the thread that waited for it running again (guard pool → the thread that runs the wave, a handed wave's thread → pipeline worker, pipeline worker → handler)",
     WF_RESPOND: "results → JSON bytes → last write",

@@ -373,7 +373,8 @@ def test_the_manifest_names_the_cell_with_one_chip_and_its_four_metrics():
     assert set(new) | {"kernels.hbm_roofline", "executor.unattributed_ms"} <= here
     assert "kernels.hbm_roofline_per_chip" not in here
     assert not set(new) & {m["name"] for m in run.metrics_of(manifest, "per_layer", "tall64.topn")}
-    assert [w["name"] for w in manifest["workloads"] if w["chips"] == 1] == ["tall64.topn", "taxi96.dashboard", CELL]
+    # the accepted one-chip cells stand first and in order; later cells are appended behind them
+    assert [w["name"] for w in manifest["workloads"] if w["chips"] == 1][:3] == ["tall64.topn", "taxi96.dashboard", CELL]
 
 
 def test_the_traffic_is_the_three_queries_over_462_requests():

@@ -433,8 +433,11 @@ def test_the_manifest_names_the_cell_with_four_chips_and_the_lists():
     assert entry["file"] == "benchmark/configs/ssb20x4.json" and entry["reduced"] == sorted(CONFIG["reduced"])
     assert entry["source"] == CONFIG["source"] and len(entry["source"]) <= 200
     assert all(w in entry["source"] for w in ("configs[4]", "SF=100", "Star Schema Benchmark", "flight 1", "SF=20"))
-    assert manifest["workloads"][-1] is cell and manifest["configs"][-1] is entry
-    assert len(manifest["workloads"]) == 5 and sum(w["chips"] == 4 for w in manifest["workloads"]) == 2
+    # where they were appended, behind the four cells before them; later cells come after
+    assert manifest["workloads"][4] is cell and manifest["configs"][4] is entry
+    assert [w["name"] for w in manifest["workloads"][:5]] == [
+        "tall64.topn", "taxi96.dashboard", "tall128x4.topn", "ssb10.flight1", CELL]
+    assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 2
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     new = by_name["executor.fusion_mesh_bypasses_per_query"]
     # appended behind ISSUE 34's, which is the last of the five that list this cell

@@ -216,6 +216,37 @@ def test_served_kernel_compiles_for_v5e(one_chip, lower):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 << 30
 
 
+# ssb10star.flight2's panels as the fuser lowers them (fusion._lower_groupby):
+# the years' 7 rows and the listed brands' rows stacked [R, S·W], the
+# filter's staged rows as leaves, lo_revenue's 24 planes + existence
+_STAR_Q21 = ("groupby_sum", (7, 40), ("Intersect", (("leaf", 0), ("leaf", 1))), 2, 24)
+_STAR_Q22 = ("groupby_sum", (7, 8), ("leaf", 0), 1, 24)
+_STAR_Q23 = ("groupby_sum", (7, 1), ("leaf", 0), 1, 24)
+
+
+@pytest.mark.parametrize("desc", [_STAR_Q21, _STAR_Q22, _STAR_Q23], ids=["q2_1", "q2_2", "q2_3"])
+def test_ssb_star_panel_compiles_and_keeps_its_group_matrix_only_at_q2_1(one_chip, desc):
+    """Q2.1 (K = 280) keeps the [K, S·W] u32 group matrix that
+    ``_lower_groupby`` declares to the admission (2,128,609,280 B; temp
+    2,197,222,912 B: PERF.md section 7); at Q2.2's K = 56 and Q2.3's 7
+    the compiler recomputes the groups in each popcount and keeps none,
+    though the admission is asked for them all the same."""
+    s = one_chip
+    wf = SSB_S * W
+    rows, leaves, depth = desc[1], desc[3], desc[4]
+    flat = [s((r, wf)) for r in rows] + [s((SSB_S, W))] * leaves + [s((depth + 1, wf))]
+    compiled = jax.jit(fusion._build_program((desc,))).lower(*flat).compile()
+    mem = compiled.memory_analysis()
+    groups = rows[0] * rows[1] * wf * 4
+    # the operands, as the chip lays them out (a stack's rows tiled: Q2.1's 624,951,296 B)
+    assert mem.argument_size_in_bytes >= ((sum(rows) + depth + 1) * wf + leaves * SSB_S * W) * 4
+    if rows[1] == 40:
+        assert groups <= mem.temp_size_in_bytes < groups + (128 << 20)
+    else:
+        assert mem.temp_size_in_bytes < 64 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 << 30
+
+
 TAXI_S = 96  # shards of benchmark/configs/taxi96.json
 
 

@@ -6,7 +6,8 @@ publishes. ISSUE 37: the launch apart from the wait at the three sites
 that launch, and the three hand-backs booked from the worker's stamp.
 ISSUE 38: a request that leads its own dispatch wave: its legs on the
 submitter's thread, its queue wait and its hand-back read off one
-thread's clock."""
+thread's clock. Last, a GroupBy panel's lowering and its answer's
+assembly, each one leg at both device sites."""
 
 import glob
 import itertools
@@ -747,6 +748,60 @@ def test_extend_adds_transport_legs_to_stage_and_total():
     assert list(s["stages"]) == order
 
 
+# -- a GroupBy panel's host legs -----------------------------------------------
+
+
+@pytest.mark.parametrize("site", ["fused", "lone"])
+def test_groupby_legs_are_booked_once_a_panel_at_both_device_sites_and_for_no_other_call(
+    gated_holder, monkeypatch, site
+):
+    """``groupby.lower`` (the panel's rows resolved, staged and stacked)
+    before the launch and ``groupby.finalize`` (the counts turned into
+    groups) after it, once each: in the fused program a lone panel
+    launches and on the per-call path behind ``fusion-enabled = false``.
+    No other call opens either."""
+    ex = Executor(gated_holder, device_policy="always", fusion_enabled=site == "fused")
+    cpu = Executor(gated_holder, device_policy="never")
+    seen: list = []
+
+    class counted(trace.leg):
+        __slots__ = ()
+
+        def __enter__(self):
+            seen.append(self.stage)
+            return super().__enter__()
+
+    monkeypatch.setattr(trace, "leg", counted)
+    ours = {trace.WF_GROUPBY_LOWER, trace.WF_GROUPBY_FINALIZE}
+    try:
+        for q in ("GroupBy(Rows(f, ids=[1, 2, 3]), Sum(field=v))",
+                  "GroupBy(Rows(f, ids=[2, 1]), Row(f=4), Sum(field=v))",
+                  "GroupBy(Rows(f, ids=[1, 5]), Row(f=3))"):
+            want = cpu.execute("i", q)
+            ex.execute("i", q)  # stage and compile
+            launches = _metric(metrics.FUSION_GROUPBY_LAUNCHES)
+            fused = _metric(metrics.FUSION_FUSED_LAUNCHES)
+            del seen[:]
+            wf: dict = {}
+            with trace.attrib_activate(wf):
+                assert ex.execute("i", q) == want
+            assert _metric(metrics.FUSION_GROUPBY_LAUNCHES) - launches == 1
+            assert _metric(metrics.FUSION_FUSED_LAUNCHES) - fused == (1 if site == "fused" else 0)
+            assert [st for st in seen if st in ours] == [trace.WF_GROUPBY_LOWER, trace.WF_GROUPBY_FINALIZE]
+            lower, finalize = seen.index(trace.WF_GROUPBY_LOWER), seen.index(trace.WF_GROUPBY_FINALIZE)
+            assert lower < seen.index(trace.WF_DEVICE_COMPUTE, lower) < finalize
+            assert wf[trace.WF_GROUPBY_LOWER] > 0.0 and wf[trace.WF_GROUPBY_FINALIZE] > 0.0
+            assert not ours & set(profiler.WaterfallAggregator.DEVICE_STAGES)
+        for q in ("Count(Row(f=1))", "Sum(Row(f=1), field=v)", "TopN(f, Row(f=1), n=3)",
+                  "Count(Row(f=1))Sum(Row(f=2), field=v)"):
+            del seen[:]
+            ex.execute("i", q)
+            assert trace.WF_DEVICE_COMPUTE in seen and not ours & set(seen), q
+    finally:
+        ex.close()
+        cpu.close()
+
+
 # -- the benchmark's readers against the program's names ----------------------
 
 
@@ -813,14 +868,18 @@ def test_the_manifest_holds_issue_37s_seven_metrics_and_each_is_a_data_file():
 def test_the_manifest_ends_with_issue_38s_metric_and_it_is_a_data_file():
     """``dispatch.waves{how=led}`` a request, every cell: the share of
     requests that crossed no thread into their wave. Nothing the
-    benchmark had is edited: one entry appended, one data file."""
+    benchmark had is edited: one entry appended, one data file. Later
+    entries are appended behind it (the five GroupBy metrics first), so
+    it is found where it was appended, not last."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         manifest = json.load(fh)
     name = "dispatch.waves_led_per_query"
-    assert manifest["per_layer"][-1] == {
+    at = [m["name"] for m in manifest["per_layer"]].index(name)
+    assert manifest["per_layer"][at] == {
         "name": name, "unit": "count/query", "better": "higher", "source": "program_counter",
         "layer": "dispatch", "moves": "query_p50_ms"}
-    assert manifest["per_layer"][-2]["name"] == "holder.cache_flush_s_in_window"
+    assert manifest["per_layer"][at - 1]["name"] == "holder.cache_flush_s_in_window"
+    assert manifest["per_layer"][at + 1]["name"] == "executor.groupby_launches_per_query"
     with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as fh:
         spec = json.load(fh)
     assert spec == {"name": name, "source": "server_metrics", "scale": 1, "per": "request",
